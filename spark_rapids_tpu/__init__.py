@@ -24,13 +24,11 @@ import jax as _jax
 # internally where Spark semantics allow.
 _jax.config.update("jax_enable_x64", True)
 
-# Persistent compilation cache: TPU cold compiles run 10-200s (AOT helper),
-# and query kernels are keyed on stable (expression, signature) pairs, so
-# cross-process reuse pays for itself immediately (measured 13.4s -> 0.3s).
-# The cache dir is keyed by a HOST FINGERPRINT (cpu flags + python/jax
-# versions): XLA:CPU AOT artifacts embed machine features that are not in
-# the cache key, and loading one compiled on a different machine SIGILLs
-# or segfaults — a repo checkout moving between hosts must not share them.
+# Host fingerprint (cpu flags + python/jax versions): XLA:CPU AOT artifacts
+# embed machine features that are not in the cache key, and loading one
+# compiled on a different machine SIGILLs or segfaults.  It keys the CPU
+# test cache (tests/conftest.py) and the kernel store's index
+# (compile/store.py); the accelerator cache does not need it.
 def _host_fingerprint() -> str:
     import hashlib
     import platform
@@ -56,9 +54,9 @@ def _enable_compile_cache(platform: str) -> None:
     cache reads even same-host), so CPU runs never touch it by default.
     The one implementation lives in the compilation service
     (compile/store.py — the tests' conftest and the conf-gated kernel
-    store are thin consumers of the same functions); the cache dir is
-    keyed by a host fingerprint because a repo checkout moves between
-    machines."""
+    store are thin consumers of the same functions):
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else the fixed
+    ``<checkout>/.jax_cache/<platform>``."""
     from spark_rapids_tpu.compile.store import enable_default_cache
     enable_default_cache(platform)
 

@@ -417,8 +417,13 @@ def _delta_dense(deltas, base, validity, out_dtype):
     per-row deltas.  Delta encoding is only selected for null-free
     columns, so ``validity`` is exactly the rows<n mask — masking with
     it reproduces the dense path's zero padding."""
-    vals = base[0] + jnp.cumsum(deltas.astype(out_dtype))
-    return jnp.where(validity, vals, 0).astype(out_dtype)
+    # pscan.prefix_sum, not jnp.cumsum: XLA:TPU's scan expansion of a
+    # full-capacity cumsum compiles for most of a minute per shape
+    # (53 s at 2^20 int64 for v5e).  The deltas are int8/int16, so
+    # their f64 partial sums are exact integers at any capacity.
+    from spark_rapids_tpu.utils.pscan import prefix_sum
+    run = prefix_sum(deltas.astype(jnp.float64)).astype(out_dtype)
+    return jnp.where(validity, base[0] + run, 0).astype(out_dtype)
 
 
 def _packed_dense(packed, cap: int):

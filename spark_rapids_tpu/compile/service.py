@@ -40,6 +40,14 @@ def engine_jit(fn, **kwargs):
     return jax.jit(fn, **kwargs)
 
 
+def pallas_interpret() -> bool:
+    """The one rule for the hand-written Pallas kernels
+    (exec/pallas_agg.py, exprs/pallas_strings.py): interpret iff the
+    backend is not ``tpu``.  On the chip a kernel always goes through
+    Mosaic, and what Mosaic refuses is an error."""
+    return jax.default_backend() != "tpu"
+
+
 def store_active() -> bool:
     from spark_rapids_tpu.compile import store
     return store.current() is not None

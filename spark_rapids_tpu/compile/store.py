@@ -10,7 +10,10 @@ Two layers, one directory (``spark.rapids.sql.compile.cacheDir``):
   or a restarted server) deserializes instead of recompiling.  The
   directory is exported through the env seam
   (``JAX_COMPILATION_CACHE_DIR``) so spawned shuffle/server worker
-  processes inherit it with the rest of the shipped conf.
+  processes inherit it with the rest of the shipped conf.  Where that
+  variable is ALREADY set, XLA's cache stays where it points and only
+  the index and payloads live under ``<dir>`` (``xla_cache_dir`` is the
+  one function that decides).
 * ``<dir>/index.jsonl`` + ``<dir>/payload/`` — the engine's OWN
   fingerprint index: one append-only JSONL line per executed
   (stage fingerprint, batch signature, capacity) triple, digested
@@ -53,22 +56,38 @@ _XLA_DIR = "xla"
 # runtime init are both thin consumers)
 # ---------------------------------------------------------------------------
 
+_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def xla_cache_dir(proposed: str) -> str:
+    """Where XLA's persistent cache lives — the one function that
+    decides.  ``JAX_COMPILATION_CACHE_DIR`` places it from outside: when
+    set, every caller (the accelerator default, the conf-gated store,
+    the test conftest) gets that directory back and never another; only
+    with it unset does the caller's ``proposed`` directory stand."""
+    return os.environ.get(_CACHE_DIR_ENV) or proposed
+
+
 def enable_persistent_cache(cache_dir: str,
                             min_compile_secs: float = 0.0,
                             export_env: bool = True) -> bool:
-    """Point the JAX persistent compilation cache at ``cache_dir`` and
-    export it through the env seam so spawned worker processes (mp
-    "spawn" in shuffle/stage.py and shuffle/worker.py import jax fresh)
-    inherit the same cache.  Never raises — the cache is an
-    optimization and must not block startup.  Returns success."""
+    """Point the JAX persistent compilation cache at
+    ``xla_cache_dir(cache_dir)`` and, with ``export_env``, export it
+    through the env seam so spawned worker processes (mp "spawn" in
+    shuffle/stage.py and shuffle/worker.py import jax fresh) inherit
+    the same cache.  Never raises — the cache is an optimization and
+    must not block startup.  Returns success."""
     import jax
+    cache_dir = xla_cache_dir(cache_dir)
     try:
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_secs))
         if export_env:
-            os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+            # never reassigned once set: whoever placed it first —
+            # the user, or an earlier export of this process — stands
+            os.environ.setdefault(_CACHE_DIR_ENV, cache_dir)
             os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = \
                 str(min_compile_secs)
         return True
@@ -79,23 +98,19 @@ def enable_persistent_cache(cache_dir: str,
 
 
 def enable_default_cache(platform: str) -> None:
-    """The accelerator-platform default (what ``_enable_compile_cache``
-    in the package root did before the store existed): TPU cold
-    compiles run 10-200s, so accelerator backends always get the
-    persistent cache, keyed by a host fingerprint.  CPU runs never
+    """The accelerator-platform default: TPU cold compiles run seconds
+    to minutes, so accelerator backends always get the persistent
+    cache, at ``JAX_COMPILATION_CACHE_DIR`` or else a FIXED path (the
+    path is part of what a later run must find again).  CPU runs never
     touch it by default — XLA:CPU AOT deserialization is unreliable
     across machine-feature mismatches — unless the store conf opts in
     explicitly (the test suite does, same-host by fingerprint)."""
     if platform == "cpu":
         return
-    cache = os.environ.get("SRT_JAX_CACHE_DIR")
-    if cache is None:
-        cache = _default_jax_cache_dir()
-    # no env export on this implicit path (matching the pre-store
-    # behavior): only an explicit opt-in — the conf-gated store or the
-    # test conftest — may overwrite a user's own JAX cache env vars
-    enable_persistent_cache(cache, min_compile_secs=1.0,
-                            export_env=False)
+    # no env export on this implicit path: only an explicit opt-in —
+    # the conf-gated store or the test conftest — exports the variable
+    enable_persistent_cache(_default_jax_cache_dir(platform),
+                            min_compile_secs=1.0, export_env=False)
 
 
 def _repo_root() -> Optional[str]:
@@ -106,15 +121,15 @@ def _repo_root() -> Optional[str]:
     return None
 
 
-def _default_jax_cache_dir() -> str:
-    from spark_rapids_tpu import _host_fingerprint
+def _default_jax_cache_dir(platform: str) -> str:
+    """``<checkout>/.jax_cache/<platform>`` (installed package: the
+    user cache dir).  No host fingerprint: accelerator executables are
+    keyed by XLA on the device, not on the host's CPU features."""
     repo = _repo_root()
     if repo is not None:
-        # repo checkout -> repo-local cache (shared with the bench and
-        # test drivers); installed package -> user cache dir
-        return os.path.join(repo, ".jax_cache", _host_fingerprint())
+        return os.path.join(repo, ".jax_cache", platform)
     return os.path.join(os.path.expanduser("~"), ".cache", "srt-jax",
-                        _host_fingerprint())
+                        platform)
 
 
 def default_store_dir(platform: Optional[str] = None) -> str:
@@ -346,7 +361,8 @@ def install(cache_dir: str, platform: str = "",
             min_compile_secs: float = 0.0) -> Optional[KernelStore]:
     """Install the store at ``cache_dir`` (idempotent on the same dir —
     counters survive) and point the JAX persistent cache at its
-    ``xla/`` subdirectory.  Returns None when the directory is
+    ``xla/`` subdirectory, unless ``JAX_COMPILATION_CACHE_DIR`` already
+    placed it (``xla_cache_dir``).  Returns None when the directory is
     unusable (the store is an optimization)."""
     global _STORE
     if not platform:
@@ -378,8 +394,7 @@ def disable() -> None:
 
 
 def reset() -> None:
-    """Test teardown: drop the installed store (the JAX cache config is
-    restored by the test fixture that snapshotted it)."""
+    """Test teardown: drop the installed store."""
     disable()
 
 

@@ -200,7 +200,9 @@ PALLAS_AGG = register(
     "spark.rapids.sql.tpu.pallas.agg.enabled", True,
     "Use the Pallas one-hot-reduction kernel for single-integer-key "
     "aggregations whose key domain fits 1024 dense slots (sort-free "
-    "update phase); falls back to the sorted-segment kernel otherwise.",
+    "update phase).  Which specs it takes is a static rule "
+    "(exec/pallas_agg.py:supports — 32-bit planes only on the chip); "
+    "every other aggregation runs the sorted-segment kernel.",
     bool)
 
 RANGE_SAMPLE_SIZE = register(

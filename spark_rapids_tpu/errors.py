@@ -12,7 +12,7 @@ retry/recompute machinery's ``isinstance`` checks are unchanged.
 Reference: the plugin maps every recoverable failure to a typed
 exception Spark's scheduler understands (FetchFailedException ->
 map-stage recompute, SplitAndRetryOOM -> retry iterator); this module
-is the analog taxonomy for the lifecycle layer
+is the analog hierarchy for the lifecycle layer
 (docs/fault_tolerance.md, "Query lifecycle").
 """
 

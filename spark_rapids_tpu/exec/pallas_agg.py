@@ -3,25 +3,30 @@
 Reference scope: the per-batch ``update`` aggregation the sorted-segment
 kernel in exec/aggregate.py implements (cuDF ``Table.groupBy().aggregate``
 analog, aggregate.scala:731).  For the common BI shape — a single integer
-group key with a small value domain (TPCH q1's 6 groups, date/flag/status
-keys) — sorting every batch by its keys is wasted work: this kernel maps
-keys to dense slots (key - lo, slot 0 reserved for nulls) and streams row
-blocks through a VMEM one-hot reduction:
+group key with a small value domain (date/flag/status keys, a year
+bucket) — sorting every batch by its keys is wasted work: this kernel
+maps keys to dense slots (key - lo, slot 0 reserved for nulls) and streams
+lane-dense row blocks through a VMEM one-hot accumulation:
 
-    grid step i:   onehot = (gid_block[:, None] == iota(K))      # VMEM
-                   acc[k] (op)= reduce(where(onehot, contrib, neutral))
+    rows live as (capacity/128, 128): one sublane row = 128 input rows
+    per sublane row s:  hit = (iota_slots(K, 128) == gid[s])     # VMEM
+                        acc[k, lane] (op)= where(hit, plane[s], neutral)
 
-TPU grid steps run sequentially, so the (K,)-shaped outputs accumulate
-across steps in place (the standard Pallas accumulation pattern) — the
-(capacity, K) one-hot never exists in HBM, and no sort runs at all.  Slot
-order (null, lo, lo+1, ...) equals the sorted kernel's group order
-(nulls-first ascending); counts/min/max/integer sums are bit-identical
-to the sort path, float sums accumulate in block order (the
-variableFloatAgg caveat, same as the reference's GPU float aggs).
+TPU grid steps run sequentially, so the (K, 128) accumulators stay
+resident in the output blocks across steps (the standard Pallas
+accumulation pattern); the final 128-lane fold is one XLA reduction
+outside the kernel.  The (capacity, K) one-hot never exists in HBM, and
+no sort runs at all.  Slot order (null, lo, lo+1, ...) equals the sorted
+kernel's group order (nulls-first ascending); counts/min/max/integer sums
+are bit-identical to the sort path, float sums accumulate in block order
+(the variableFloatAgg caveat, same as the reference's GPU float aggs).
 
-The kernel runs in interpret mode off-TPU (tests/virtual CPU meshes), and
-a one-time probe disables it gracefully if the platform rejects 64-bit
-Pallas ops (conf: spark.rapids.sql.tpu.pallas.agg.enabled).
+Mosaic has no 64-bit types, so on the chip every plane is int32 or
+float32 and every index the kernel touches is typed int32 explicitly
+(the package runs with ``jax_enable_x64``).  ``supports()`` decides that
+statically from the spec and the device float policy; a spec it admits
+compiles, and a compile error propagates.  Off-TPU the same kernel runs
+in interpret mode (compile/service.py:pallas_interpret).
 """
 
 from __future__ import annotations
@@ -30,6 +35,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu.compile.service import engine_jit
 from spark_rapids_tpu.columnar.dtypes import (
@@ -41,30 +47,33 @@ from spark_rapids_tpu.exprs.base import (
 from spark_rapids_tpu.exprs import aggregates as agf
 
 MAX_K = 1024          # largest dense key domain the kernel handles
-_BLOCK = 256          # rows per grid step (VMEM plane = _BLOCK x K)
+_LANES = 128          # rows per sublane row of the lane-dense planes
+_SUBLANES = 64        # sublane rows per grid step (8192 input rows)
+# accumulator slots one pallas_call may keep resident: each plane holds a
+# (K, 128) 32-bit block twice (Pallas double-buffers outputs), so
+# 10 * 1024 slots is 10 MiB of the 16 MiB scoped VMEM; wider specs run
+# as several calls over the same gid plane
+_MAX_RESIDENT_SLOTS = 10 * 1024
 
 from spark_rapids_tpu.utils.kernel_cache import KernelCache
 
 _RANGE_CACHE = KernelCache("pallas.range", 128)
 _UPDATE_CACHE = KernelCache("pallas.update", 128)
-_probe_result: Optional[bool] = None
 
 
 def enabled(conf) -> bool:
-    # the dense-slot fast path always has a backend: the Pallas kernel
-    # where Mosaic supports the plane dtypes, XLA segment ops otherwise
     from spark_rapids_tpu.conf import PALLAS_AGG
     return bool(conf.get(PALLAS_AGG))
 
 
 def max_capacity(spec) -> int:
     """Largest batch capacity the dense-slot kernel stays EXACT at for
-    this spec.  Int64 sums decompose into f64 limbs whose lo-limb
-    per-slot sum must stay under 2^53 (2^32 * capacity), capping those
-    at 2^21 rows; count-only / float-sum / min-max specs have no limb
-    bound and run to 2^24 (the band-join + COUNT shape, TPCx-BB q3/q8,
-    aggregates 8M joined pairs in one dense kernel instead of a
-    2^23-capacity bitonic sort)."""
+    this spec.  Int64 sums decompose into four 16-bit limbs summed in
+    int32: one (slot, lane) accumulator sees capacity/128 rows, so its
+    limb sum stays under 2^16 * 2^14 = 2^30 up to 2^21 rows; count-only
+    / float-sum / min-max specs have no limb bound and run to 2^24 (the
+    band-join + COUNT shape, TPCx-BB q3/q8, aggregates 8M joined pairs
+    in one dense kernel instead of a 2^23-capacity bitonic sort)."""
     from spark_rapids_tpu.exprs import aggregates as _agf
     for _, f in spec.aggs:
         if isinstance(f, (_agf.Sum, _agf.Average)):
@@ -76,13 +85,17 @@ def max_capacity(spec) -> int:
 
 def supports(spec) -> bool:
     """Single integer-like group key; Count/Sum/Min/Max/Average over
-    non-string inputs (their buffers all reduce with add/min/max)."""
+    non-string inputs (their buffers all reduce with add/min/max).
+    Static in the spec and the device float policy: every plane a spec
+    admitted here emits is one the kernel compiles for."""
     if len(spec.groupings) != 1:
         return False
     kdt = spec.groupings[0].dtype
     if kdt == STRING or kdt.is_floating:
         return False
-    from spark_rapids_tpu.columnar.dtypes import INT64
+    from spark_rapids_tpu.columnar.dtypes import INT64, device_dtype
+    from spark_rapids_tpu.compile import service
+    mosaic = not service.pallas_interpret()
     for _, f in spec.aggs:
         if not isinstance(f, (agf.Count, agf.Sum, agf.Min, agf.Max,
                               agf.Average)):
@@ -90,125 +103,126 @@ def supports(spec) -> bool:
         proj = f.input_projection()[0]
         if proj.dtype == STRING or proj.dtype == BOOLEAN:
             return False
-        # Mosaic has no 64-bit reductions: int64 SUMS decompose into two
-        # exact f64 limb sums (below), but 64-bit MIN/MAX would need a
+        # Mosaic has no 64-bit types: int64 SUMS decompose into exact
+        # 16-bit limb sums (below), but 64-bit MIN/MAX would need a
         # two-pass lexicographic reduce -> those stay on the sorted path
         if isinstance(f, (agf.Min, agf.Max)) and \
                 proj.dtype in (INT64, TIMESTAMP):
             return False
+        # a DOUBLE the device keeps as f64 (doubleAsFloat off) has no
+        # Mosaic plane; the interpreted kernel (tests, CPU) carries it
+        if mosaic and proj.dtype.is_floating and \
+                device_dtype(proj.dtype) == np.float64:
+            return False
     return True
 
 
-def _interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
-
-
-def _probe() -> bool:
-    """One-time check that a tiny 64-bit Pallas reduction compiles and
-    runs on this backend; off-TPU interpret mode always passes."""
-    global _probe_result
-    if _probe_result is None:
-        try:
-            gid = jnp.zeros(_BLOCK, jnp.int32)
-            # every plane dtype x op combination make_update can emit:
-            # int32 add/min/max (counts, narrow ints), f64 add (sums,
-            # int64 limbs), f64/f32 min/max (float extrema)
-            planes = (jnp.ones(_BLOCK, jnp.int32),
-                      jnp.ones(_BLOCK, jnp.float64),
-                      jnp.ones(_BLOCK, jnp.float32),
-                      jnp.ones(_BLOCK, jnp.float64),
-                      jnp.ones(_BLOCK, jnp.int32))
-            out = _pallas_reduce(
-                gid, planes, ("add", "add", "min", "max", "min"),
-                128, _BLOCK)
-            _probe_result = int(out[0][0]) == _BLOCK
-        except Exception:
-            _probe_result = False
-    return _probe_result
-
-
-def _neutral(op: str, dtype) -> jnp.ndarray:
+def _neutral(op: str, dtype) -> np.ndarray:
+    """The value an unoccupied slot holds under ``op`` — a numpy scalar
+    of the plane's own dtype, so tracing it inside the kernel never
+    widens to the x64 defaults."""
+    dtype = np.dtype(dtype)
     if op == "add":
-        return jnp.zeros((), dtype)
-    if jnp.issubdtype(dtype, jnp.floating):
-        return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dtype)
-    info = jnp.iinfo(dtype)
-    return jnp.asarray(info.max if op == "min" else info.min, dtype)
+        return np.zeros((), dtype)
+    if np.issubdtype(dtype, np.floating):
+        return np.asarray(np.inf if op == "min" else -np.inf, dtype)
+    info = np.iinfo(dtype)
+    return np.asarray(info.max if op == "min" else info.min, dtype)
+
+
+_COMBINE = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+
+
+def _fold_lanes(acc: jnp.ndarray, op: str) -> jnp.ndarray:
+    """(K, 128) lane accumulators -> (K,).  Integer sums widen first:
+    one accumulator stays inside int32 (max_capacity), but 128 of them
+    together need not — a 16-bit limb over 2^21 rows reaches 2^37."""
+    if op == "min":
+        return jnp.min(acc, axis=1)
+    if op == "max":
+        return jnp.max(acc, axis=1)
+    if jnp.issubdtype(acc.dtype, jnp.integer):
+        acc = acc.astype(jnp.int64)
+    return jnp.sum(acc, axis=1)
 
 
 def _pallas_reduce(gid: jnp.ndarray, planes: Tuple[jnp.ndarray, ...],
                    ops: Tuple[str, ...], K: int, capacity: int):
-    """(capacity,) planes -> per-slot (K,) reductions via a sequential
-    block grid with in-place output accumulation."""
-    from jax.experimental import pallas as pl
+    """(capacity,) planes -> per-slot (K,) reductions.  Wide specs split
+    into several calls so the resident accumulators fit VMEM."""
+    from spark_rapids_tpu.compile import service
+    interpret = service.pallas_interpret()
+    if not interpret:
+        bad = [str(p.dtype) for p in planes
+               if p.dtype not in (jnp.int32, jnp.float32)]
+        if bad:
+            # supports() admits only specs whose planes Mosaic has; a
+            # plane that got here anyway is an engine bug, not a reason
+            # to run something else
+            raise TypeError(
+                f"pallas_agg: {bad} planes have no Mosaic lowering")
+    # lane-dense layout: 128 input rows per sublane row, at least one
+    # (8, 128) tile; padded rows carry gid -1 and hit no slot
+    rows2 = max(capacity // _LANES, 8)
+    pad = rows2 * _LANES - capacity
 
-    block = min(_BLOCK, capacity)
-    n = len(planes)
+    def dense(a, fill):
+        if pad:
+            a = jnp.pad(a, (0, pad), constant_values=fill)
+        return a.reshape(rows2, _LANES)
 
-    def kernel(gid_ref, *refs):
-        crefs, orefs = refs[:n], refs[n:]
-        i = pl.program_id(0)
-        onehot = gid_ref[:][:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, K), 1)
-
-        def emit(b, op):
-            c = crefs[b][:]
-            neutral = _neutral(op, c.dtype)
-            plane = jnp.where(onehot, c[:, None], neutral)
-            if op == "add":
-                red = jnp.sum(plane, axis=0)
-            elif op == "min":
-                red = jnp.min(plane, axis=0)
-            else:
-                red = jnp.max(plane, axis=0)
-
-            @pl.when(i == 0)
-            def _init():
-                orefs[b][:] = red
-
-            @pl.when(i > 0)
-            def _acc():
-                prev = orefs[b][:]
-                if op == "add":
-                    orefs[b][:] = prev + red
-                elif op == "min":
-                    orefs[b][:] = jnp.minimum(prev, red)
-                else:
-                    orefs[b][:] = jnp.maximum(prev, red)
-
-        for b, op in enumerate(ops):
-            emit(b, op)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(capacity // block,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))] * (1 + n),
-        out_specs=[pl.BlockSpec((K,), lambda i: (0,))] * n,
-        out_shape=[jax.ShapeDtypeStruct((K,), p.dtype) for p in planes],
-        interpret=_interpret(),
-    )(gid, *planes)
-
-
-def _xla_reduce(gid: jnp.ndarray, planes: Tuple[jnp.ndarray, ...],
-                ops: Tuple[str, ...], K: int):
-    """Same contract as _pallas_reduce in plain XLA segment ops — the
-    backend when Mosaic lacks the plane dtypes (e.g. no 64-bit types on
-    this platform's Pallas); still sort-free."""
-    outs = []
-    for p, op in zip(planes, ops):
-        if op == "add":
-            outs.append(jax.ops.segment_sum(p, gid, num_segments=K))
-        elif op == "min":
-            outs.append(jax.ops.segment_min(p, gid, num_segments=K))
-        else:
-            outs.append(jax.ops.segment_max(p, gid, num_segments=K))
+    gid2 = dense(gid, -1)
+    per_call = max(1, _MAX_RESIDENT_SLOTS // K)
+    outs: list = []
+    for at in range(0, len(planes), per_call):
+        outs.extend(_pallas_reduce_call(
+            gid2, tuple(dense(p, 0) for p in planes[at:at + per_call]),
+            ops[at:at + per_call], K, interpret))
     return outs
 
 
-def _reduce_planes(gid, planes, ops, K, capacity):
-    if _probe():
-        return _pallas_reduce(gid, planes, ops, K, capacity)
-    return _xla_reduce(gid, planes, ops, K)
+def _pallas_reduce_call(gid2, planes2, ops, K: int, interpret: bool):
+    from jax.experimental import pallas as pl
+
+    rows2 = gid2.shape[0]
+    sub = min(_SUBLANES, rows2)
+    n = len(planes2)
+    zero = np.int32(0)
+
+    def kernel(gid_ref, *refs):
+        crefs, orefs = refs[:n], refs[n:]
+
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            for o, op in zip(orefs, ops):
+                o[...] = jnp.full((K, _LANES), _neutral(op, o.dtype),
+                                  o.dtype)
+
+        slot = jax.lax.broadcasted_iota(jnp.int32, (K, _LANES), 0)
+
+        def row(s, carry):
+            # one sublane row = 128 input rows; the (1, 128) gid row
+            # broadcasts down the K slots
+            hit = slot == gid_ref[pl.ds(s, 1), :]
+            for c, o, op in zip(crefs, orefs, ops):
+                v = jnp.where(hit, c[pl.ds(s, 1), :],
+                              _neutral(op, c.dtype))
+                o[...] = _COMBINE[op](o[...], v)
+            return carry
+
+        jax.lax.fori_loop(zero, np.int32(sub), row, zero)
+
+    accs = pl.pallas_call(
+        kernel,
+        grid=(rows2 // sub,),
+        in_specs=[pl.BlockSpec((sub, _LANES), lambda i: (i, zero))]
+        * (1 + n),
+        out_specs=[pl.BlockSpec((K, _LANES), lambda i: (zero, zero))] * n,
+        out_shape=[jax.ShapeDtypeStruct((K, _LANES), p.dtype)
+                   for p in planes2],
+        interpret=interpret,
+    )(gid2, *planes2)
+    return [_fold_lanes(a, op) for a, op in zip(accs, ops)]
 
 
 def key_range(grouping, batch, info: Optional[dict] = None,
@@ -329,11 +343,11 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
         # slot occupancy: any LIVE row (null keys land in slot 0)
         planes.append(live.astype(jnp.int32))
         ops.append("add")
-        # Mosaic rejects 64-bit reductions, so every plane is <= 32-bit
-        # int or float: counts reduce in int32 (capacity < 2^31) and cast
-        # back; int64 sums split into (lo 32 bits, hi arithmetic-shift)
-        # limb planes summed in f64 — both limb sums stay under 2^53 for
-        # capacity <= 2^20, so recombining (hi << 32) + lo in int64 is
+        # Mosaic has no 64-bit types, so every plane is a 32-bit int or
+        # float: counts reduce in int32 (capacity < 2^31) and cast
+        # back; int64 sums split into four unsigned 16-bit limb planes
+        # summed in int32 (max_capacity keeps every accumulator under
+        # 2^31), recombined as sum(limb_k << 16k) in wrapping int64 —
         # EXACT including Java wraparound; narrow int min/max reduce in
         # int32 and cast back
         post: List[tuple] = []  # (kind, indices...) per output buffer
@@ -353,15 +367,13 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                         post.append(("plain", len(planes) - 1))
                     else:
                         v = cv.data.astype(jnp.int64)
-                        lo_limb = (v & 0xFFFFFFFF).astype(jnp.float64)
-                        hi_limb = (v >> 32).astype(jnp.float64)
-                        z = jnp.zeros((), jnp.float64)
-                        planes.append(jnp.where(m, lo_limb, z))
-                        ops.append("add")
-                        planes.append(jnp.where(m, hi_limb, z))
-                        ops.append("add")
-                        post.append(("sum64", len(planes) - 2,
-                                     len(planes) - 1))
+                        z = jnp.zeros((), jnp.int32)
+                        for limb in range(4):
+                            bits = ((v >> (16 * limb)) & 0xFFFF).astype(
+                                jnp.int32)
+                            planes.append(jnp.where(m, bits, z))
+                            ops.append("add")
+                        post.append(("sum64", len(planes) - 4))
                 elif jnp.issubdtype(cv.data.dtype, jnp.floating):
                     # Spark NaN ordering (same as _segment_reduce):
                     # min ignores NaN unless all-NaN; max: any NaN -> NaN
@@ -390,7 +402,7 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                     post.append(("cast", len(planes) - 1,
                                  cv.data.dtype))
 
-        reds = _reduce_planes(gid, tuple(planes), tuple(ops), K,
+        reds = _pallas_reduce(gid, tuple(planes), tuple(ops), K,
                               capacity)
 
         seen = reds[0] > 0
@@ -422,9 +434,12 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                     jnp.take(reds[item[1]], perm).astype(item[2]),
                     group_valid, None))
             elif item[0] == "sum64":
-                lo_s = jnp.take(reds[item[1]], perm).astype(jnp.int64)
-                hi_s = jnp.take(reds[item[2]], perm).astype(jnp.int64)
-                buf_outs.append(ColVal((hi_s << 32) + lo_s,
+                total = jnp.zeros((K,), jnp.int64)
+                for limb in range(4):
+                    total = total + (
+                        reds[item[1] + limb].astype(jnp.int64)
+                        << (16 * limb))
+                buf_outs.append(ColVal(jnp.take(total, perm),
                                        group_valid, None))
             else:
                 base = jnp.take(reds[item[1]], perm)

@@ -137,16 +137,13 @@ def bitonic_lex_sort(keys: List[jnp.ndarray],
     # under shard_map the operands may carry varying manual axes (vma)
     # while the fresh iota is replicated; pvary everything to the union
     # so the fori carry avals match
-    try:
-        vma = set()
-        for a in canon:
-            vma |= set(getattr(jax.typeof(a), "vma", ()) or ())
-        if vma:
-            canon = [a if set(getattr(jax.typeof(a), "vma", ()) or ())
-                     == vma else jax.lax.pvary(a, tuple(vma))
-                     for a in canon]
-    except Exception:
-        pass
+    vma = set()
+    for a in canon:
+        vma |= set(jax.typeof(a).vma)
+    if vma:
+        canon = [a if set(jax.typeof(a).vma) == vma
+                 else jax.lax.pcast(a, tuple(vma), to="varying")
+                 for a in canon]
     arrs = tuple(canon)
     nk = len(keys) + 1  # iota is the stability tiebreak key
 

@@ -4,114 +4,115 @@
 The XLA string kernels in ``exprs/strings.py`` unroll their pattern
 loop at trace time — ``Contains`` emits one shifted comparison per
 pattern byte, which is ideal for short literals and pathological for
-long ones (a 64-byte needle is 64 full-width comparisons in the HLO).
-This module carries the Pallas alternative: a ``fori_loop`` over
-candidate windows inside ONE kernel, so the program size is constant
-in the pattern length and the VPU walks the char matrix once.
+long ones (a 64-byte needle is 64 full-width comparisons in the HLO,
+which XLA:TPU no longer fuses: each shifted slice lands in HBM).
+This module carries the Pallas alternative: a ``fori_loop`` over the
+pattern bytes inside ONE kernel, so the program size is constant in
+the pattern length and the VPU walks the char matrix once.
 
-Availability is probed, never assumed: the first use runs a tiny
-kernel (interpreted off-TPU, compiled on it) and any failure — Pallas
-missing, Mosaic rejecting the lowering — permanently degrades to the
-XLA path.  ``PallasContains`` is therefore always correct and at worst
-exactly ``Contains``; the fuzz suite drives both against the CPU
-oracle.
+Layout: the kernel reads the char matrix TRANSPOSED — bytes down the
+sublanes, rows across the lanes — so a window shift is a sublane
+rotate (``pltpu.roll``, which Mosaic lowers for a traced shift) and
+every per-row result is lane-dense.  A row grid streams one
+``(width, 8 * C)`` block at a time through VMEM; the matrix itself
+stays in HBM.  Off-TPU the same kernel runs in interpret mode
+(compile/service.py:pallas_interpret); on the chip a lowering error
+propagates — nothing here degrades to the XLA path.
 """
 
 from __future__ import annotations
 
-import logging
-import threading
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu.exprs.base import ColVal
 from spark_rapids_tpu.exprs.strings import Contains
 
-log = logging.getLogger("spark_rapids_tpu.exprs.pallas_strings")
+# Literals at least this long route to the Pallas kernel;
+# functions.contains reads this.  It is a memory rule set by the chip's
+# compiler, not a speed one (tests/test_tpu_compile.py pins it): up to 23
+# bytes XLA:TPU fuses the unroll into one pass with no HBM temp whatever
+# the needle's bytes; from 24 on a needle of distinct bytes has its
+# shifted slices materialised — 0.3-6 GB per 2^20-row batch at widths
+# 32-256, and a 128-byte needle over a 256-wide column no longer
+# compiles.  On time the kernel is ahead only past that limit on wide
+# matrices (14.1 vs 15.4 ms at (2^20, 128), 30 bytes) and behind at
+# width 64 (PERF.md, PR 21); ROADMAP S9 owns the rest.
+PALLAS_PATTERN_MIN = 24
 
-# patterns at least this long route to the Pallas kernel (below it the
-# XLA unroll is small and fuses better); functions.contains reads this
-PALLAS_PATTERN_MIN = 16
-
-_PROBE_LOCK = threading.Lock()
-_PROBE: Optional[bool] = None
+_SUBLANES = 8     # per-row result tile: (8, C) int32
+_LANES = 512      # C, rows per result sublane (128 for small inputs)
+_CHAR_TILE = 32   # uint8 sublane tiling: width pads to a multiple
 
 
-def _interpret() -> bool:
-    """Interpret off-TPU: the kernel then runs anywhere (tier-1 runs
-    JAX_PLATFORMS=cpu) while real hardware gets the Mosaic lowering."""
-    return jax.default_backend() != "tpu"
-
-
-def _contains_kernel(pat_ref, chars_ref, lens_ref, out_ref):
-    """out[r] <- any window of chars[r] equals the pattern.  The
-    window loop is a ``fori_loop`` (constant program size in k); each
-    step compares one (rows, k) slice against the needle."""
-    chars = chars_ref[...]
-    lens = lens_ref[...]
-    pat = pat_ref[...]
-    k = pat.shape[0]
-    rows, w = chars.shape
-    npos = w - k + 1
-
-    def body(j, acc):
-        win = jax.lax.dynamic_slice(chars, (0, j), (rows, k))
-        hit = jnp.all(win == pat[None, :], axis=1)
-        return acc | (hit & (j + k <= lens[:, 0]))
-
-    acc = jax.lax.fori_loop(0, npos, body,
-                            jnp.zeros((rows,), jnp.bool_))
-    out_ref[...] = acc[:, None]
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _run_contains(chars: jnp.ndarray, lengths: jnp.ndarray,
                   pat: bytes) -> jnp.ndarray:
+    """``out[r]`` <- some window of ``chars[r, :lengths[r]]`` equals
+    ``pat``."""
     from jax.experimental import pallas as pl
-    pat_arr = jnp.asarray(bytearray(pat), jnp.uint8)
+    from jax.experimental.pallas import tpu as pltpu
+
+    from spark_rapids_tpu.compile import service
+
+    rows, w = chars.shape
+    k = len(pat)
+    c = _LANES if rows >= _SUBLANES * _LANES else 128
+    blk = _SUBLANES * c
+    rows_p = _round_up(rows, blk)
+    w_p = _round_up(w, _CHAR_TILE)
+    # padded rows have length 0 and padded bytes sit past every length,
+    # so neither can complete a window
+    chars_t = jnp.pad(chars, ((0, rows_p - rows), (0, w_p - w))).T
+    lens2 = jnp.pad(lengths.astype(jnp.int32),
+                    (0, rows_p - rows)).reshape(rows_p // c, c)
+    pat_arr = jnp.asarray(np.frombuffer(pat, np.uint8).astype(np.int32))
+    zero = np.int32(0)
+
+    def kernel(pat_ref, chars_ref, lens_ref, out_ref):
+        pos = jax.lax.broadcasted_iota(jnp.int32, (w_p, c), 0)
+        for a in range(_SUBLANES):
+            x = chars_ref[:, a * c:(a + 1) * c].astype(jnp.int32)
+
+            def step(p, acc):
+                # rotate byte j+p up to sublane j; a window that wraps
+                # past w_p starts beyond lengths - k and is masked below
+                shifted = pltpu.roll(x, jnp.int32(w_p) - p, 0)
+                return jnp.where(shifted == pat_ref[p], acc, zero)
+
+            # int32 carry: Mosaic does not legalize an i1 loop carry.
+            # Traced int32 bounds: with concrete ones the loop becomes a
+            # scan whose index Mosaic's convert_element_type rule
+            # recurses on without end
+            acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(k), step,
+                                    jnp.ones((w_p, c), jnp.int32))
+            ok = (acc != 0) & (pos + np.int32(k) <= lens_ref[a:a + 1, :])
+            out_ref[a:a + 1, :] = jnp.max(ok.astype(jnp.int32), axis=0,
+                                          keepdims=True)
+
     out = pl.pallas_call(
-        _contains_kernel,
-        out_shape=jax.ShapeDtypeStruct((chars.shape[0], 1), jnp.bool_),
-        interpret=_interpret(),
-    )(pat_arr, chars, lengths.astype(jnp.int32)[:, None])
-    return out[:, 0]
-
-
-def pallas_available() -> bool:
-    """One probe per process: run the kernel on a toy batch and cache
-    the verdict.  Any failure (import, lowering, execution) degrades
-    every PallasContains to the XLA path for the process lifetime."""
-    global _PROBE
-    if _PROBE is not None:
-        return _PROBE
-    with _PROBE_LOCK:
-        if _PROBE is not None:
-            return _PROBE
-        try:
-            chars = jnp.zeros((8, 16), jnp.uint8)
-            lens = jnp.zeros(8, jnp.int32)
-            got = _run_contains(chars, lens, b"xy")
-            _PROBE = bool(got.shape == (8,))
-        except Exception as e:
-            log.warning("pallas string kernels unavailable (XLA path "
-                        "stands): %s", e)
-            _PROBE = False
-        return _PROBE
-
-
-def reset_probe() -> None:
-    """Test seam: forget the availability verdict."""
-    global _PROBE
-    with _PROBE_LOCK:
-        _PROBE = None
+        kernel,
+        grid=(rows_p // blk,),
+        in_specs=[
+            pl.BlockSpec((k,), lambda i: (zero,),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((w_p, blk), lambda i: (zero, i)),
+            pl.BlockSpec((_SUBLANES, c), lambda i: (i, zero)),
+        ],
+        out_specs=pl.BlockSpec((_SUBLANES, c), lambda i: (i, zero)),
+        out_shape=jax.ShapeDtypeStruct((rows_p // c, c), jnp.int32),
+        interpret=service.pallas_interpret(),
+    )(pat_arr, chars_t, lens2)
+    return out.reshape(rows_p)[:rows] != 0
 
 
 class PallasContains(Contains):
-    """``Contains`` with the window loop in a Pallas kernel — same
-    semantics, constant program size in the pattern length.  Falls
-    back to the parent's XLA unroll when the probe fails, so planners
-    can route long literals here unconditionally."""
+    """``Contains`` with the pattern loop in a Pallas kernel — same
+    semantics, constant program size in the pattern length."""
 
     def key(self) -> str:
         return "Pallas" + super().key()
@@ -123,6 +124,4 @@ class PallasContains(Contains):
             return jnp.ones_like(c.validity)
         if k > w:
             return jnp.zeros_like(c.validity)
-        if not pallas_available():
-            return super()._match(c)
         return _run_contains(c.chars, c.data, self.pat)

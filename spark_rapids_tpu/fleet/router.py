@@ -157,6 +157,30 @@ class _ReplicaSlot:
         self.generation = 0
 
 
+def _require_chip_free() -> None:
+    """One process per chip: a replica builds its own TpuSession, and an
+    accelerator belongs to the process that initialised JAX first.  A
+    router process that already holds the chip can only watch its
+    replicas fail or wait out ``fleet.startupTimeoutMs`` — refuse at
+    once, typed, naming the cause (docs/serving.md, "One process per
+    chip").  On the host backend there is nothing to hold.
+
+    ``backends_are_initialized`` is private to the installed JAX
+    (0.9.0): no public call says whether a backend is up without
+    bringing it up — ``jax.default_backend()`` alone would take the
+    chip for a router process that had stayed off it."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() != "cpu":
+        raise ReplicaFailedError(
+            0, f"this process has initialised JAX and holds the "
+               f"{jax.default_backend()} device(s); a spawned replica "
+               "process cannot open them (one process per chip). Start "
+               "the fleet from a process that has not touched JAX, or "
+               "serve in-process with session.server()")
+
+
 class FleetRouter:
     """Front door over R SessionServer replica processes; constructed
     via ``session.fleet()`` with ``spark.rapids.fleet.replicas`` >= 1.
@@ -168,6 +192,7 @@ class FleetRouter:
         if self._n < 1:
             raise ValueError(
                 "session.fleet() needs spark.rapids.fleet.replicas >= 1")
+        _require_chip_free()
         self._conf = conf
         # conf-driven fault injection must reach the DRIVER-side fleet
         # sites (fleet.route fires before any replica sees the query;

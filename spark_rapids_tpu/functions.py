@@ -348,9 +348,10 @@ def regexp_replace(c, pattern, rep) -> Column:
 
 
 def contains(c, substr) -> Column:
-    """Substring predicate.  Long literal needles route to the Pallas
-    kernel (constant program size in pattern length); short ones keep
-    the XLA unrolled compare, which fuses into the stage."""
+    """Substring predicate.  Literal needles of ``PALLAS_PATTERN_MIN``
+    bytes or more route to the Pallas kernel (constant program size and
+    no HBM temp in pattern length); shorter ones keep the XLA unrolled
+    compare, which fuses into the stage."""
     from spark_rapids_tpu.exprs import strings as st
     from spark_rapids_tpu.exprs import pallas_strings as ps
     p = substr if isinstance(substr, Column) else lit(substr)

@@ -288,10 +288,7 @@ class DistributedHashJoin:
         if fn is not None:
             return fn
         from spark_rapids_tpu.parallel.mesh import DATA_AXIS
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         lkeys, rkeys = self.left_keys, self.right_keys
 
@@ -322,10 +319,7 @@ class DistributedHashJoin:
         if fn is not None:
             return fn
         from spark_rapids_tpu.parallel.mesh import DATA_AXIS
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from spark_rapids_tpu.utils.pscan import (
             masked_positions, prefix_sum,

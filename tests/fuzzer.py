@@ -162,7 +162,8 @@ def gen_join_tables(seed: int, n_left: int, n_right: int,
     return left, right
 
 
-_NEEDLES = ["qu", "ick", "%", "_", "", "the needle is long enough!",
+_NEEDLES = ["qu", "ick", "%", "_", "",
+            "the needle is surely long enough!",  # >= PALLAS_PATTERN_MIN
             "zz9"]
 _DELIMS = [",", "|", "::"]
 
@@ -171,7 +172,7 @@ def gen_string_column(rng: np.random.Generator, n: int,
                       null_prob: float = 0.08,
                       needle_prob: float = 0.35) -> pa.Array:
     """Free-form strings exercising the device string kernels: random
-    alphabet runs with planted needles (short and >=16-byte, so both
+    alphabet runs with planted needles (short and >=24-byte, so both
     the unrolled-XLA and the Pallas contains paths fire), empty
     strings, and LIKE metacharacters as literal content."""
     alphabet = list("abcdefgh XYZ019._%")

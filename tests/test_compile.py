@@ -463,3 +463,41 @@ def test_store_hit_timing_pins_deserialize_seam(monkeypatch):
         "not be attributed to the hit bucket)")
     assert st["cold_ms"] == 0
     assert "xlaCompileTraceMs" in service.snapshot()
+
+
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+@pytest.mark.parametrize("how", ["default_tpu", "enable", "install",
+                                 "unset_default_tpu"])
+def test_xla_cache_dir_is_placed_from_outside(how, monkeypatch,
+                                              tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` places XLA's cache: with it set
+    (the conftest exports it for the whole test process) neither the
+    accelerator default, an explicit enable, nor a store install moves
+    ``jax.config.jax_compilation_cache_dir`` or reassigns the variable;
+    unset, the tpu default is the fixed ``<checkout>/.jax_cache/tpu``."""
+    import jax
+    other = str(tmp_path / "elsewhere")
+    if how == "unset_default_tpu":
+        monkeypatch.delenv(_CACHE_ENV)
+        store.enable_default_cache("tpu")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(repo, ".jax_cache", "tpu")
+        assert _CACHE_ENV not in os.environ  # the implicit path: no export
+        return
+    placed = os.environ[_CACHE_ENV]
+    assert jax.config.jax_compilation_cache_dir == placed
+    if how == "default_tpu":
+        store.enable_default_cache("tpu")
+    elif how == "enable":
+        assert store.enable_persistent_cache(other)
+    else:
+        st = store.install(other)
+        # the index and payloads still live under the store's own dir
+        assert st is not None and st.root == other
+        assert os.path.isdir(os.path.join(other, "payload"))
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert os.environ[_CACHE_ENV] == placed
+    assert not os.path.exists(os.path.join(other, "xla"))

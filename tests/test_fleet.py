@@ -173,6 +173,27 @@ def test_fleet_requires_conf_and_keys_are_result_neutral(fleet_data):
     assert conf_fingerprint(base) == conf_fingerprint(fleeted)
 
 
+def test_fleet_refuses_at_once_when_this_process_holds_an_accelerator(
+        monkeypatch):
+    """One process per chip (docs/serving.md): from a process whose JAX
+    backend is initialised on an accelerator, ``session.fleet()``
+    raises typed and names the cause — it spawns nothing and waits out
+    no startup window."""
+    import jax
+    assert jax.devices()  # this process has initialised JAX
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = st.TpuSession({"spark.rapids.fleet.replicas": 1})
+    try:
+        t0 = time.monotonic()
+        with pytest.raises(ReplicaFailedError,
+                           match="one process per chip"):
+            s.fleet()
+        assert time.monotonic() - t0 < 5.0
+        assert s._fleet is None
+    finally:
+        s.stop()
+
+
 # ---------------------------------------------------------------------------
 # tier-1: conf-driven fault sites with @r targeting + budget-0 shed
 # (its own fleet, run BEFORE the shared fleet boots: fault specs and

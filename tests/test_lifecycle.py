@@ -44,7 +44,7 @@ def test_error_hierarchy_consolidated():
         BlockCorruptError, ChecksumUnavailableError, CodecUnavailableError,
         FrameUnavailableError,
     )
-    # lifecycle taxonomy: a timeout IS a cancellation
+    # lifecycle hierarchy: a timeout IS a cancellation
     assert issubclass(QueryTimeoutError, QueryCancelledError)
     assert issubclass(QueryCancelledError, EngineError)
     assert issubclass(QueryHangError, EngineError)

@@ -225,12 +225,18 @@ def test_ooc_off_keeps_old_fallback_and_stays_inert(rng):
     assert df_abs.explain() == df_exp.explain(), \
         "ooc.enabled=false must not perturb the plan"
     # both runs see identical process-global AQE exchange stats (the
-    # measured-bytes estimates feed the over-budget gate): reset before
-    # each so the two sessions make the same cold decisions
-    from spark_rapids_tpu.exec import aqe
+    # measured-bytes estimates feed the over-budget gate) and the same
+    # Pallas range-probe miss count (exec/aggregate.py: after two
+    # fresh-buffer misses a spec's NEXT run takes the sorted-segment
+    # kernel, whose float sums round in another order than the Pallas
+    # lane fold): reset both before each so the two sessions make the
+    # same cold decisions
+    from spark_rapids_tpu.exec import aggregate, aqe
     aqe.reset_stats()
+    aggregate._PALLAS_FRESH_MISSES.clear()
     t_abs = df_abs.to_arrow()
     aqe.reset_stats()
+    aggregate._PALLAS_FRESH_MISSES.clear()
     t_exp = df_exp.to_arrow()
     assert t_abs.equals(t_exp), "absent vs false: results byte-differ"
     assert_tables_equal(t_abs, absent_t, approx_float=True)

@@ -114,6 +114,22 @@ def test_pallas_agg_int_sums_exact():
     assert out[1] == 7
 
 
+def test_pallas_agg_int_sum_limbs_do_not_overflow_the_lane_fold():
+    """A 16-bit limb summed over more than 2^15 rows of one group passes
+    2^31: the 128 int32 lane accumulators must fold in int64."""
+    n = 70_000
+    big = (1 << 40) - 1  # limbs 0xFFFF, 0xFFFF, 0x00FF
+    t = pa.table({"k": pa.array([3] * n, pa.int64()),
+                  "v": pa.array([big] * n, pa.int64())})
+    s = tpu_session()
+    s.set_conf("spark.rapids.sql.tpu.pallas.agg.enabled", "true")
+    df = s.create_dataframe(t).group_by("k").agg(
+        F.sum(col("v")).alias("s"), F.count(col("v")).alias("c"))
+    out = df.to_arrow().to_pylist()
+    assert _agg_exec(s).metrics["pallasAggBatches"].value > 0
+    assert out == [{"k": 3, "s": n * big, "c": n}]
+
+
 def test_pallas_agg_wide_domain_falls_back():
     rng = np.random.default_rng(1)
     t = pa.table({

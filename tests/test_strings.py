@@ -338,8 +338,9 @@ from fuzzer import gen_string_table  # noqa: E402
 
 @pytest.mark.parametrize("seed", [3, 17])
 def test_fuzz_contains_short_and_long_needles(seed):
-    """Short needles keep the unrolled XLA compare; >=16-byte needles
-    route to the Pallas contains kernel.  Both must match the oracle
+    """Short needles keep the unrolled XLA compare; needles of
+    PALLAS_PATTERN_MIN (24) bytes or more route to the Pallas contains
+    kernel.  Both must match the oracle
     over the needle-planted fuzz column."""
     t = gen_string_table(seed, 600)
     assert_tpu_and_cpu_equal(
@@ -348,12 +349,14 @@ def test_fuzz_contains_short_and_long_needles(seed):
             F.contains(col("s"), "%").alias("b"),
             F.contains(col("s"), "").alias("c"),
             F.contains(col("s"),
-                       "the needle is long enough!").alias("d")))
+                       "the needle is surely long enough!").alias("d"),
+            # 20 bytes: long, but still under the Pallas threshold
+            F.contains(col("s"), "the needle is surely").alias("e")))
 
 
 def test_fuzz_contains_pallas_kernel_selected():
     from spark_rapids_tpu.exprs import pallas_strings as ps
-    needle = "the needle is long enough!"
+    needle = "the needle is surely long enough!"
     assert len(needle) >= ps.PALLAS_PATTERN_MIN
     t = gen_string_table(5, 200)
     s_tpu = __import__("tests.compare", fromlist=["tpu_session"])

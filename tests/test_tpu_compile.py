@@ -1,0 +1,193 @@
+"""The main path's kernels, compiled for a v5e that is described and not
+attached (``jax.experimental.topologies``): what the chip's compiler
+refuses — a 64-bit type inside a Pallas kernel, a slice Mosaic cannot
+lower, a program that does not fit — fails here, at no chip time.
+Nothing runs; a compile that passes is not a chip run
+(``python chip_smoke.py`` through the chip tool is).
+
+The described chip is not the default backend (tier-1 pins the CPU), so
+the Pallas tests steer the ONE interpret rule
+(``compile.service.pallas_interpret``) from here.  The topology is
+described inside a module-scoped fixture — never at import — because
+only one process may load libtpu: every xdist worker collects this
+file, and only the worker that runs it may touch the library.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+CAP = 1 << 20  # spark.rapids.sql.reader.batchSizeRows default
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Compile the Pallas kernels as the chip would: never interpreted."""
+    from spark_rapids_tpu.compile import service
+    monkeypatch.setattr(service, "pallas_interpret", lambda: False)
+
+
+def _compile(fn, *avals):
+    return jax.jit(fn).lower(*avals).compile()
+
+
+@pytest.mark.parametrize("K", [128, 1024])
+def test_pallas_agg_compiles_for_v5e(one_chip, mosaic, K):
+    """Every plane dtype x op ``make_update`` emits on the chip: int32
+    add (counts, int64-sum limbs), f32 add (sums), int32/f32 min/max."""
+    from spark_rapids_tpu.exec import pallas_agg
+    dtypes = (jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.int32,
+              jnp.int32, jnp.int32, jnp.float32, jnp.float32, jnp.int32,
+              jnp.int32)
+    ops = ("add", "add", "add", "add", "add", "add", "add", "min",
+           "max", "min", "max")
+
+    def sds(dt):
+        return jax.ShapeDtypeStruct((CAP,), dt, sharding=one_chip)
+
+    compiled = _compile(
+        lambda gid, *planes: pallas_agg._pallas_reduce(
+            gid, planes, ops, K, CAP),
+        sds(jnp.int32), *[sds(dt) for dt in dtypes])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_agg_refuses_64bit_planes_on_the_chip(mosaic):
+    """What ``supports()`` must never admit raises, typed, at trace
+    time — it does not run another path."""
+    from spark_rapids_tpu.exec import pallas_agg
+    with pytest.raises(TypeError, match="no Mosaic lowering"):
+        jax.eval_shape(
+            lambda g, p: pallas_agg._pallas_reduce(
+                g, (p,), ("add",), 128, 1024),
+            jax.ShapeDtypeStruct((1024,), jnp.int32),
+            jax.ShapeDtypeStruct((1024,), jnp.float64))
+
+
+def _needle(k: int) -> bytes:
+    """All bytes distinct: the worst case for the XLA unroll's fusion."""
+    return bytes(range(65, 65 + k))
+
+
+def _chars_and_lens(one_chip):
+    return (jax.ShapeDtypeStruct((CAP, 64), jnp.uint8, sharding=one_chip),
+            jax.ShapeDtypeStruct((CAP,), jnp.int32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("at_threshold", [True, False])
+def test_pallas_contains_compiles_for_v5e(one_chip, mosaic, at_threshold):
+    """At the routing threshold and at 16 bytes below it: the kernel
+    takes any length, whatever ``functions.contains`` sends it."""
+    from spark_rapids_tpu.exprs import pallas_strings
+    k = pallas_strings.PALLAS_PATTERN_MIN if at_threshold else 16
+    compiled = _compile(
+        lambda chars, lens: pallas_strings._run_contains(
+            chars, lens, _needle(k)),
+        *_chars_and_lens(one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_contains_threshold_is_where_the_xla_unroll_stops_fusing(
+        one_chip, fused):
+    """Why ``PALLAS_PATTERN_MIN`` is 24: one byte under it the chip's
+    compiler fuses the XLA unroll into a single pass with no HBM temp
+    whatever the needle's bytes; from it on a needle of distinct bytes
+    has its shifted slices materialised (1.2 GB at this shape, 6 GB at
+    width 256; a 128-byte needle there no longer compiles).  If this
+    fails after an upgrade, move the threshold to the new limit."""
+    from types import SimpleNamespace
+
+    from spark_rapids_tpu.exprs import pallas_strings
+    from spark_rapids_tpu.exprs.strings import Contains
+    k = pallas_strings.PALLAS_PATTERN_MIN - (1 if fused else 0)
+    compiled = _compile(
+        lambda chars, lens: Contains._match(
+            SimpleNamespace(pat=_needle(k)),
+            SimpleNamespace(chars=chars, data=lens, validity=None)),
+        *_chars_and_lens(one_chip))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (1 << 20) if fused else temp > (256 << 20), temp
+
+
+def test_fused_entry_step_compiles_for_v5e(one_chip):
+    """``__graft_entry__.entry()``: filter + project + sorted-segment
+    aggregate in one XLA program at the default batch capacity."""
+    import __graft_entry__ as graft
+    step, (flat, num_rows) = graft.entry(CAP)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        (flat, num_rows))
+    compiled = _compile(step, *avals)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+def test_sort_permutation_compiles_for_v5e(one_chip):
+    from spark_rapids_tpu.exec.sortkeys import sort_permutation
+
+    def sds(dt):
+        return jax.ShapeDtypeStruct((CAP,), dt, sharding=one_chip)
+
+    compiled = _compile(
+        lambda k0, k1, live: sort_permutation([k0, k1], CAP, live),
+        sds(jnp.int64), sds(jnp.float32), sds(jnp.bool_))
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+def test_distributed_aggregate_compiles_for_four_chips(topo):
+    """One width-4 ``DistributedAggregate`` step over ``Mesh(topo
+    .devices)``: the exchange must lower to an ``all_to_all`` across the
+    mesh, and each chip's share must fit beside a real working set."""
+    from spark_rapids_tpu.columnar.dtypes import FLOAT32, INT64
+    from spark_rapids_tpu.exprs.aggregates import Count, Sum
+    from spark_rapids_tpu.exprs.base import Alias, BoundReference
+    from spark_rapids_tpu.parallel import DistributedAggregate
+    from spark_rapids_tpu.parallel.mesh import DATA_AXIS
+    mesh = Mesh(np.asarray(topo.devices), (DATA_AXIS,))
+    n_dev, cap = mesh.devices.size, CAP // mesh.devices.size
+    assert n_dev == 4
+    k = BoundReference(0, INT64, True, "k")
+    v = BoundReference(1, FLOAT32, True, "v")
+    dist = DistributedAggregate(
+        [k], [Alias(Count(v), "cnt"), Alias(Sum(v), "s")], mesh=mesh)
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+
+    def plane(dt):
+        return jax.ShapeDtypeStruct((n_dev, cap), dt, sharding=rows)
+
+    stacked = ((plane(jnp.int64), plane(jnp.bool_), None),
+               (plane(jnp.float32), plane(jnp.bool_), None))
+    counts = jax.ShapeDtypeStruct((n_dev,), jnp.int32, sharding=rows)
+    compiled = dist._step(cap).lower(stacked, counts, ()).compile()
+    assert "all-to-all" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
