@@ -1,0 +1,203 @@
+"""The command end to end on the CPU, for each cell: the result line has the
+contract's keys and every metric ``BENCHMARK.json`` names for the cell; a
+wrong answer, planted in the reference or where the timed path produces it,
+makes ``correct`` false; a new cell is files and entries only."""
+
+import json
+import os
+
+import pyarrow as pa
+import pytest
+from conftest import CELLS, SERVED
+
+BOTH = CELLS + (SERVED,)
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+OPTIONAL_KEYS = {"breakdown", "compared", "stderr", "first_run_in_checkout"}
+
+
+def expected_metrics(copy, cell, trace):
+    with open(os.path.join(copy.root, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    e2e = [m for m in bench["end_to_end"]
+           if cell in m.get("workloads", [cell])]
+    if not trace:
+        return {m["name"] for m in e2e}
+    # a CPU trace has no device plane: the readers of the device's trace
+    # find nothing to read and leave their metrics out
+    return {m["name"] for m in bench["per_layer"]
+            if cell in m.get("workloads", [cell])
+            and m["source"] != "device_trace"}
+
+
+@pytest.mark.parametrize("trace", (0, 1))
+@pytest.mark.parametrize("cell", BOTH, indirect=True)
+def test_result_line(copy, capsys, cell, trace):
+    result = copy.run(capsys, cell, trace=trace)
+    assert RESULT_KEYS <= set(result) <= RESULT_KEYS | OPTIONAL_KEYS
+    assert result["first_run_in_checkout"] in (True, False)
+    assert list(result)[-2] == "compared"  # last in the line itself
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["attempted"] > 0
+    assert set(result["metrics"]) == expected_metrics(copy, cell, trace)
+    for name, metric in result["metrics"].items():
+        assert set(metric) == {"value", "unit"}, name
+    device = result["device"]
+    assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(device)
+    if trace:
+        assert {"busy_s", "window_s"} <= set(device)
+        assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    # every number compared stands beside its limit, on stderr too
+    for name, pair in result["compared"].items():
+        assert set(pair) == {"value", "limit"}
+        assert f"compared {name}: " in result["stderr"]
+    assert result["stderr"].strip().splitlines()[-1].startswith("compared ")
+
+
+def test_same_seed_same_traffic(copy):
+    traffic = copy.harness.load_module("traffic.py")
+    cell = {"kind": "served", "streams": 2, "stream_offset": 2,
+            "cycle": ["q6", "q6", "q6"]}
+    queries = os.path.join(copy.bench, "queries")
+
+    def first(seed, stream, n=30):
+        t = traffic.ServedTraffic(cell, queries, seed)
+        it = t.stream(stream)
+        return t, [next(it) for _ in range(n)]
+
+    big = 2**31 + 12345  # more than 32 signed bits hold
+    assert first(big, 0)[1] == first(big, 0)[1]
+    assert first(big, 0)[1] != first(big + 1, 0)[1]
+    # every seed sends the same set of bindings (TPC-H 2.4.6: 5 dates x 8
+    # discounts x 2 quantities), dealt to the streams without replacement,
+    # the one that warms the template left out
+    for seed in (1, big):
+        t, a = first(seed, 0, 40)
+        _, b = first(seed, 1, 40)
+        sent = [p for _, p in a + b]
+        assert len(set(sent)) == 79 and t.warm("q6") not in sent
+        assert sorted(set(sent) | {t.warm("q6")}) == \
+            sorted(set(first(1, 0)[0].grid["q6"]))
+
+
+@pytest.mark.parametrize("cell", BOTH, indirect=True)
+def test_wrong_reference_is_not_correct(copy, capsys, cell):
+    with open(os.path.join(copy.bench, "reference", "tpch.py"), "a") as fh:
+        fh.write(
+            "\n\n_sound_q6 = _q6\n\n"
+            "def _q6(*args):\n"
+            "    t = _sound_q6(*args)\n"
+            "    return pa.table({'revenue': [t.column(0)[0].as_py()"
+            " * 1.001]})\n")
+    result = copy.run(capsys, cell)
+    assert result["correct"] is False
+    assert result["compared"]["gap.q6.revenue"]["value"] > \
+        result["compared"]["gap.q6.revenue"]["limit"]
+
+
+def _alter_float(table):
+    i = next(i for i, f in enumerate(table.schema)
+             if pa.types.is_floating(f.type))
+    values = table.column(i).to_pylist()
+    values[0] = values[0] * 1.001
+    return table.set_column(i, table.schema.field(i), pa.array(values))
+
+
+def _alter_integer(table):
+    i = next(i for i, f in enumerate(table.schema)
+             if pa.types.is_integer(f.type))
+    values = table.column(i).to_pylist()
+    values[-1] += 1
+    return table.set_column(i, table.schema.field(i),
+                            pa.array(values, table.schema.field(i).type))
+
+
+def _drop_row(table):
+    return table.slice(0, table.num_rows - 1)
+
+
+FAULTS = {"float": (_alter_float, "gap."),
+          "integer": (_alter_integer, "exact_mismatches"),
+          "row": (_drop_row, "exact_mismatches")}
+# Q6 alone has no integer cell to alter
+CELL_FAULTS = [(c, f) for c in BOTH for f in sorted(FAULTS)
+               if (c, f) != (SERVED, "integer")]
+
+
+@pytest.mark.parametrize("cell,fault", CELL_FAULTS, indirect=["cell"])
+def test_broken_timed_path_is_not_correct(copy, capsys, monkeypatch, cell,
+                                          fault):
+    """The rest of a run, with the answer altered where the timed path
+    produces it: a float cell off by a thousandth, an integer cell off by
+    one, a row missing."""
+    from spark_rapids_tpu.api import DataFrame
+    from spark_rapids_tpu.server.core import ServerQuery
+    alter, fails = FAULTS[fault]
+    sound_to_arrow, sound_result = DataFrame.to_arrow, ServerQuery.result
+
+    def broken(table):
+        try:
+            return alter(table)
+        except StopIteration:  # no column of that type in this answer
+            return table
+
+    monkeypatch.setattr(DataFrame, "to_arrow",
+                        lambda self, *a, **k: broken(
+                            sound_to_arrow(self, *a, **k)))
+    if cell == SERVED:
+        # the server's workers call to_arrow themselves: break the ticket
+        monkeypatch.setattr(DataFrame, "to_arrow", sound_to_arrow)
+        monkeypatch.setattr(ServerQuery, "result",
+                            lambda self, *a, **k: broken(
+                                sound_result(self, *a, **k)))
+    result = copy.run(capsys, cell)
+    assert result["correct"] is False
+    over = [k for k, v in result["compared"].items()
+            if v["limit"] is not None and v["value"] > v["limit"]]
+    assert any(k.startswith(fails) for k in over), over
+
+
+def test_a_new_cell_is_files_and_entries_only(copy, capsys):
+    """A throw-away cell, metric and all, without a line of ``run.py``."""
+    with open(os.path.join(copy.bench, "workloads",
+                           "tpch_sf1.q6_only.json"), "w") as fh:
+        json.dump({"config": "tpch_sf1", "kind": "stream",
+                   "queries": ["q6"], "why": "throw-away"}, fh)
+    with open(os.path.join(copy.bench, "metrics",
+                           "d2h_bytes_per_query.json"), "w") as fh:
+        json.dump({"reader": "engine_stat_delta",
+                   "args": {"path": "d2h.bytes", "per": "queries"}}, fh)
+
+    def add(bench):
+        bench["workloads"].append({
+            "name": "tpch_sf1.q6_only", "config": "tpch_sf1",
+            "traffic": "q6_only", "chips": 1, "why": "throw-away"})
+        for m in bench["end_to_end"]:
+            if m["name"] == "query_s":
+                m["workloads"].append("tpch_sf1.q6_only")
+        bench["per_layer"].append({
+            "name": "d2h_bytes_per_query", "unit": "bytes",
+            "better": "lower", "source": "program_counter",
+            "layer": "egress", "moves": "query_s",
+            "workloads": ["tpch_sf1.q6_only"]})
+
+    copy.edit_json("BENCHMARK.json", add)
+    result = copy.run(capsys, "tpch_sf1.q6_only")
+    assert result["correct"] is True
+    assert set(result["metrics"]) == {"query_s", "setup_s"}
+    traced = copy.run(capsys, "tpch_sf1.q6_only", trace=1)
+    assert set(traced["metrics"]) == {"d2h_bytes_per_query"}
+    assert traced["metrics"]["d2h_bytes_per_query"]["value"] > 0
+
+
+def test_no_tpu_no_result(capsys):
+    """The gate itself, unlifted: exit 2 and not a line of result."""
+    import conftest
+    harness = conftest.load(os.path.join(conftest.BENCH, "run.py"),
+                            "gated_run")
+    with pytest.raises(SystemExit) as e:
+        harness.main(["--workload", CELLS[0], "--seed", "1", "--seconds",
+                      "1", "--trace", "0"])
+    assert e.value.code == 2
+    out, err = capsys.readouterr()
+    assert out == "" and "refusing to run" in err
