@@ -641,8 +641,7 @@ class DataFrame:
 
     def _execute(self) -> pa.Table:
         from spark_rapids_tpu import lifecycle
-        from spark_rapids_tpu.utils.tracing import query_trace
-        result = plan_query(self.plan, self.session.conf)
+        from spark_rapids_tpu.utils import tracing
         # the query's fault domain (lifecycle.py): deadline + cancel
         # token + resource registry; teardown runs on scope exit
         # whether the drain below succeeds, times out, or fails
@@ -651,16 +650,23 @@ class DataFrame:
             # the process-global span switch from the conf, but only
             # query_trace snapshots and restores the prior state — the
             # switch must be query-scoped on this path
-            # (tests/test_tracing.py)
-            with query_trace(self.session.conf):
+            # (tests/test_tracing.py).  Planning runs inside it, so the
+            # planner's spans (plan.fusion, plan.aqe) are a traced
+            # query's too
+            with tracing.query_trace(self.session.conf):
+                with tracing.phase(tracing.SPAN_QUERY_PLAN, "plan_us"):
+                    result = plan_query(self.plan, self.session.conf)
                 ctx = ExecContext(self.session.conf)
                 batches = []
-                for rb in result.physical.execute_host(ctx):
-                    # root-drain checkpoint: covers plans (or subtrees)
-                    # on the CPU fallback engine, whose operators have
-                    # no device pull boundary of their own
-                    lifecycle.check_cancel()
-                    batches.append(rb)
+                with tracing.phase(tracing.SPAN_QUERY_EXECUTE,
+                                   "execute_us"):
+                    for rb in result.physical.execute_host(ctx):
+                        # root-drain checkpoint: covers plans (or
+                        # subtrees) on the CPU fallback engine, whose
+                        # operators have no device pull boundary of
+                        # their own
+                        lifecycle.check_cancel()
+                        batches.append(rb)
         if qc.sem_wait_ms:
             # per-query admission-wait telemetry, visible through
             # session.last_query_metrics() beside the operator metrics
@@ -671,6 +677,7 @@ class DataFrame:
         # concurrent session could overwrite
         result.query_id = qc.query_id
         result.wall_ms = qc.wall_ms
+        result.programs = qc.programs
         self.session._last_plan_result = result
         if self.session.conf.placement_mode != "tpu":
             # calibration feed (plan/cost.py, docs/placement.md): the
@@ -698,7 +705,9 @@ class DataFrame:
         (train directly on the query output, still in HBM)."""
         from spark_rapids_tpu.exec.basic import DeviceToHostExec
         from spark_rapids_tpu.exec.base import TpuExec
-        result = plan_query(self.plan, self.session.conf)
+        from spark_rapids_tpu.utils import tracing
+        with tracing.phase(tracing.SPAN_QUERY_PLAN, "plan_us"):
+            result = plan_query(self.plan, self.session.conf)
         root = result.physical
         if isinstance(root, DeviceToHostExec):
             root = root.children[0]
@@ -707,18 +716,20 @@ class DataFrame:
                 "plan did not stay on the device engine; device handoff "
                 "needs a fully TPU plan (see explain())")
         from spark_rapids_tpu import lifecycle
-        from spark_rapids_tpu.utils.tracing import query_trace
         with lifecycle.query_scope(self.session.conf) as qc:
             # query_trace scopes the span switch here exactly as in
             # _execute: the handoff path must not leak it either
-            with query_trace(self.session.conf):
+            with tracing.query_trace(self.session.conf):
                 ctx = ExecContext(self.session.conf)
-                batches = list(root.execute_columnar(ctx))
+                with tracing.phase(tracing.SPAN_QUERY_EXECUTE,
+                                   "execute_us"):
+                    batches = list(root.execute_columnar(ctx))
         # retain + stamp only after the drain succeeded (the _execute
         # invariant): a failed handoff must not replace a prior query's
         # valid profile with an unexecuted, unstamped tree
         result.query_id = qc.query_id
         result.wall_ms = qc.wall_ms
+        result.programs = qc.programs
         self.session._last_plan_result = result
         return batches
 

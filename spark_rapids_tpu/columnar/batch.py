@@ -140,7 +140,7 @@ def _compile_batch_gather(sig: tuple, out_len: int):
             outs.append((data, jnp.where(ok, valid, False), chars))
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="concat", name="gather")
     _BATCH_GATHER_CACHE[key] = fn
     return fn
 

@@ -57,7 +57,8 @@ class LazyRows:
 
     def get(self) -> int:
         if self._val is None:
-            self._val = int(jax.device_get(self.dev))
+            from spark_rapids_tpu.columnar.transfer import blocking_read
+            self._val = int(blocking_read(self.dev, "rows"))
         return self._val
 
     def __repr__(self):
@@ -255,18 +256,20 @@ class DeviceColumn:
     def to_numpy(self):
         """Returns (values, validity) trimmed to num_rows. STRING returns an
         object ndarray of python strings."""
+        from spark_rapids_tpu.columnar.transfer import blocking_read
         n = self.num_rows
-        valid = np.asarray(jax.device_get(self.validity))[:n]
+        planes = blocking_read((self.validity, self.data, self.chars),
+                               "column")
+        valid = np.asarray(planes[0])[:n]
         if self.dtype == STRING:
-            chars = np.asarray(jax.device_get(self.chars))[:n]
-            lengths = np.asarray(jax.device_get(self.data))[:n]
+            chars = np.asarray(planes[2])[:n]
+            lengths = np.asarray(planes[1])[:n]
             out = np.empty(n, dtype=object)
             for i in range(n):
                 out[i] = bytes(chars[i, :lengths[i]]).decode("utf-8",
                                                              errors="replace")
             return out, valid
-        data = np.asarray(jax.device_get(self.data))[:n]
-        return data, valid
+        return np.asarray(planes[1])[:n], valid
 
     def __repr__(self):
         return (f"DeviceColumn({self.dtype}, rows={self.num_rows}, "

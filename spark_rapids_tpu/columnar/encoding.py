@@ -268,7 +268,7 @@ def _compile_decode(cap: int, dcap: int, width: int):
             chars = jnp.where(valid[:, None],
                               jnp.take(d_chars, idx, axis=0), 0)
             return lens.astype(jnp.int32), chars
-        return engine_jit(run)
+        return engine_jit(run, family="scan", name="decode")
     return _DECODE_CACHE.get_or_build(key, build)
 
 
@@ -493,7 +493,7 @@ class RleColumn(DeviceColumn):
         def build():
             def run(rv, re_, valid):
                 return _rle_dense(rv, re_, valid, cap, rcap)
-            return engine_jit(run)
+            return engine_jit(run, family="scan", name="rle_dense")
         fn = _PLANE_DECODE_CACHE.get_or_build(
             ("rle", cap, rcap, self.dtype.name), build)
         return fn(self.run_values, self.run_ends, self.validity)
@@ -558,7 +558,7 @@ class DeltaColumn(DeviceColumn):
         def build():
             def run(deltas, base, valid):
                 return _delta_dense(deltas, base, valid, out_dt)
-            return engine_jit(run)
+            return engine_jit(run, family="scan", name="delta_dense")
         fn = _PLANE_DECODE_CACHE.get_or_build(
             ("delta", self._cap, store, self.dtype.name), build)
         return fn(self.deltas, self.base, self.validity)
@@ -621,7 +621,7 @@ class PackedBoolColumn(DeviceColumn):
         def build():
             def run(packed, valid):
                 return _packed_dense(packed, cap)
-            return engine_jit(run)
+            return engine_jit(run, family="scan", name="packed_bool_dense")
         fn = _PLANE_DECODE_CACHE.get_or_build(("packed", cap), build)
         return fn(self.packed, self.validity)
 
@@ -1048,7 +1048,7 @@ def hash_planes(planes: DictPlanes):
             h = _hash_colval(ColVal(lens, valid, chars), STRING)
             return h, valid
 
-        fn = engine_jit(run)
+        fn = engine_jit(run, family="scan", name="hash_planes")
         h, v = fn(planes.lengths, planes.validity, planes.chars)
         return (h, v, None)
 
@@ -1606,7 +1606,7 @@ def _compile_translate(cap: int, tcap: int):
             idx = jnp.clip(codes, 0, tcap - 1)
             out = jnp.where(valid, jnp.take(trans, idx), 0)
             return out.astype(jnp.int32)
-        return engine_jit(run)
+        return engine_jit(run, family="scan", name="translate")
     return _TRANS_CACHE.get_or_build(key, build)
 
 

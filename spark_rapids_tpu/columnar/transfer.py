@@ -29,6 +29,7 @@ maxlen) stats to shrink the big pull.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -47,6 +48,7 @@ from spark_rapids_tpu.columnar.column import rows_traced
 from spark_rapids_tpu.columnar.dtypes import (
     BOOLEAN, DataType, Schema, STRING,
 )
+from spark_rapids_tpu.utils import tracing
 from spark_rapids_tpu.utils.metrics import (
     METRIC_D2H_BYTES, METRIC_D2H_OVERLAP_MS, METRIC_D2H_PULLS,
 )
@@ -106,9 +108,11 @@ def device_pull(tree, metrics=None):
     # cooperative cancellation cannot reach: a wedged pull is bounded
     # by the watchdog and surfaces as a typed QueryHangError
     t0 = time.perf_counter_ns()
-    host = lifecycle.supervise(lambda: jax.device_get(tree),
-                               lifecycle.FAULT_SITE_PIPELINE_HANG)
+    with tracing.trace_range(tracing.SPAN_D2H_PULL):
+        host = lifecycle.supervise(lambda: jax.device_get(tree),
+                                   lifecycle.FAULT_SITE_PIPELINE_HANG)
     pull_us = (time.perf_counter_ns() - t0) // 1000
+    tracing.phase_add("pull_wait_us", pull_us)
     nbytes = sum(getattr(x, "nbytes", 8)
                  for x in jax.tree_util.tree_leaves(host))
     _bump_d2h("pulls", 1)
@@ -122,6 +126,38 @@ def device_pull(tree, metrics=None):
         metrics[METRIC_D2H_PULLS].add(1)
         metrics[METRIC_D2H_BYTES].add(nbytes)
     return host
+
+
+@contextlib.contextmanager
+def _blocked(what: str):
+    """A synchronous wait on the device that is not an egress pull: it
+    carries a span (``d2h.sync:<what>``) and lands in the ``phases``
+    counters (``blocking_reads``, ``pull_wait_us``), so an idle gap under
+    it is named for what it is.  Not a counted link pull: ``d2hPulls`` /
+    ``d2hBytes`` keep meaning what egress moved."""
+    import time
+    t0 = time.perf_counter_ns()
+    try:
+        with tracing.trace_range(f"{tracing.SPAN_D2H_SYNC}:{what}"):
+            yield
+    finally:
+        tracing.phase_add("pull_wait_us",
+                          (time.perf_counter_ns() - t0) // 1000)
+        tracing.phase_add("blocking_reads", 1)
+
+
+def blocking_read(tree, what: str):
+    """The sibling of ``device_pull`` for a small synchronous read that
+    is not egress (a lazy row count, a debug ``to_numpy``)."""
+    with _blocked(what):
+        return jax.device_get(tree)
+
+
+def blocking_wait(arrays, what: str) -> None:
+    """Wait, copying nothing, until ``arrays`` are computed (``with_retry``
+    does, so a launch failure raises inside its scope)."""
+    with _blocked(what):
+        jax.block_until_ready(arrays)
 
 
 def place_on_device(host_array, device):
@@ -250,7 +286,6 @@ def pipelined_h2d(items, upload, runtime, metrics=None, enabled=True):
     """
     import time
     from spark_rapids_tpu.obs import registry as obs
-    from spark_rapids_tpu.utils import tracing
 
     def _timed_upload(item):
         # upload dispatch latency + size distribution: jax.device_put
@@ -361,7 +396,6 @@ def pipelined_d2h(items, dispatch, finish, ctx=None, metrics=None,
                 close()
         return
     import time
-    from spark_rapids_tpu.utils import tracing
     if limiter is None and ctx is not None:
         limiter = ctx.runtime.catalog.egress_staging
 
@@ -500,7 +534,7 @@ def _compile_stats(sig: tuple, dtypes_key: tuple, capacity: int,
                 outs.append(hi)
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="egress", name="stats")
     _STATS_CACHE[key] = fn
     return fn
 
@@ -534,7 +568,8 @@ def bitpack_plane(arr):
     cap = int(arr.shape[0])
 
     def build():
-        return engine_jit(lambda a: _bitpack(a, cap))
+        return engine_jit(lambda a: _bitpack(a, cap),
+                          family="egress", name="bitpack")
     return _BITPACK_CACHE.get_or_build(("pack", cap), build)(arr)
 
 
@@ -621,7 +656,7 @@ def _compile_pack(sigs: tuple, plan_key: tuple, out_cap: int,
             return tuple(outs), total
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="egress", name="pack")
     _PACK_CACHE[key] = fn
     return fn
 

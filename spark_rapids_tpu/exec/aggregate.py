@@ -339,7 +339,7 @@ def _compile_agg(spec: _AggSpec, phase: str, input_sig, capacity: int,
 
         def body(flat_cols, num_rows, _inner=inner, _dec=decoder):
             return _inner(_dec(flat_cols), num_rows)
-    fn = engine_jit(body)
+    fn = engine_jit(body, family="aggregate", name=phase)
     _AGG_CACHE[cache_key] = fn
     return fn
 
@@ -369,7 +369,7 @@ def _compile_evaluate(spec: _AggSpec, input_sig, capacity: int):
             outs.append(ColVal(ev.data, ev.validity & live, ev.chars))
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="aggregate", name="evaluate")
     _EVAL_CACHE[cache_key] = fn
     return fn
 

@@ -131,6 +131,12 @@ class TpuExec(PhysicalPlan):
         # query raises typed within one batch of work (lifecycle.py);
         # a one-global-read no-op when no query is supervised
         from spark_rapids_tpu.lifecycle import check_cancel
+        from spark_rapids_tpu.utils import tracing
+        if tracing.is_enabled():
+            # a program launched while this node's next() runs is this
+            # node's (compile/service.py charges the top of the stack):
+            # deviceTime / deviceDispatches, under the switch only
+            it = tracing.running(self, it)
         for b in it:
             check_cancel()
             rows.add(b.rows_raw)  # no sync for device-resident counts

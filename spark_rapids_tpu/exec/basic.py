@@ -284,6 +284,14 @@ class DeviceToHostExec(CpuExec):
         return "DeviceToHost"
 
     def execute_host(self, ctx: ExecContext) -> Iterator[pa.RecordBatch]:
+        from spark_rapids_tpu.utils import tracing
+        it = self._egress(ctx)
+        # under the trace switch the pack programs launched here are
+        # this node's (deviceTime / deviceDispatches), as an operator's
+        # are in TpuExec._count_output
+        return tracing.running(self, it) if tracing.is_enabled() else it
+
+    def _egress(self, ctx: ExecContext) -> Iterator[pa.RecordBatch]:
         """Result egress runs through the pipelined download loop
         (columnar/transfer.py:pipelined_d2h, docs/d2h_egress.md): group
         k+1's pack kernel and device->host copy are dispatched —

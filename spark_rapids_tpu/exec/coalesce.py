@@ -109,7 +109,7 @@ def _compile_concat(sigs: tuple, out_cap: int):
         return tuple(outs), csum[-1]
 
     from spark_rapids_tpu.compile.service import engine_jit
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="concat", name="concat")
     _CONCAT_CACHE[key] = fn
     return fn
 

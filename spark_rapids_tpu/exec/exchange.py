@@ -146,7 +146,7 @@ def _compile_partitioner(mode: str, keys_key: str, keys: List[Expression],
                    % num_parts).astype(jnp.int32)
         return _pid_to_counts_perm(pid, live, num_parts)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="partition")
     _PARTITION_CACHE[key] = fn
     return fn
 
@@ -281,7 +281,7 @@ def _compile_fused_hash(steps, keys, keys_key: str, input_sig,
     from spark_rapids_tpu.compile import service as compile_service
     from spark_rapids_tpu.exec import stage as _stage
     from spark_rapids_tpu.utils.metrics import METRIC_XLA_COMPILE_MS
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="fused_hash")
     compiled, ms, _store_hit = compile_service.aot_compile(
         fn, _stage.aval_inputs(input_sig, capacity, values, aux_sig),
         store_key=key)
@@ -357,7 +357,7 @@ def _compile_keys_kernel(orders_key: tuple, orders, input_sig,
             keys.extend(colval_sort_keys(cv, expr.dtype, asc, nf))
         return tuple(keys)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="keys")
     _PARTITION_CACHE[key] = fn
     return fn
 
@@ -417,7 +417,7 @@ def _compile_range_assign(nkeys: int, capacity: int, num_parts: int):
         pid = jnp.sum(gt, axis=1).astype(jnp.int32)
         return _pid_to_counts_perm(pid, live, num_parts)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="range_assign")
     _PARTITION_CACHE[key] = fn
     return fn
 

@@ -196,7 +196,7 @@ def _compile_build(keys_key, key_exprs, input_sig, capacity):
             khi = jnp.int64(-1)
         return sorted_h, perm, run_len, max_run, klo, khi
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="build")
     _BUILD_CACHE[k] = fn
     return fn
 
@@ -516,7 +516,7 @@ def _compile_probe(keys_key, key_exprs, bkey_exprs, input_sig, capacity,
         exclusive = inclusive - counts
         return total, lo, inclusive, exclusive
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="probe")
     _PROBE_CACHE[k] = fn
     return fn
 
@@ -605,7 +605,7 @@ def _compile_expand(keys_key, skey_exprs, bkey_exprs, s_sig, b_sig,
         return (keep, i, brow, kept, m_stream, m_build,
                 unmatched, n_unmatched, matched_sel, n_matched)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="expand")
     _EXPAND_CACHE[k] = fn
     return fn
 
@@ -655,7 +655,7 @@ def _compile_fk_join(keys_key, skey_exprs, bkey_exprs, s_sig, b_sig,
                                  s_cap)
         return outs, kept
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="fk")
     _FK_CACHE[k] = fn
     return fn
 
@@ -705,7 +705,7 @@ def _compile_fk_dense_join(keys_key, skey_exprs, bkey_exprs, s_sig,
                                  s_cap)
         return outs, kept
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="fk_dense")
     _FK_DENSE_CACHE[k] = fn
     return fn
 
@@ -778,7 +778,7 @@ def _compile_gather_pairs(s_sig, b_sig, in_cap: int, out_cap: int):
         return _gather_pair_tail(s_flat, b_flat, keep, i, brow, kept_t,
                                  out_cap, in_cap=in_cap)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="gather_pairs")
     _PAIRS_CACHE[key] = fn
     return fn
 
@@ -813,7 +813,7 @@ def _compile_unmatched(cap: int):
             live = jnp.arange(cap) < jnp.asarray(rows, jnp.int32)
             um = live & (m_total == 0)
             return um, jnp.sum(um.astype(jnp.int32))
-        fn = engine_jit(run)
+        fn = engine_jit(run, family="join", name="unmatched")
         _UNMATCHED_CACHE[cap] = fn
     return fn
 
@@ -851,7 +851,7 @@ def _compile_side_gather(sig, in_cap: int, out_cap: int,
                 nulls.append((jnp.zeros(out_cap, np_dt), nvalid, None))
         return tuple(outs), tuple(nulls)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="join", name="side_gather")
     _SIDE_NULLS_CACHE[key] = fn
     return fn
 

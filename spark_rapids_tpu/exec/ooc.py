@@ -491,7 +491,7 @@ def _compile_merge_step(orders_key: tuple, orders, sig, block_cap: int,
                          g[3 * ci + 2]))
         return tuple(outs), emit_n, counts
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="ooc_merge")
     _MERGE_CACHE[key] = fn
     return fn
 

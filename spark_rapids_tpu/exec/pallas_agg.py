@@ -257,7 +257,7 @@ def key_range(grouping, batch, info: Optional[dict] = None,
             hi = jnp.max(jnp.where(m, v, jnp.iinfo(jnp.int64).min))
             return lo, hi, jnp.any(m)
 
-        fn = engine_jit(run)
+        fn = engine_jit(run, family="aggregate", name="pallas_key_range")
         _RANGE_CACHE[sig] = fn
     # one combined pull for all three scalars (each separate host read of
     # a device scalar costs a full link round trip); memoized on buffer
@@ -453,6 +453,6 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                 buf_outs.append(ColVal(out, group_valid, None))
         return n_groups, (key_out,), tuple(buf_outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="aggregate", name="pallas_update")
     _UPDATE_CACHE[cache_key] = fn
     return fn

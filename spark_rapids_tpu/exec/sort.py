@@ -57,7 +57,7 @@ def _compile_sort(orders_key: tuple, orders, input_sig, capacity: int):
                                g[3 * ci + 2]))
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="sort", name="full")
     _SORT_CACHE[key] = fn
     return fn
 
@@ -154,7 +154,7 @@ def _compile_head_take(sig, out_cap: int, limit: int):
             outs.append((data, valid, chars))
         return tuple(outs), keep_n
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="sort", name="head")
     _HEAD_CACHE[key] = fn
     return fn
 

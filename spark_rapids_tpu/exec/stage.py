@@ -266,7 +266,8 @@ class StageKernel:
     def __call__(self, *args):
         if self._compiled is not None:
             try:
-                return self._compiled(*args)
+                # through the Program, so the launch is in the ledger
+                return self._fn.call_compiled(self._compiled, *args)
             except TypeError:
                 # aval mismatch (not a launch failure): retrace via jit
                 pass
@@ -339,8 +340,10 @@ def compile_hoisted_stage(h_steps: Sequence[Step], values,
         # the owning build failed; fall through and build ourselves
     try:
         from spark_rapids_tpu.compile import service as compile_service
+        kinds = {kind for kind, _ in h_steps}
         fn = compile_service.engine_jit(
-            _build_stage_fn(h_steps, capacity))
+            _build_stage_fn(h_steps, capacity), family="stage",
+            name=kinds.pop() if len(kinds) == 1 else "fused")
 
         def payload():
             # the warm pool's replay unit (compile/warm.py): the

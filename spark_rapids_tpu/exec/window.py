@@ -527,7 +527,7 @@ def _compile_window(window_cols, input_sig, cap: int):
             outs.append((data, valid))
         return tuple(outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="window", name="evaluate")
     _WINDOW_CACHE[cache_key] = fn
     return fn
 

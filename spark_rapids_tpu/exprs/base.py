@@ -475,7 +475,7 @@ def compile_projection(exprs: Sequence[Expression], input_sig: tuple,
         return tuple(ColVal(o.data, o.validity & live, o.chars)
                      for o in outs)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="stage", name="projection")
     _PROJECTION_CACHE[key] = fn
     return fn, values
 

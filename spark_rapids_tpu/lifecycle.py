@@ -294,6 +294,11 @@ class QueryContext:
         # the in-flight error ``query_scope`` noted (journal fodder:
         # the query_error event carries type + typedness)
         self.error: Optional[BaseException] = None
+        # the dispatch ledger's rows when this query began, and the
+        # programs it launched since (compile/service.py); both stay
+        # None / empty unless spark.rapids.sql.trace.enabled is on
+        self.programs_mark: Optional[dict] = None
+        self.programs: list = []
         self._finished = False
         self._finish_lock = threading.Lock()
 
@@ -544,6 +549,8 @@ def query_scope(conf=None, timeout_ms: Optional[int] = None):
         # no compile keys leaves another session's store alone
         from spark_rapids_tpu import compile as _compile
         _compile.configure_from_conf(conf)
+        if conf.trace_enabled:
+            qc.programs_mark = _compile.service.ledger_mark()
     else:
         qc = QueryContext(timeout_ms=timeout_ms or 0)
     from spark_rapids_tpu.obs import journal as _journal
@@ -559,19 +566,28 @@ def query_scope(conf=None, timeout_ms: Optional[int] = None):
         raise
     finally:
         _set_current(prev)
+        if qc.programs_mark is not None:
+            # a traced query: wait (off the device's path, on this
+            # thread only) until the watcher has credited every program
+            # it launched, so its profile and the `programs` counters
+            # are complete when the scope closes
+            from spark_rapids_tpu.compile import service as _service
+            _service.drain_watcher()
+            qc.programs = _service.ledger_since(qc.programs_mark)
         qc.finish()
 
 
 def register_resource(close: Callable[[], None], kind: str = "resource",
                       name: str = "",
-                      nbytes: Optional[Callable[[], int]] = None
-                      ) -> _Registration:
+                      nbytes: Optional[Callable[[], int]] = None,
+                      process_wide: bool = False) -> _Registration:
     """Register a close callable with the active query's registry (or
-    the module-global fallback when no query is supervised).  Returns a
+    the module-global fallback when no query is supervised, or when the
+    resource serves every query: ``process_wide``).  Returns a
     handle whose ``release()`` deregisters after the resource closed
     itself on its normal path.  ``nbytes``, when given, reports the
     bytes the resource currently holds (``supervised_bytes``)."""
-    qc = current()
+    qc = None if process_wide else current()
     if qc is not None:
         return qc.register(close, kind, name, nbytes)
     return _GLOBAL_REGISTRY.add(close, kind, name, nbytes)
@@ -591,11 +607,15 @@ def supervised_bytes() -> int:
 
 def register_thread(thread: threading.Thread,
                     stop: Optional[Callable[[], None]] = None,
-                    join_timeout: float = 10.0) -> _Registration:
+                    join_timeout: float = 10.0,
+                    process_wide: bool = False) -> _Registration:
     """Register a (daemon) engine thread: teardown calls ``stop`` (if
     any) and joins with a bounded timeout.  Every ``threading.Thread``
     constructed under spark_rapids_tpu/ must pass through here or a
-    QueryContext registration (tests/lint_robustness.py)."""
+    QueryContext registration (tests/lint_robustness.py).
+    ``process_wide`` registers a thread that outlives the query that
+    happened to start it (the dispatch watcher) with ``shutdown_all``'s
+    registry, not the query's."""
     def close():
         if stop is not None:
             stop()
@@ -604,7 +624,8 @@ def register_thread(thread: threading.Thread,
             if thread.is_alive():
                 log.warning("lifecycle teardown: thread %r still alive "
                             "after %.1fs join", thread.name, join_timeout)
-    return register_resource(close, kind="thread", name=thread.name)
+    return register_resource(close, kind="thread", name=thread.name,
+                             process_wide=process_wide)
 
 
 def cancel_thread_queries(idents, reason: str) -> int:

@@ -17,6 +17,9 @@ Three renderings share the walk:
 * ``render()`` — the ``df.explain(analyze=True)`` text tree: one line
   per operator with rows / batches / wall time / self time (own wall
   minus children's, clamped at zero) and every other non-zero metric;
+  under ``spark.rapids.sql.trace.enabled`` also ``device=`` /
+  ``dispatches=`` (the programs the node launched and the device time
+  the dispatch ledger measured for them) and a ``Programs:`` table;
 * ``to_dict()`` — the same tree as plain dicts for programmatic
   consumers (``session.last_query_profile().to_dict()``);
 * ``legacy_lines()`` — byte-identical to the pre-obs flat
@@ -27,6 +30,8 @@ Three renderings share the walk:
 from __future__ import annotations
 
 from typing import Dict, List, Optional
+
+_NO_NODE = "(no node)"
 
 
 class OperatorProfile:
@@ -69,7 +74,8 @@ class QueryProfile:
     def __init__(self, root: OperatorProfile,
                  query_id: Optional[int] = None,
                  wall_ms: Optional[float] = None,
-                 placement: Optional[List[dict]] = None):
+                 placement: Optional[List[dict]] = None,
+                 programs: Optional[List[dict]] = None):
         self.root = root
         self.query_id = query_id
         self.wall_ms = wall_ms
@@ -77,24 +83,31 @@ class QueryProfile:
         # empty unless spark.rapids.sql.placement.mode != tpu, so the
         # default analyze rendering is unchanged (docs/placement.md)
         self.placement = list(placement or [])
+        # the programs the query launched (compile/service.py
+        # ``ledger_since``): empty unless the query ran under
+        # spark.rapids.sql.trace.enabled, so the default renderings are
+        # unchanged
+        self.programs = list(programs or [])
 
     # -- construction -------------------------------------------------------
 
     @classmethod
     def from_plan(cls, physical, query_id: Optional[int] = None,
                   wall_ms: Optional[float] = None,
-                  placement: Optional[List[dict]] = None
+                  placement: Optional[List[dict]] = None,
+                  programs: Optional[List[dict]] = None
                   ) -> "QueryProfile":
         def walk(node) -> OperatorProfile:
             children = [walk(c) for c in node.children]
             return OperatorProfile(node.node_name, node.describe(),
                                    node.metrics.snapshot(), children)
         return cls(walk(physical), query_id=query_id, wall_ms=wall_ms,
-                   placement=placement)
+                   placement=placement, programs=programs)
 
     # -- renderings ---------------------------------------------------------
 
-    _CORE = ("numOutputRows", "numOutputBatches", "totalTime")
+    _CORE = ("numOutputRows", "numOutputBatches", "totalTime",
+             "deviceTime", "deviceDispatches")
 
     @staticmethod
     def _fmt(name: str, v) -> str:
@@ -122,6 +135,14 @@ class QueryProfile:
             if node.metrics.get("totalTime", 0):
                 parts.append(f"time={node.time_ms:.1f}ms")
                 parts.append(f"self={node.self_time_ms:.1f}ms")
+            if node.metrics.get("deviceDispatches", 0):
+                # time= is host wall time around calls that return
+                # before the device has run; device= is what the
+                # programs this node launched took on the device
+                parts.append(
+                    f"device={node.metrics.get('deviceTime', 0) / 1e6:.1f}ms")
+                parts.append(
+                    f"dispatches={node.metrics['deviceDispatches']}")
             for name, v in sorted(node.metrics.items()):
                 if name in self._CORE or not v:
                     continue
@@ -132,6 +153,17 @@ class QueryProfile:
                 walk(c, depth + 1)
 
         walk(self.root, 0)
+        if self.programs:
+            lines.append("Programs:")
+        for p in self.programs:
+            starved = sum(p["starved_ms"].values())
+            lines.append(
+                f"  {p['program']}: dispatches={p['dispatches']} "
+                f"device={p['device_ms']:.1f}ms"
+                + (f" {_NO_NODE}={p['no_node_ms']:.1f}ms"
+                   if p["no_node_ms"] else "")
+                + (f" starved={starved:.1f}ms" if starved else "")
+                + (f" untimed={p['untimed']}" if p["untimed"] else ""))
         for d in self.placement:
             lines.append(
                 f"Placement: {d.get('fragment')} -> {d.get('engine')} "
@@ -154,6 +186,9 @@ class QueryProfile:
             # only under a non-default placement mode: the default
             # profile dict schema stays byte-identical
             out["placement"] = self.placement
+        if self.programs:
+            # only for a query run under the trace switch
+            out["programs"] = self.programs
         return out
 
     def legacy_lines(self) -> List[str]:

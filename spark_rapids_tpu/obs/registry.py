@@ -181,6 +181,7 @@ def snapshot() -> dict:
     from spark_rapids_tpu.obs import journal
     from spark_rapids_tpu.plan import placement
     from spark_rapids_tpu.server import stats as server_stats
+    from spark_rapids_tpu.utils import tracing
     return {
         "prefetch": prefetch.global_stats(),
         "d2h": transfer.d2h_stats(),
@@ -195,6 +196,13 @@ def snapshot() -> dict:
         # store hit/miss/bytes counters, the cold-vs-store-hit split of
         # measured compile time, warm-pool counters, ladder bounds
         "compile": compile_service.snapshot(),
+        # the dispatch ledger (docs/observability.md, "Programs"):
+        # launches per program family, always counted; device and
+        # host-starved microseconds under spark.rapids.sql.trace.enabled
+        "programs": compile_service.programs_snapshot(),
+        # where a query's wall time went on the host: planning,
+        # executing, and blocked in a device read
+        "phases": tracing.phase_stats(),
         "aqe": aqe.global_stats(),
         # cost-based hybrid placement (docs/placement.md): fragments
         # per engine, AQE runtime demotions, degraded passes, and the

@@ -208,7 +208,8 @@ class DistributedAggregate:
     def _step(self, cap: int):
         fn = self._step_cache.get(cap)
         if fn is None:
-            fn = engine_jit(self._build_step(cap))
+            fn = engine_jit(self._build_step(cap),
+                            family="exchange", name="dist_agg")
             self._step_cache[cap] = fn
         return fn
 

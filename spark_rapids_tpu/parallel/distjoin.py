@@ -309,7 +309,8 @@ class DistributedHashJoin:
             device_step, mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
-            out_specs=P(DATA_AXIS)))
+            out_specs=P(DATA_AXIS)),
+            family="exchange", name="dist_join_count")
         self._count_cache[key] = fn
         return fn
 
@@ -472,7 +473,8 @@ class DistributedHashJoin:
             device_step, mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
-            out_specs=(P(DATA_AXIS), P(DATA_AXIS))))
+            out_specs=(P(DATA_AXIS), P(DATA_AXIS))),
+            family="exchange", name="dist_join")
         self._join_cache[key] = fn
         return fn
 

@@ -165,7 +165,8 @@ class DistributedSort:
         # must never serve bounds computed at another
         fn = self._step_cache.get((cap, pad))
         if fn is None:
-            fn = engine_jit(self._build_step(cap, pad))
+            fn = engine_jit(self._build_step(cap, pad),
+                            family="exchange", name="dist_sort")
             self._step_cache[(cap, pad)] = fn
         return fn
 

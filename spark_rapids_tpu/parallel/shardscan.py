@@ -466,7 +466,7 @@ def _compile_stack(sigs: tuple, cap: int, widths: tuple):
             outs.append((data, valid, chars))
         return tuple(outs), csum[-1].astype(jnp.int32)
 
-    fn = engine_jit(run)
+    fn = engine_jit(run, family="exchange", name="stack")
     _STACK_CACHE[key] = fn
     return fn
 

@@ -89,7 +89,7 @@ def probe_link() -> dict:
         d.block_until_ready()
         out["h2d_mbps"] = round(
             _PROBE_BYTES / (time.perf_counter() - t0) / 1e6, 1)
-        g = engine_jit(lambda x: x + 1)
+        g = engine_jit(lambda x: x + 1, family="scan", name="calibrate")
         y = g(d)
         t0 = time.perf_counter()
         device_pull(y)

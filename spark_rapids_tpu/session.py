@@ -144,7 +144,9 @@ class TpuSession:
                                       query_id=r.query_id,
                                       wall_ms=r.wall_ms,
                                       placement=getattr(
-                                          r, "placement", None))
+                                          r, "placement", None),
+                                      programs=getattr(
+                                          r, "programs", None))
 
     def engine_stats(self) -> dict:
         """The process-wide engine-stats snapshot (docs/observability.md):

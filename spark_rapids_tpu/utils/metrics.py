@@ -12,6 +12,8 @@ import threading
 import time
 from typing import Dict, List
 
+from spark_rapids_tpu.utils import tracing
+
 
 METRIC_NUM_OUTPUT_ROWS = "numOutputRows"
 METRIC_NUM_OUTPUT_BATCHES = "numOutputBatches"
@@ -111,6 +113,12 @@ METRIC_OOC_PARTITIONS = "oocPartitions"
 METRIC_OOC_SPILL_BYTES = "oocSpillBytes"
 METRIC_OOC_RECURSIONS = "oocRecursions"
 METRIC_OOC_FALLBACKS = "oocFallbacks"
+# the dispatch ledger (compile/service.py, docs/observability.md
+# "Programs"): programs this node launched and the device nanoseconds
+# the completion watcher measured for them.  Written only under
+# spark.rapids.sql.trace.enabled, so untraced profiles are unchanged
+METRIC_DEVICE_TIME = "deviceTime"
+METRIC_DEVICE_DISPATCHES = "deviceDispatches"
 
 
 def _collect_known_metrics() -> frozenset:
@@ -221,6 +229,7 @@ class MetricSet:
             self._check(n)
         self._metrics: Dict[str, Metric] = {n: Metric(n) for n in (*base, *names)}
         self.owner = owner
+        self._span_names: Dict[str, str] = {}
 
     def _check(self, name: str) -> None:
         if self._adhoc or name in KNOWN_METRICS:
@@ -241,7 +250,16 @@ class MetricSet:
         return self._metrics[name]
 
     def timed(self, name: str):
-        return _Timer(self[name], self.owner)
+        return _Timer(self[name], self)
+
+    def span_name(self, metric: str) -> str:
+        """``<owner>.<metric>``, built once per metric (and only when a
+        traced timer first asks)."""
+        name = self._span_names.get(metric)
+        if name is None:
+            name = f"{self.owner}.{metric}" if self.owner else metric
+            self._span_names[metric] = name
+        return name
 
     def items(self):
         return self._metrics.items()
@@ -251,29 +269,30 @@ class MetricSet:
 
 
 class _Timer:
-    __slots__ = ("_metric", "_start", "_ann", "_owner")
+    __slots__ = ("_metric", "_start", "_ann", "_set")
 
-    def __init__(self, metric: Metric, owner: str = ""):
+    def __init__(self, metric: Metric, owner: MetricSet):
         self._metric = metric
-        self._owner = owner
+        self._set = owner
         self._start = 0
+        self._ann = None
 
     def __enter__(self):
         self._start = time.perf_counter_ns()
         # named profiler range so timed operator sections show in Xprof
         # (reference NvtxWithMetrics.scala:27 fusing NVTX + SQLMetric);
         # gated on the session trace switch so untraced runs pay one check
-        from spark_rapids_tpu.utils import tracing
-        name = (f"{self._owner}.{self._metric.name}" if self._owner
-                else self._metric.name)
-        self._ann = tracing.annotation(name)
-        if self._ann is not None:
-            self._ann.__enter__()
+        if tracing.is_enabled():
+            self._ann = tracing.annotation(
+                self._set.span_name(self._metric.name))
+            if self._ann is not None:
+                self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         if self._ann is not None:
             self._ann.__exit__(*exc)
+            self._ann = None
         self._metric.add(time.perf_counter_ns() - self._start)
         return False
 

@@ -101,8 +101,10 @@ def _sync_result(obj) -> None:
     arrays = []
     _collect_arrays(obj, arrays)
     if arrays:
-        import jax
-        jax.block_until_ready(arrays)
+        # the host's longest wait on the device in most queries: spanned
+        # (d2h.sync:retry) and counted like every other blocking read
+        from spark_rapids_tpu.columnar.transfer import blocking_wait
+        blocking_wait(arrays, "retry")
 
 
 def with_retry(fn: Callable, batch, ctx=None,

@@ -16,6 +16,7 @@ no profiler is attached).
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 
 try:
@@ -45,6 +46,83 @@ SPAN_PLAN_FUSION = "plan.fusion"
 # adaptive replanning passes (docs/adaptive.md): one span per
 # stats-driven replan of the not-yet-executed plan remainder
 SPAN_PLAN_AQE = "plan.aqe"
+# the two phases of a query (api.py): planning, then the root drain with
+# the dispatch watcher's drain at its end; and the two blocking device
+# reads (columnar/transfer.py): an egress pull, and a small synchronous
+# read outside egress (``d2h.sync:<what>``: a row count, a debug column)
+SPAN_QUERY_PLAN = "query.plan"
+SPAN_QUERY_EXECUTE = "query.execute"
+SPAN_D2H_PULL = "d2h.pull"
+SPAN_D2H_SYNC = "d2h.sync"
+
+# Always-on phase counters (the ``phases`` group of ``engine_stats()``,
+# docs/observability.md): microseconds a query spent planning and
+# executing, microseconds a thread sat in a blocking device read, and
+# the number of synchronous reads outside ``device_pull``.  Bumped once
+# per query or per pull, never per batch.
+_PHASES_LOCK = threading.Lock()
+_PHASES = {"plan_us": 0, "execute_us": 0, "pull_wait_us": 0,
+           "blocking_reads": 0}
+
+# Per-thread stacks kept under the switch only: the names of the open
+# spans (``trace_range`` / ``MetricSet.timed``) and the plan nodes whose
+# ``next()`` is running (exec/base.py ``_count_output``).  The dispatch
+# ledger (compile/service.py) reads the top of each when a program is
+# launched.
+_TLS = threading.local()
+
+
+def _stack(which: str) -> list:
+    st = getattr(_TLS, which, None)
+    if st is None:
+        st = []
+        setattr(_TLS, which, st)
+    return st
+
+
+def current_span() -> str:
+    """Innermost open span of this thread ('' when none)."""
+    st = getattr(_TLS, "spans", None)
+    return st[-1] if st else ""
+
+
+def current_node():
+    """The plan node whose ``next()`` runs on this thread, or None."""
+    st = getattr(_TLS, "nodes", None)
+    return st[-1] if st else None
+
+
+def running(node, it):
+    """Iterate ``it`` with ``node`` on this thread's node stack while
+    each ``next()`` runs, so a program launched inside it is charged to
+    ``node``.  Callers wrap only when the switch is on."""
+    it = iter(it)
+    while True:
+        nodes = _stack("nodes")  # of the thread that runs this next()
+        nodes.append(node)
+        try:
+            item = next(it)
+        except StopIteration:
+            return
+        finally:
+            nodes.pop()
+        yield item
+
+
+def phase_add(counter: str, amount: int) -> None:
+    with _PHASES_LOCK:
+        _PHASES[counter] += amount
+
+
+def phase_stats() -> dict:
+    with _PHASES_LOCK:
+        return dict(_PHASES)
+
+
+def reset_phase_stats() -> None:
+    with _PHASES_LOCK:
+        for k in _PHASES:
+            _PHASES[k] = 0
 
 
 def set_enabled(on: bool) -> None:
@@ -58,11 +136,32 @@ def is_enabled() -> bool:
     return _enabled
 
 
+class _Span:
+    """One open span: the profiler annotation plus an entry on the
+    thread's span stack."""
+
+    __slots__ = ("name", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._ann = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        _stack("spans").append(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        _stack("spans").pop()
+        return False
+
+
 def annotation(name: str):
-    """A profiler annotation for ``name`` if tracing is on, else None.
-    Callers hold it across a timed section (metrics._Timer)."""
+    """A span for ``name`` if tracing is on, else None.  Callers hold it
+    across a timed section (metrics._Timer)."""
     if _enabled and _HAVE_JAX:
-        return jax.profiler.TraceAnnotation(name)
+        return _Span(name)
     return None
 
 
@@ -81,6 +180,18 @@ def trace_range(name: str, metric=None):
             ann.__exit__(None, None, None)
         if metric is not None:
             metric.add(time.perf_counter_ns() - start)
+
+
+@contextlib.contextmanager
+def phase(span: str, counter: str):
+    """``trace_range(span)`` whose elapsed microseconds also land in the
+    always-on phase counter ``counter``."""
+    start = time.perf_counter_ns()
+    try:
+        with trace_range(span):
+            yield
+    finally:
+        phase_add(counter, (time.perf_counter_ns() - start) // 1000)
 
 
 @contextlib.contextmanager

@@ -191,6 +191,18 @@ def _reset_obs():
 
 
 @pytest.fixture(autouse=True)
+def _stop_dispatch_watcher(_lifecycle_leak_audit):
+    # the dispatch ledger's completion watcher (compile/service.py) is
+    # one thread per process, started by the first traced program launch
+    # and joined by session.stop(); a test that traces a query and keeps
+    # its session must not leave it for the leak audit (set up first,
+    # so it looks after this has run)
+    yield
+    from spark_rapids_tpu.compile import service
+    service.stop_watcher()
+
+
+@pytest.fixture(autouse=True)
 def _reset_ooc():
     # the out-of-core counters are process-global (docs/out_of_core.md):
     # partitions one test spilled must not inflate another's assertions

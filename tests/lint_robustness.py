@@ -870,7 +870,9 @@ def test_dictionary_materialization_confined_to_encoding(path):
 #
 # 14. **No raw ``jax.jit`` outside compile/** (use
 #     ``compile.service.engine_jit``), and no ``from jax import jit``
-#     alias smuggling one in.
+#     alias smuggling one in; and every ``engine_jit`` call names its
+#     program: a literal ``family`` of ``compile.service.FAMILIES`` and a
+#     ``name`` (docs/observability.md, "Programs").
 #
 # 15. **No AOT ``.lower(...).compile(...)`` chains outside compile/**
 #     (use ``compile.service.aot_compile``, which measures, classifies
@@ -922,6 +924,51 @@ def test_xla_compiles_confined_to_compile_service():
         "(compile.service.engine_jit / aot_compile) so the persistent "
         "store, the compile-time split, and the compile.store fault "
         f"site cover it (docs/compile_cache.md): {offenders}")
+
+
+def _engine_jit_calls():
+    """(rel_path, call) for every ``engine_jit(...)`` call in the package
+    outside compile/."""
+    for path in _compile_banned_sources():
+        rel = os.path.relpath(path, _REPO)
+        for node in ast.walk(_parsed(path)):
+            if isinstance(node, ast.Call) and _is_call_named(
+                    node, "engine_jit"):
+                yield rel, node
+
+
+def test_every_engine_jit_names_its_family_and_program():
+    """Rule 14, second half (docs/observability.md, "Programs"): every
+    program is built with a ``family`` from the fixed tuple and a
+    ``[a-z0-9_]+`` ``name``, so no XLA module is ``jit_run`` and every
+    launch lands in a row of the dispatch ledger.  A name computed at
+    the call site (the aggregate's phase, the stage's kind) is checked
+    by ``engine_jit`` itself when the program is built."""
+    import re
+    from spark_rapids_tpu.compile.service import FAMILIES
+    calls = list(_engine_jit_calls())
+    assert len(calls) >= 30, "the engine_jit call sites were not found"
+    offenders = []
+    for rel, node in calls:
+        kw = {k.arg: k.value for k in node.keywords}
+        family, name = kw.get("family"), kw.get("name")
+        where = f"{rel}:{node.lineno}"
+        if not (isinstance(family, ast.Constant)
+                and family.value in FAMILIES):
+            offenders.append(f"{where} (family must be a literal of "
+                             f"{FAMILIES})")
+        if name is None:
+            offenders.append(f"{where} (no name=)")
+        elif isinstance(name, ast.Constant) and not (
+                isinstance(name.value, str)
+                and re.fullmatch(r"[a-z0-9_]+", name.value)):
+            offenders.append(f"{where} (name {name.value!r} is not "
+                             "[a-z0-9_]+)")
+    assert not offenders, (
+        "engine_jit without a stable program name — every program "
+        "needs family= and name= (compile/service.py) so profiles and "
+        f"engine_stats()['programs'] can say who owns the device: "
+        f"{offenders}")
 
 
 def test_native_transport_has_receive_timeouts():

@@ -115,6 +115,20 @@ def test_snapshot_unifies_every_stats_group():
     assert "queries" in snap["lifecycle"] or snap["lifecycle"]
 
 
+def test_snapshot_has_the_programs_and_phases_groups_flat_and_numeric():
+    from spark_rapids_tpu.compile import service
+    snap = registry.snapshot()
+    assert set(snap["phases"]) == {"plan_us", "execute_us",
+                                   "pull_wait_us", "blocking_reads"}
+    assert {"dispatches", "device_us", "starved_us", "untimed"} \
+        <= set(snap["programs"])
+    for fam in service.FAMILIES:
+        assert f"{fam}_dispatches" in snap["programs"]
+        assert f"{fam}_device_us" in snap["programs"]
+    for group in ("programs", "phases"):
+        assert all(type(v) is int for v in snap[group].values())
+
+
 def test_engine_stats_is_the_registry_snapshot():
     s = tpu_session()
     stats = s.engine_stats()
@@ -127,6 +141,12 @@ def test_prometheus_text_renders_gauges_and_summaries():
     assert "# TYPE spark_rapids_tpu_d2h_pulls gauge" in txt
     assert 'spark_rapids_tpu_test_prom_us{quantile="0.5"}' in txt
     assert "spark_rapids_tpu_test_prom_us_count" in txt
+    # the dispatch ledger's two groups export unchanged: flat gauges
+    assert "# TYPE spark_rapids_tpu_programs_dispatches gauge" in txt
+    assert "spark_rapids_tpu_programs_aggregate_device_us " in txt
+    assert "spark_rapids_tpu_programs_starved_us " in txt
+    assert "# TYPE spark_rapids_tpu_phases_plan_us gauge" in txt
+    assert "spark_rapids_tpu_phases_blocking_reads " in txt
     # every non-comment line is "name{labels}? value"
     for line in txt.strip().splitlines():
         if line.startswith("#"):
@@ -418,6 +438,40 @@ def test_last_query_metrics_is_byte_identical_to_pre_obs_walk():
 
     walk(r.physical, 0)
     assert s.last_query_metrics() == "\n".join(lines)
+
+
+def test_default_profile_dict_schema_is_unchanged_with_the_switch_off():
+    """The dispatch ledger adds `programs`, `deviceTime` and
+    `deviceDispatches` only to a query run under the trace switch."""
+    s = tpu_session()
+    _df(s).filter(F.col("v") > 0).group_by("k").agg(
+        F.count(F.col("v")).alias("c")).collect()
+    d = s.last_query_profile().to_dict()
+    assert set(d) == {"query_id", "wall_ms", "plan"}
+
+    def walk(node):
+        assert set(node) == {"name", "describe", "rows", "batches",
+                             "time_ms", "self_time_ms", "metrics",
+                             "children"}
+        assert not {"deviceTime", "deviceDispatches"} & set(node["metrics"])
+        for c in node["children"]:
+            walk(c)
+    walk(d["plan"])
+    txt = s.last_query_profile().render()
+    assert "device=" not in txt and "Programs:" not in txt
+
+
+def test_traced_profile_adds_device_time_beside_host_time():
+    s = tpu_session({"spark.rapids.sql.trace.enabled": "true"})
+    txt = _df(s).filter(F.col("v") > 0).group_by("k").agg(
+        F.count(F.col("v")).alias("c")).explain(analyze=True)
+    d = s.last_query_profile().to_dict()
+    assert set(d) == {"query_id", "wall_ms", "plan", "programs"}
+    assert any(" device=" in ln and " dispatches=" in ln
+               for ln in txt.splitlines())
+    assert "deviceTime=" not in txt  # printed once, as device=
+    # the legacy flat string prints the two metrics like any other
+    assert "deviceDispatches=" in s.last_query_metrics()
 
 
 def test_query_wall_histogram_records():

@@ -119,3 +119,132 @@ def test_query_trace_restores_on_exception():
             assert tracing.is_enabled()
             raise RuntimeError("query failed mid-trace")
     assert not tracing.is_enabled()
+
+
+# -- the span and node stacks the dispatch ledger reads ---------------------
+
+def test_span_stack_names_the_innermost_open_span():
+    tracing.set_enabled(True)
+    assert tracing.current_span() == ""
+    ms = MetricSet(owner="TestOp")
+    with tracing.trace_range("outer.section"):
+        assert tracing.current_span() == "outer.section"
+        with ms.timed("totalTime"):
+            assert tracing.current_span() == "TestOp.totalTime"
+        assert tracing.current_span() == "outer.section"
+    assert tracing.current_span() == ""
+
+
+def test_span_stack_is_not_kept_with_the_switch_off():
+    tracing.set_enabled(False)
+    with tracing.trace_range("outer.section"):
+        assert tracing.current_span() == ""
+
+
+def test_span_stack_unwinds_on_exception():
+    tracing.set_enabled(True)
+    with pytest.raises(RuntimeError):
+        with tracing.trace_range("failing.section"):
+            raise RuntimeError("boom")
+    assert tracing.current_span() == ""
+
+
+def test_running_puts_the_node_on_the_stack_only_while_next_runs():
+    seen = []
+
+    def child():
+        seen.append(tracing.current_node())
+        yield 1
+        seen.append(tracing.current_node())
+        yield 2
+
+    def parent():
+        for item in tracing.running("child", child()):
+            seen.append(tracing.current_node())
+            yield item
+
+    assert tracing.current_node() is None
+    for _ in tracing.running("parent", parent()):
+        seen.append(tracing.current_node())  # the consumer's own code
+    # inside child's next(): child; back in parent's body: parent; in
+    # the consumer: nobody
+    assert seen == ["child", "parent", None, "child", "parent", None]
+    assert tracing.current_node() is None
+
+
+def test_timer_builds_its_span_name_once_and_only_under_the_switch():
+    ms = MetricSet(owner="TestOp")
+    tracing.set_enabled(False)
+    with ms.timed("totalTime"):
+        pass
+    assert ms._span_names == {}
+    tracing.set_enabled(True)
+    with ms.timed("totalTime"):
+        pass
+    first = ms._span_names["totalTime"]
+    assert first == "TestOp.totalTime"
+    with ms.timed("totalTime"):
+        pass
+    assert ms._span_names["totalTime"] is first
+    assert MetricSet().span_name("totalTime") == "totalTime"
+
+
+def _spans_of(monkeypatch):
+    seen = []
+    enter = tracing._Span.__enter__
+
+    def recording(self):
+        seen.append(self.name)
+        return enter(self)
+
+    monkeypatch.setattr(tracing._Span, "__enter__", recording)
+    return seen
+
+
+def _small_query(s):
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu import functions as F
+    df = s.create_dataframe(pa.table({
+        "a": pa.array(np.arange(64), pa.int64()),
+        "b": pa.array(np.arange(64) * 0.5)}))
+    return df.filter(F.col("a") > 3).select(
+        (F.col("b") * 2).alias("c"), F.col("a"))
+
+
+@pytest.mark.parametrize("span", [
+    tracing.SPAN_QUERY_PLAN, tracing.SPAN_PLAN_FUSION,
+    tracing.SPAN_QUERY_EXECUTE, tracing.SPAN_D2H_PULL])
+def test_traced_query_emits_the_phase_spans(monkeypatch, span):
+    """Planning runs inside the query's switch scope, so the planner's
+    own spans are a traced query's too."""
+    from tests.compare import tpu_session
+    seen = _spans_of(monkeypatch)
+    tracing.set_enabled(False)
+    _small_query(tpu_session(
+        {"spark.rapids.sql.trace.enabled": "true"})).collect()
+    assert span in seen
+    assert seen.index(tracing.SPAN_QUERY_PLAN) \
+        < seen.index(tracing.SPAN_QUERY_EXECUTE)
+    assert not tracing.is_enabled()
+
+
+def test_untraced_query_emits_no_span(monkeypatch):
+    from tests.compare import tpu_session
+    seen = _spans_of(monkeypatch)
+    tracing.set_enabled(False)
+    _small_query(tpu_session()).collect()
+    assert seen == []
+
+
+def test_blocking_read_is_spanned_and_counted(monkeypatch):
+    import jax.numpy as jnp
+    from spark_rapids_tpu.columnar.column import LazyRows
+    seen = _spans_of(monkeypatch)
+    tracing.set_enabled(True)
+    before = tracing.phase_stats()
+    assert LazyRows(jnp.int32(7), 16).get() == 7
+    after = tracing.phase_stats()
+    assert "d2h.sync:rows" in seen
+    assert after["blocking_reads"] == before["blocking_reads"] + 1
+    assert after["pull_wait_us"] >= before["pull_wait_us"]
