@@ -435,9 +435,10 @@ def assert_no_fallback(sess, data: dict, device: dict) -> None:
     emit({"phase": "no_fallback", **counters, "to_jax_devices": where,
           "pallas_agg": {"batches_per_run": agg["pallas_agg_batches"],
                          "programs": agg["custom_call"],
-                         "query": "sql_year_revenue (q1 groups by two "
-                                  "string keys; the dense-slot kernel "
-                                  "takes one integer key)",
+                         "query": "sql_year_revenue (one integer key: "
+                                  "the route that probes its range; q1 "
+                                  "and q6 take the kernel too, their "
+                                  "domain known without a pull)",
                          "why_not_every_batch":
                              "join outputs are fresh buffers: after two "
                              "range-probe pulls the miss gate sends the "

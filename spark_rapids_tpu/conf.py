@@ -198,11 +198,14 @@ MAX_READER_BATCH_SIZE_BYTES = register(
 
 PALLAS_AGG = register(
     "spark.rapids.sql.tpu.pallas.agg.enabled", True,
-    "Use the Pallas one-hot-reduction kernel for single-integer-key "
-    "aggregations whose key domain fits 1024 dense slots (sort-free "
-    "update phase).  Which specs it takes is a static rule "
-    "(exec/pallas_agg.py:supports — 32-bit planes only on the chip); "
-    "every other aggregation runs the sorted-segment kernel.",
+    "Use the Pallas one-hot-reduction kernel (sort-free update phase, "
+    "partials as long as the key domain) for aggregations whose key "
+    "domain the host knows and fits 1024 dense slots: every group key "
+    "a dictionary code (the slot is the mixed radix of the codes, "
+    "nothing pulled), no group key at all, or one integer key whose "
+    "range a memoized probe pulls.  Which specs it takes is a static "
+    "rule (exec/pallas_agg.py:supports — 32-bit planes only on the "
+    "chip); every other aggregation runs the sorted-segment kernel.",
     bool)
 
 RANGE_SAMPLE_SIZE = register(

@@ -17,10 +17,18 @@ TPU design — sort-based segmented reduction in ONE fused kernel per batch:
   5. group representatives gather the key columns back.
 The merge phase runs the same kernel shape over concatenated partials with
 the merge ops.  All shapes static; only the final group count syncs to host.
+
+An update whose key domain the host already knows (every key a dictionary
+code, no keys at all, or one integer key of small probed range) skips the
+sort: ``_try_dense_update`` reduces it by direct address over K slots
+(exec/pallas_agg.py) and its partial is K slots long, so the concat, the
+merge and everything downstream run at the domain's capacity, not the
+input's.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -134,7 +142,7 @@ from spark_rapids_tpu.utils.kernel_cache import KernelCache
 _AGG_CACHE = KernelCache("aggregate", 256)
 
 # agg-spec -> consecutive pallas range-probe memo misses (see
-# _try_pallas_update: probing costs a host sync, so specs whose inputs
+# _probe_key_range: probing costs a host sync, so specs whose inputs
 # are fresh every run stop probing after 2 misses)
 _PALLAS_FRESH_MISSES: dict = {}
 
@@ -475,11 +483,6 @@ class TpuHashAggregateExec(TpuExec):
                    conf=None):
         from spark_rapids_tpu.columnar.column import LazyRows
         with self.metrics.timed("computeAggTime"):
-            if phase == "update" and conf is not None and \
-                    batch.rows_bound > 0:
-                out = self._try_pallas_update(batch, conf)
-                if out is not None:
-                    return out
             spec, vbatch, wrap = self._agg_view(phase, batch)
             # plane-compressed inputs (rle/delta/packed bool) feed the
             # agg kernel their compressed planes and decode INSIDE it —
@@ -491,31 +494,76 @@ class TpuHashAggregateExec(TpuExec):
             else:
                 flat = _flatten_batch(vbatch)
                 sig, decoder = _batch_signature(vbatch), None
-            fn = _compile_agg(spec, phase, sig, vbatch.capacity,
-                              decoder)
-            n_groups, key_outs, buf_outs = fn(flat, vbatch.rows_traced)
-            # n_groups <= num_rows, except empty-input global agg -> 1
-            n = LazyRows(n_groups,
-                         max(1, min(batch.rows_bound, batch.capacity)))
+            dense = None
+            if phase == "update" and conf is not None:
+                dense = self._try_dense_update(spec, vbatch, wrap, conf,
+                                               flat, sig, decoder)
+            if dense is not None:
+                # the partial has the shape of its key domain: every
+                # possible key combination owns a slot, so the bound
+                # cannot cut a group off
+                (n_groups, key_outs, buf_outs), bound = dense
+            else:
+                fn = _compile_agg(spec, phase, sig, vbatch.capacity,
+                                  decoder)
+                n_groups, key_outs, buf_outs = fn(flat,
+                                                  vbatch.rows_traced)
+                # n_groups <= num_rows, except empty-input global agg
+                bound = max(1, min(batch.rows_bound, batch.capacity))
             return _colvals_to_batch(
                 list(key_outs) + list(buf_outs), self._buffer_dtypes(),
-                n, wrap=wrap)
+                LazyRows(n_groups, bound), wrap=wrap)
 
-    def _try_pallas_update(self, batch: ColumnarBatch, conf):
-        """Low-cardinality fast path: sort-free Pallas one-hot reduction
-        when the single integer key's observed domain is small (see
-        exec/pallas_agg.py); None -> take the sorted-segment kernel.
-        The first batch whose domain does not fit disables the probe for
-        this exec so high-cardinality aggs don't pay a blocking range
-        check (kernel + host sync) per batch."""
+    def _try_dense_update(self, spec: _AggSpec, vbatch: ColumnarBatch,
+                          wrap, conf, flat, sig, decoder):
+        """Sort-free update over a key domain the host knows (see
+        exec/pallas_agg.py): ``((n_groups, keys, buffers), bound)`` with
+        ``bound`` the exact domain size, or None -> take the
+        sorted-segment kernel.  ``spec``/``vbatch``/``wrap`` are the code
+        view of the batch (``_agg_view``).
+
+        The domain is known without a pull when every key is a
+        dictionary-code view (radix ``dict.size + 1``, digit 0 the null
+        key) or there are no keys; one bare integer key learns its range
+        from a memoized probe instead, and the first batch whose range
+        does not fit disables that probe for this exec so
+        high-cardinality aggs don't pay a blocking range check (kernel +
+        host sync) per batch."""
         from spark_rapids_tpu.exec import pallas_agg as pag
-        if getattr(self, "_pallas_off", False):
+        if not (pag.enabled(conf) and pag.supports(spec)):
             return None
-        if batch.capacity > pag.max_capacity(self.spec):
+        if vbatch.capacity > pag.max_capacity(spec):
             # per-spec exactness bound (int64-sum limb decomposition)
             return None
-        if not (pag.enabled(conf) and pag.supports(self.spec)):
-            self._pallas_off = True
+        coded = wrap or {}
+        nk = len(spec.groupings)
+        if len(coded) == nk:
+            radices = [coded[i].size + 1 for i in range(nk)]
+            bases = [0] * nk
+            bound = math.prod(radices)
+            if bound > pag.MAX_K:
+                return None
+        elif nk == 1 and not coded and vbatch.rows_bound > 0:
+            rng = self._probe_key_range(spec, vbatch, flat, sig, decoder)
+            if rng is None:
+                return None
+            lo, hi = rng
+            radices, bases = [pag.range_radix(lo, hi)], [lo]
+            bound = min(vbatch.rows_bound, hi - lo + 2)
+        else:
+            return None
+        fn = pag.make_update(spec, sig, vbatch.capacity, radices,
+                             decoder=decoder)
+        out = fn(flat, vbatch.rows_traced, np.asarray(bases, np.int64))
+        self.metrics["pallasAggBatches"].add(1)
+        return out, bound
+
+    def _probe_key_range(self, spec: _AggSpec, vbatch: ColumnarBatch,
+                         flat, sig, decoder):
+        """(lo, hi) of the one integer key when it fits the dense
+        kernel, else None."""
+        from spark_rapids_tpu.exec import pallas_agg as pag
+        if getattr(self, "_pallas_off", False):
             return None
         # The range probe is a host sync (~100ms+ over a remote link).
         # Re-runs over device-cached scans hit the buffer memo for free,
@@ -523,24 +571,14 @@ class TpuHashAggregateExec(TpuExec):
         # pay the sync each time — after 2 fresh-buffer misses for this
         # agg spec, the probe becomes memo-only (a later memo hit still
         # uses Pallas and resets the counter; only the PULL is gated).
-        spec_key = self.spec.key()
+        spec_key = spec.key()
         # at large capacities the sorted-segment fallback costs seconds
         # (bitonic at 2^22+), so the ~100ms probe sync is always worth
         # paying; the miss gate only governs small fast batches
         allow_pull = _PALLAS_FRESH_MISSES.get(spec_key, 0) < 2 or \
-            batch.capacity >= (1 << 21)
-        # plane-compressed inputs (rle/delta/packed bool) ride their
-        # compressed planes into BOTH the range probe and the update
-        # kernel; the decode traces inside each jitted body
-        from spark_rapids_tpu.columnar import encoding as _enc
-        pv = _enc.plane_view(batch, count=False)
-        if pv is not None:
-            flat, sig, decoder = pv
-        else:
-            flat = _flatten_batch(batch)
-            sig, decoder = _batch_signature(batch), None
+            vbatch.capacity >= (1 << 21)
         info: dict = {}
-        rng = pag.key_range(self.spec.groupings[0], batch, info=info,
+        rng = pag.key_range(spec.groupings[0], vbatch, info=info,
                             allow_pull=allow_pull, flat=flat, sig=sig,
                             decoder=decoder)
         if info.get("hit"):
@@ -548,24 +586,10 @@ class TpuHashAggregateExec(TpuExec):
         elif info.get("pulled"):
             _PALLAS_FRESH_MISSES[spec_key] = \
                 _PALLAS_FRESH_MISSES.get(spec_key, 0) + 1
-        if rng is None:
-            return None
-        if not pag.fits(*rng):
+        if rng is not None and not pag.fits(*rng):
             self._pallas_off = True
             return None
-        from spark_rapids_tpu.columnar.column import LazyRows
-        lo, hi = rng
-        fn = pag.make_update(self.spec, sig, batch.capacity, lo, hi,
-                             decoder=decoder)
-        if decoder is not None:
-            _enc.count_fused_decodes(batch)
-        n_groups, key_outs, buf_outs = fn(
-            flat, batch.rows_traced, jnp.int64(lo))
-        self.metrics["pallasAggBatches"].add(1)
-        return _colvals_to_batch(
-            list(key_outs) + list(buf_outs), self._buffer_dtypes(),
-            LazyRows(n_groups, max(1, min(batch.rows_bound,
-                                          batch.capacity))))
+        return rng
 
     def execute_columnar(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         def gen():

@@ -2,10 +2,15 @@
 
 Reference scope: the per-batch ``update`` aggregation the sorted-segment
 kernel in exec/aggregate.py implements (cuDF ``Table.groupBy().aggregate``
-analog, aggregate.scala:731).  For the common BI shape — a single integer
-group key with a small value domain (date/flag/status keys, a year
-bucket) — sorting every batch by its keys is wasted work: this kernel
-maps keys to dense slots (key - lo, slot 0 reserved for nulls) and streams
+analog, aggregate.scala:731).  For the common BI shape — group keys whose
+joint value domain is small and known on the host — sorting every batch
+by its keys is wasted work.  The domain is known two ways: every key is
+a dictionary-code view (``encoding.agg_code_view``: radix ``dict.size +
+1`` per key, nothing pulled; no keys at all is the one-slot case), or
+one bare integer key whose range a memoized probe pulled (date/flag/
+status keys, a year bucket).  Either way each key is one DIGIT
+(key - base + 1, digit 0 reserved for null), the slot is the mixed radix
+of the digits (first key most significant), and this kernel streams
 lane-dense row blocks through a VMEM one-hot accumulation:
 
     rows live as (capacity/128, 128): one sublane row = 128 input rows
@@ -16,8 +21,9 @@ TPU grid steps run sequentially, so the (K, 128) accumulators stay
 resident in the output blocks across steps (the standard Pallas
 accumulation pattern); the final 128-lane fold is one XLA reduction
 outside the kernel.  The (capacity, K) one-hot never exists in HBM, and
-no sort runs at all.  Slot order (null, lo, lo+1, ...) equals the sorted
-kernel's group order (nulls-first ascending); counts/min/max/integer sums
+no sort runs at all.  Slot order (per digit: null, base, base+1, ...)
+equals the sorted kernel's group order (nulls-first ascending, first key
+most significant); counts/min/max/integer sums
 are bit-identical to the sort path, float sums accumulate in block order
 (the variableFloatAgg caveat, same as the reference's GPU float aggs).
 
@@ -31,16 +37,16 @@ in interpret mode (compile/service.py:pallas_interpret).
 
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+import math
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu.compile.service import engine_jit
-from spark_rapids_tpu.columnar.dtypes import (
-    BOOLEAN, DATE, STRING, TIMESTAMP, DataType,
-)
+from spark_rapids_tpu.columnar.column import bucket_capacity
+from spark_rapids_tpu.columnar.dtypes import BOOLEAN, STRING, TIMESTAMP
 from spark_rapids_tpu.exprs.base import (
     ColVal, EvalContext, _batch_signature, _flatten_batch,
 )
@@ -84,15 +90,14 @@ def max_capacity(spec) -> int:
 
 
 def supports(spec) -> bool:
-    """Single integer-like group key; Count/Sum/Min/Max/Average over
-    non-string inputs (their buffers all reduce with add/min/max).
-    Static in the spec and the device float policy: every plane a spec
-    admitted here emits is one the kernel compiles for."""
-    if len(spec.groupings) != 1:
-        return False
-    kdt = spec.groupings[0].dtype
-    if kdt == STRING or kdt.is_floating:
-        return False
+    """Every group key integer-like (a digit; whether each has a
+    host-known radix is the caller's to establish); Count/Sum/Min/Max/
+    Average over non-string inputs (their buffers all reduce with
+    add/min/max).  Static in the spec and the device float policy: every
+    plane a spec admitted here emits is one the kernel compiles for."""
+    for g in spec.groupings:
+        if g.dtype == STRING or g.dtype.is_floating:
+            return False
     from spark_rapids_tpu.columnar.dtypes import INT64, device_dtype
     from spark_rapids_tpu.compile import service
     mosaic = not service.pallas_interpret()
@@ -308,35 +313,57 @@ def _round_k(span: int) -> int:
     return k
 
 
-def make_update(spec, input_sig, capacity: int, lo_hint: int,
-                hi_hint: int, decoder=None):
-    """Jitted ``(flat_cols, num_rows, lo) -> (n_groups, keys, buffers)``
+def range_radix(lo: int, hi: int) -> int:
+    """The radix of a bare integer key whose range was probed: its span
+    plus the null digit, rounded up the kernel's K ladder so batches
+    with nearby ranges share one compiled kernel."""
+    return _round_k(hi - lo + 2)
+
+
+def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
+                decoder=None):
+    """Jitted ``(flat_cols, num_rows, bases) -> (n_groups, keys, buffers)``
     matching make_agg_body's update contract (group order identical).
-    The slot count K is derived here (single owner of the +1-null-slot
-    layout); ``lo``/the key base stays a traced argument so batches with
-    different ranges share a kernel per K bucket.  ``decoder``
+
+    ``radices`` holds one host-known radix per grouping: key ``i`` is the
+    digit ``key - bases[i] + 1`` (0 = null) and the slot is the mixed
+    radix of the digits, first key most significant — this function is
+    the single owner of that layout.  ``bases`` (an int64 vector, one per
+    digit) stays a traced argument so batches with different key ranges
+    share a kernel per radix bucket.  No groupings is zero digits: every
+    live row hits slot 0 and there is always exactly one group (the
+    empty-input rule, aggregate.scala:406-419).  The outputs are
+    ``bucket_capacity(prod(radices))`` slots long — the partial has the
+    shape of its domain, not of its input.  ``decoder``
     (encoding.plane_view) densifies plane-compressed triples inside the
     jitted body; its marker-bearing ``input_sig`` keys the variant."""
-    K = _round_k(hi_hint - lo_hint + 2)
-    cache_key = (spec.key(), input_sig, capacity, K)
+    radices = tuple(int(r) for r in radices)
+    domain = math.prod(radices)
+    K = _round_k(domain)
+    out_cap = min(K, bucket_capacity(domain))
+    cache_key = (spec.key(), input_sig, capacity, radices)
     fn = _UPDATE_CACHE.get(cache_key)
     if fn is not None:
         return fn
-    grouping = spec.groupings[0]
-    kdt: DataType = grouping.dtype
+    groupings = list(spec.groupings)
+    # stride of digit i = product of the radices after it
+    strides = [math.prod(radices[i + 1:]) for i in range(len(radices))]
 
-    def run(flat_cols, num_rows, lo):
+    def run(flat_cols, num_rows, bases):
         if decoder is not None:
             flat_cols = decoder(flat_cols)
         cols = [ColVal(*t) for t in flat_cols]
         ctx = EvalContext(cols, num_rows, capacity)
         live = jnp.arange(capacity) < num_rows
-        kcv = grouping.emit(ctx)
-        kvalid = kcv.validity & live
-        gid = jnp.where(kvalid,
-                        kcv.data.astype(jnp.int64) - lo + 1,
-                        jnp.zeros((), jnp.int64))
-        gid = jnp.clip(gid, 0, K - 1).astype(jnp.int32)
+        key_cvs = [g.emit(ctx) for g in groupings]
+        gid = jnp.zeros((capacity,), jnp.int64)
+        for i, kcv in enumerate(key_cvs):
+            digit = jnp.where(
+                kcv.validity & live,
+                kcv.data.astype(jnp.int64) - bases[i] + 1,
+                jnp.zeros((), jnp.int64))
+            gid = gid + jnp.clip(digit, 0, radices[i] - 1) * strides[i]
+        gid = gid.astype(jnp.int32)
 
         planes: List[jnp.ndarray] = []
         ops: List[str] = []
@@ -406,23 +433,21 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                               capacity)
 
         seen = reds[0] > 0
-        n_groups = jnp.sum(seen.astype(jnp.int32))
+        n_groups = jnp.sum(seen.astype(jnp.int32)) if radices \
+            else jnp.int32(1)
         # compact occupied slots to the front; slot order already equals
-        # the sorted kernel's nulls-first-ascending group order
-        perm = jnp.argsort(~seen, stable=True)
-        pos = jnp.arange(K, dtype=jnp.int32)
-        group_valid = pos < n_groups
+        # the sorted kernel's nulls-first-ascending group order.  Slots
+        # past the domain cannot be hit, so the compacted front
+        # ``out_cap`` slots hold every group
+        perm = jnp.argsort(~seen, stable=True)[:out_cap]
+        group_valid = jnp.arange(out_cap, dtype=jnp.int32) < n_groups
 
-        kd = (lo - 1 + jnp.arange(K, dtype=jnp.int64))
-        if kdt in (DATE,):
-            kd = kd.astype(jnp.int32)
-        elif kdt == BOOLEAN:
-            kd = kd.astype(jnp.bool_)
-        elif not (kdt == TIMESTAMP):
-            kd = kd.astype(kcv.data.dtype)
-        key_data = jnp.take(kd, perm)
-        null_slot = jnp.take(pos, perm) == 0
-        key_out = ColVal(key_data, group_valid & ~null_slot, None)
+        slot = perm.astype(jnp.int64)
+        key_outs = []
+        for i, kcv in enumerate(key_cvs):
+            digit = (slot // strides[i]) % radices[i]
+            kd = (bases[i] - 1 + digit).astype(kcv.data.dtype)
+            key_outs.append(ColVal(kd, group_valid & (digit != 0), None))
 
         buf_outs = []
         for item in post:
@@ -451,7 +476,7 @@ def make_update(spec, input_sig, capacity: int, lo_hint: int,
                 else:
                     out = jnp.where(has_nan, nan_v, base)
                 buf_outs.append(ColVal(out, group_valid, None))
-        return n_groups, (key_out,), tuple(buf_outs)
+        return n_groups, tuple(key_outs), tuple(buf_outs)
 
     fn = engine_jit(run, family="aggregate", name="pallas_update")
     _UPDATE_CACHE[cache_key] = fn

@@ -271,8 +271,11 @@ def _node_sums(node, out):
     return out
 
 
-@pytest.mark.parametrize("query", [_q1, _q6], ids=["q1", "q6"])
-def test_node_device_time_adds_up_to_the_programs_group(query):
+@pytest.mark.parametrize("query,update", [
+    (_q1, "aggregate_update"),          # plain string keys: the sorted body
+    (_q6, "aggregate_pallas_update"),   # no keys: the host knows the domain
+], ids=["q1", "q6"])
+def test_node_device_time_adds_up_to_the_programs_group(query, update):
     s = tpu_session(TRACED)
     query(s).collect()
     before = s.engine_stats()
@@ -296,7 +299,7 @@ def test_node_device_time_adds_up_to_the_programs_group(query):
                - grown["device_us"]) <= slack_us
     assert sum(r["untimed"] for r in rows) == 0
     assert "device=" in txt and "dispatches=" in txt
-    assert "Programs:" in txt and "aggregate_update: dispatches=" in txt
+    assert "Programs:" in txt and f"{update}: dispatches=" in txt
 
 
 def test_phases_count_plan_execute_and_blocking_reads():

@@ -81,6 +81,53 @@ def test_pallas_agg_compiles_for_v5e(one_chip, mosaic, K):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("radices", [(4, 3), ()],
+                         ids=["q1_two_code_keys", "q6_keyless"])
+def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
+                                       radices):
+    """The whole ``make_update`` program of the static route at the
+    scan's batch capacity: the mixed-radix slot, q1's sixteen planes
+    (four sums and three averages with their counts, a row count) at
+    K = 128, the compaction and the per-digit key rebuild; zero digits
+    is q6's keyless shape.  Outputs are as long as the domain's bucket,
+    not as the input."""
+    from spark_rapids_tpu.columnar import dtypes
+    from spark_rapids_tpu.columnar.dtypes import FLOAT64, INT32
+    from spark_rapids_tpu.exec import pallas_agg
+    from spark_rapids_tpu.exec.aggregate import _AggSpec
+    from spark_rapids_tpu.exprs import aggregates as agf
+    from spark_rapids_tpu.exprs.base import BoundReference, Literal
+    monkeypatch.setattr(dtypes, "_DOUBLE_AS_FLOAT", True)
+    nk = len(radices)
+    keys = [BoundReference(i, INT32, True, f"k{i}") for i in range(nk)]
+    vals = [BoundReference(nk + i, FLOAT64, True, f"v{i}")
+            for i in range(4)]
+    aggs = [(f"s{i}", agf.Sum(v)) for i, v in enumerate(vals)]
+    aggs += [(f"a{i}", agf.Average(v)) for i, v in enumerate(vals[:3])]
+    aggs.append(("n", agf.Count(Literal(1, INT32))))
+    spec = _AggSpec(keys, aggs)
+    assert pallas_agg.supports(spec)
+    pallas_agg._UPDATE_CACHE.clear()
+    prog = pallas_agg.make_update(spec, ("compile-test", nk), CAP,
+                                  radices)
+
+    def col(dt):
+        return (jax.ShapeDtypeStruct((CAP,), dt, sharding=one_chip),
+                jax.ShapeDtypeStruct((CAP,), jnp.bool_,
+                                     sharding=one_chip), None)
+
+    flat = tuple([col(jnp.int32)] * nk + [col(jnp.float32)] * 4)
+    lowered = prog.lower(
+        flat, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((nk,), jnp.int64, sharding=one_chip))
+    pallas_agg._UPDATE_CACHE.clear()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+    _n_groups, key_outs, buf_outs = lowered.out_info
+    assert len(key_outs) == nk and len(buf_outs) == 15
+    out_cap = 16 if radices else 8
+    assert {cv.data.shape for cv in key_outs + buf_outs} == {(out_cap,)}
+
+
 def test_pallas_agg_refuses_64bit_planes_on_the_chip(mosaic):
     """What ``supports()`` must never admit raises, typed, at trace
     time — it does not run another path."""
