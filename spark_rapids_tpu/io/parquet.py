@@ -10,9 +10,12 @@ to the device.
 
 from __future__ import annotations
 
+import datetime as _dt
+import functools
 import glob as _glob
 import os
-from typing import Iterator, List, Optional, Sequence
+import threading
+from typing import Dict, Iterator, List, Optional, Sequence
 
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -25,6 +28,9 @@ from spark_rapids_tpu.io.hostio import (
 )
 from spark_rapids_tpu.exprs.base import Expression, Literal, BoundReference
 from spark_rapids_tpu.exprs import predicates as pr
+
+
+_EPOCH = _dt.date(1970, 1, 1)
 
 
 def expand_paths(path) -> List[str]:
@@ -59,23 +65,47 @@ def tail_marker(path: str) -> str:
         return f.read(8).hex()
 
 
-def _stats_prune(md, ridx: int, pred: Optional[Expression],
-                 schema: Schema) -> bool:
-    """True if row group `ridx` may contain matching rows.  Conservative
-    min/max pruning for simple `col <op> literal` predicates (reference:
-    predicate pushdown through the clipped footer, GpuParquetScan.scala:316)."""
-    if pred is None:
-        return True
-    checks = _collect_simple_predicates(pred)
-    if not checks:
-        return True
-    rg = md.row_group(ridx)
-    col_stats = {}
-    for ci in range(rg.num_columns):
-        col = rg.column(ci)
-        st = col.statistics
-        if st is not None and st.has_min_max:
-            col_stats[col.path_in_schema] = (st.min, st.max)
+def _comparable(v):
+    """A footer statistic in the units a ``Literal`` carries: a date as
+    days since the epoch (exprs/base.py ``Literal.__init__``).  A
+    timestamp stays a ``datetime``, which no literal's integer compares
+    with, so timestamps never prune (their unit and zone would have to
+    be settled first); every other value as it is."""
+    if isinstance(v, _dt.date) and not isinstance(v, _dt.datetime):
+        return (v - _EPOCH).days
+    return v
+
+
+def _group_stats(md) -> tuple:
+    """Per row group of a footer: ``{column path: (min, max)}`` for the
+    columns that carry min/max statistics."""
+    groups = []
+    for ridx in range(md.num_row_groups):
+        rg = md.row_group(ridx)
+        col_stats = {}
+        for ci in range(rg.num_columns):
+            col = rg.column(ci)
+            st = col.statistics
+            if st is not None and st.has_min_max:
+                col_stats[col.path_in_schema] = (
+                    _comparable(st.min), _comparable(st.max))
+        groups.append(col_stats)
+    return tuple(groups)
+
+
+@functools.lru_cache(maxsize=512)
+def _footer_stats(path: str, mtime: float, size: int) -> tuple:
+    """``_group_stats`` of the file at ``path``, remembered under the
+    file's identity: the scan cache's key reads it for every query, and
+    a rewritten file is another identity."""
+    return _group_stats(pq.read_metadata(path))
+
+
+def _may_match(col_stats: dict, checks) -> bool:
+    """True if a row group with these statistics may contain matching
+    rows.  Conservative min/max pruning for simple `col <op> literal`
+    predicates (reference: predicate pushdown through the clipped footer,
+    GpuParquetScan.scala:316)."""
     for (name, op, value) in checks:
         if name not in col_stats:
             continue
@@ -94,6 +124,20 @@ def _stats_prune(md, ridx: int, pred: Optional[Expression],
         except TypeError:
             continue
     return True
+
+
+def kept_row_groups(groups: tuple, checks, rg_shard=None) -> List[int]:
+    """The row groups a scan reads of a file whose footer says
+    ``groups`` (``_group_stats``): those ``checks`` cannot rule out, and
+    of them the ``rg_shard`` = (r, k) share (post-prune position r mod
+    k).  The ONE place pruning is decided: the reader reads these, and
+    the scan cache's key names them."""
+    keep = [i for i, col_stats in enumerate(groups)
+            if _may_match(col_stats, checks)]
+    if rg_shard is not None:
+        r, k = rg_shard
+        keep = [g for j, g in enumerate(keep) if j % k == r]
+    return keep
 
 
 _SIMPLE_OPS = {
@@ -131,8 +175,9 @@ def _literal_value(e: Expression):
     return None
 
 
-def _collect_simple_predicates(pred: Expression):
-    """AND-tree of (bound_col <op> literal) -> [(col_name, op, value)]."""
+def _collect_simple_predicates(pred: Optional[Expression]):
+    """AND-tree of (bound_col <op> literal) -> [(col_name, op, value)];
+    nothing for no predicate."""
     out = []
 
     def walk(e):
@@ -187,19 +232,18 @@ class ParquetPartitionReader:
         f = pq.ParquetFile(self.path,
                            read_dictionary=self.read_dictionary or None)
         md = f.metadata
-        keep = [i for i in range(md.num_row_groups)
-                if _stats_prune(md, i, self.pred, self.schema)]
+        checks = _collect_simple_predicates(self.pred)
+        keep = kept_row_groups(_group_stats(md) if checks
+                               else ({},) * md.num_row_groups,
+                               checks, self.rg_shard)
         self.total_row_groups = md.num_row_groups
-        if self.rg_shard is not None:
-            r, k = self.rg_shard
-            keep = [g for j, g in enumerate(keep) if j % k == r]
-            # k shard clones share the planner scan node's metrics and
-            # each re-reads this footer: attribute the file's total to
-            # shard 0 only, so the summed numRowGroupsTotal stays the
-            # file's real count instead of k x it (read counts are
-            # disjoint per shard and sum correctly on their own)
-            if r != 0:
-                self.total_row_groups = 0
+        # k shard clones share the planner scan node's metrics and each
+        # re-reads this footer: attribute the file's total to shard 0
+        # only, so the summed numRowGroupsTotal stays the file's real
+        # count instead of k x it (read counts are disjoint per shard
+        # and sum correctly on their own)
+        if self.rg_shard is not None and self.rg_shard[0] != 0:
+            self.total_row_groups = 0
         self.read_row_groups = len(keep)
         return self._iter_batches(f, keep)
 
@@ -217,17 +261,72 @@ def scan_cache_key(kind: str, paths: List[str], schema: Schema,
                    pred_key, batch_rows: int, max_w) -> Optional[tuple]:
     """Cache key for a device-resident scan: file identities (path,
     mtime, size) + the scan shape.  None when any file is unstatable.
+    ``pred_key`` is whatever else decides WHICH rows the scan uploads:
+    a callable is given the file identities and returns it (parquet:
+    the row groups pruning keeps, ``pruning_outcome``).
     The compressed-ingest switch is part of the key: the cache is
     process-wide, and a compressed-off session must never be served
     another session's encoded batches (off = byte-identical planes)."""
     try:
         ids = tuple((p, os.path.getmtime(p), os.path.getsize(p))
                     for p in paths)
+        if callable(pred_key):
+            pred_key = pred_key(ids)
     except OSError:
         return None
     from spark_rapids_tpu.columnar import encoding
     return (kind, ids, tuple((f.name, f.dtype.name) for f in schema),
             pred_key, batch_rows, max_w, encoding.ingest_enabled())
+
+
+def pruning_outcome(pred: Optional[Expression], rg_shard):
+    """What a parquet scan's pushed-down predicate contributes to the
+    scan cache's key: its text with every prepared-statement parameter
+    masked (``plan.fingerprint.mask_params``: slot and dtype, no value),
+    and in place of the values what they DO, which is prune row groups:
+    per file the row groups kept (``kept_row_groups``, the reader's own
+    decision).  Two bindings of a prepared statement that keep the same
+    row groups share one device-resident entry; a binding that prunes
+    differently gets its own; a predicate with nothing to prune by (no
+    `col <op> literal` conjunct) keeps everything under every binding.
+    An inline literal stays in the text, as it does in the plan
+    fingerprint: it is the query, not a binding of it.  For
+    ``scan_cache_key``'s ``pred_key``."""
+    from spark_rapids_tpu.plan.fingerprint import mask_params
+    text = mask_params(pred).key() if pred is not None else None
+    checks = _collect_simple_predicates(pred)
+
+    def outcome(ids):
+        kept = None
+        if checks:
+            kept = tuple(tuple(kept_row_groups(
+                _footer_stats(*ident), checks, rg_shard)) for ident in ids)
+        return (text, kept, rg_shard)
+    return outcome
+
+
+# Always-on counters of the device scan cache (the ``scan`` group of
+# ``engine_stats()``, docs/observability.md): a served request leaves no
+# plan to read ``scanCacheHits`` from, so the cache counts for itself.
+# Bumped once per scan, never per batch.
+_SCAN_LOCK = threading.Lock()
+_SCAN = {"cache_lookups": 0, "cache_hits": 0, "decoded_bytes": 0}
+
+
+def _scan_add(counter: str, amount: int = 1) -> None:
+    with _SCAN_LOCK:
+        _SCAN[counter] += amount
+
+
+def scan_stats() -> Dict[str, int]:
+    with _SCAN_LOCK:
+        return dict(_SCAN)
+
+
+def reset_scan_stats() -> None:
+    with _SCAN_LOCK:
+        for k in _SCAN:
+            _SCAN[k] = 0
 
 
 def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
@@ -236,14 +335,18 @@ def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
     (``spark.rapids.sql.scan.deviceCacheEnabled``).  ``gen`` is a
     zero-arg callable producing the fresh batch iterator; the named
     scan metrics are snapshotted with the entry and replayed on a hit so
-    observability (row-group pruning counters etc.) survives caching."""
+    observability (row-group pruning counters etc.) survives caching.
+    Counts every lookup, every hit, and the device bytes a miss decoded
+    and uploaded (``scan_stats``)."""
     from spark_rapids_tpu.memory.spill import SpillableBatch
     cache = ctx.runtime.scan_cache
     if key is None or not ctx.conf.scan_device_cache_enabled:
         yield from gen()
         return
     hit = cache.get(key)
+    _scan_add("cache_lookups")
     if hit is not None:
+        _scan_add("cache_hits")
         handles, _, snap = hit
         if metrics is not None:
             for name, v in snap.items():
@@ -267,6 +370,7 @@ def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
         yield b
     snap = {n: metrics[n].value - before[n] for n in metric_names} \
         if metrics is not None else {}
+    _scan_add("decoded_bytes", sum(h.size for h in handles))
     cache.put(key, handles, schema, snap)
 
 
@@ -368,9 +472,7 @@ class TpuParquetScanExec(TpuExec):
 
         key = scan_cache_key(
             "parquet", files, self._schema,
-            (self.pred.key() if self.pred is not None else None,
-             self.rg_shard),
-            rows, max_w)
+            pruning_outcome(self.pred, self.rg_shard), rows, max_w)
         return self._count_output(cached_device_scan(
             ctx, key, gen, metrics=self.metrics,
             metric_names=("numRowGroupsTotal", "numRowGroupsRead")))

@@ -176,6 +176,7 @@ def snapshot() -> dict:
     from spark_rapids_tpu.columnar import encoding, transfer
     from spark_rapids_tpu.compile import service as compile_service
     from spark_rapids_tpu.exec import aqe, meshexec, stage
+    from spark_rapids_tpu.io import parquet as scan_io
     from spark_rapids_tpu.io import prefetch
     from spark_rapids_tpu.fleet import stats as fleet_stats
     from spark_rapids_tpu.obs import journal
@@ -185,6 +186,10 @@ def snapshot() -> dict:
     return {
         "prefetch": prefetch.global_stats(),
         "d2h": transfer.d2h_stats(),
+        # the device scan cache, counted where it is looked up
+        # (io/parquet.py cached_device_scan): lookups, hits, and the
+        # device bytes the misses decoded and uploaded
+        "scan": scan_io.scan_stats(),
         # compressed-domain execution trajectory (docs/compressed.md):
         # `encodedColumns` (columns ingested as codes), `lateDecodes`
         # (separate decode dispatches — the escape hatch), and

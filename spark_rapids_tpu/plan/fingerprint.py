@@ -121,12 +121,15 @@ class _MaskedParam(Expression):
         return f"param[{self.slot}:{self._dtype.name}]"
 
 
-def _mask_params(e: Expression) -> Expression:
+def mask_params(e: Expression) -> Expression:
+    """``e`` with every ParamLiteral replaced by its slot and dtype: what
+    all bindings of a prepared statement have in common (the plan
+    fingerprint here, and the scan cache's key in io/parquet.py)."""
     if isinstance(e, ParamLiteral):
         return _MaskedParam(e.slot, e._dtype)
     if not e.children:
         return e
-    new = [_mask_params(c) for c in e.children]
+    new = [mask_params(c) for c in e.children]
     if all(a is b for a, b in zip(new, e.children)):
         return e
     return e.with_children(new)
@@ -134,7 +137,7 @@ def _mask_params(e: Expression) -> Expression:
 
 def _value_fp(v) -> str:
     if isinstance(v, Expression):
-        return _mask_params(v).key()
+        return mask_params(v).key()
     if isinstance(v, (list, tuple)):
         return "[" + ",".join(_value_fp(x) for x in v) + "]"
     if isinstance(v, Schema):

@@ -49,6 +49,7 @@ from spark_rapids_tpu.server.prepared import PreparedStatement
 from spark_rapids_tpu.server.result_cache import (
     DiskResultTier, ResultCache,
 )
+from spark_rapids_tpu.utils import tracing
 
 FAULT_SITE_ADMIT = "server.admit"
 
@@ -344,38 +345,54 @@ class SessionServer:
         ``spark.rapids.server.retry.maxAttempts`` and only when the
         failed attempt surfaced no results."""
         ticket.started_at = time.monotonic()
-        obs.record(obs.HIST_SERVER_ADMIT_WAIT_US,
-                   int((ticket.started_at - ticket.submitted_at) * 1e6))
+        waited_us = int((ticket.started_at - ticket.submitted_at) * 1e6)
+        obs.record(obs.HIST_SERVER_ADMIT_WAIT_US, waited_us)
+        stats.bump("admit_wait_us", waited_us)
         attempts = 0
         try:
-            while True:
-                attempts += 1
-                view = _TenantSession(
-                    self.session, self._tenant_conf(ticket.tenant,
-                                                    ticket.timeout_ms))
-                try:
-                    self._run_attempt(ticket, view)
-                    return
-                except ChipFailedError as e:
-                    self._check_replay(ticket, view, attempts, e)
-                    health.note_replay()
-                    journal.emit(journal.EVENT_QUERY_REPLAY,
-                                 tenant=ticket.tenant, chip=e.chip,
-                                 attempt=attempts)
+            # the request is a traced scope of its own: its bookkeeping
+            # before and after the query (tenant conf, bind, result-cache
+            # key) is under the switch too, not only DataFrame._execute
+            with tracing.switch_scope(self.session.conf.trace_enabled), \
+                    tracing.trace_range(
+                        f"{tracing.SPAN_SERVER_EXECUTE}:{ticket.tenant}"):
+                # the wait is over by the time a worker can say so: a
+                # marker at the pick-up; its length is in admit_wait_us
+                with tracing.trace_range(tracing.SPAN_SERVER_ADMIT_WAIT):
+                    pass
+                while True:
+                    attempts += 1
+                    view = _TenantSession(
+                        self.session, self._tenant_conf(ticket.tenant,
+                                                        ticket.timeout_ms))
+                    try:
+                        self._run_attempt(ticket, view)
+                        return
+                    except ChipFailedError as e:
+                        self._check_replay(ticket, view, attempts, e)
+                        health.note_replay()
+                        journal.emit(journal.EVENT_QUERY_REPLAY,
+                                     tenant=ticket.tenant, chip=e.chip,
+                                     attempt=attempts)
         except BaseException as e:
             stats.bump("failed")
             ticket._fail(e)
+        finally:
+            stats.bump("execute_us", int(
+                (time.monotonic() - ticket.started_at) * 1e6))
 
     def _run_attempt(self, ticket: ServerQuery,
                      view: "_TenantSession") -> None:
-        df = self._resolve(ticket, view)
+        with tracing.trace_range(tracing.SPAN_SERVER_RESOLVE):
+            df = self._resolve(ticket, view)
         key = pins = None
         leaves = None
         maintain = False
         if self._cache is not None and ticket.use_cache:
             maintain = view.conf.get(STREAM_CACHE_MAINTAIN)
-            key, pins, leaves = self._cache_key(
-                df, ticket.params, view.conf, with_leaves=maintain)
+            with tracing.trace_range(tracing.SPAN_SERVER_CACHE_KEY):
+                key, pins, leaves = self._cache_key(
+                    df, ticket.params, view.conf, with_leaves=maintain)
             if key is not None:
                 hit = self._cache.lookup(key)
                 if hit is not None:

@@ -34,6 +34,11 @@ _COUNTERS = {
     "disk_cache_corrupt": 0,   # corrupt/unreadable entries degraded to miss
     "prepared": 0,         # PreparedStatement handles created
     "prepared_execs": 0,   # bindings executed through handles
+    # microseconds, summed over requests: submit -> a worker picked the
+    # ticket up (what the server.admit.wait.us histogram observes), and
+    # pick-up -> typed outcome on the ticket (replays included)
+    "admit_wait_us": 0,
+    "execute_us": 0,
 }
 # replay/drain counters live in the HEALTH stats object alone
 # (health.py: replays / replays_shed / drains / drain_ms) — one store,

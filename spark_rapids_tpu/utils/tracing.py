@@ -54,6 +54,17 @@ SPAN_QUERY_PLAN = "query.plan"
 SPAN_QUERY_EXECUTE = "query.execute"
 SPAN_D2H_PULL = "d2h.pull"
 SPAN_D2H_SYNC = "d2h.sync"
+# a served request (server/core.py), on the worker that picked its
+# ticket up: ``server.execute:<tenant>`` from pick-up to typed outcome,
+# a marker ``server.admit_wait`` at the pick-up (the wait itself ended
+# there; its microseconds are the ``server.admit_wait_us`` counter), and
+# the server's own work around the query: resolving the ticket to a
+# DataFrame (parse or bind) and forming the result cache's key (plan and
+# input-snapshot fingerprints, which stat and read every scanned file)
+SPAN_SERVER_ADMIT_WAIT = "server.admit_wait"
+SPAN_SERVER_EXECUTE = "server.execute"
+SPAN_SERVER_RESOLVE = "server.resolve"
+SPAN_SERVER_CACHE_KEY = "server.cache_key"
 
 # Always-on phase counters (the ``phases`` group of ``engine_stats()``,
 # docs/observability.md): microseconds a query spent planning and
@@ -194,30 +205,63 @@ def phase(span: str, counter: str):
         phase_add(counter, (time.perf_counter_ns() - start) // 1000)
 
 
+# Traced scopes that are open now, and the switch as it stood before
+# the first of them.  Two overlapping traced queries (two server workers
+# under one traced session) each used to restore what they had found:
+# the first to finish switched the other's spans and device clock off
+# mid-query, and the second left the switch on behind it.
+_SCOPES_LOCK = threading.Lock()
+_open_traced = 0
+_before_traced = False
+
+
+@contextlib.contextmanager
+def switch_scope(traced: bool):
+    """Set the span switch to ``traced`` for the enclosed work and put
+    it back on exit, success or failure.  Overlapping TRACED scopes
+    count themselves in and out: the switch stays on until the last of
+    them leaves, and then goes back to what it was before the first.
+    An untraced scope restores what it found."""
+    global _open_traced, _before_traced
+    with _SCOPES_LOCK:
+        prev = is_enabled()
+        if traced:
+            if _open_traced == 0:
+                _before_traced = prev
+            _open_traced += 1
+        set_enabled(traced)
+    try:
+        yield
+    finally:
+        with _SCOPES_LOCK:
+            if not traced:
+                set_enabled(prev)
+            else:
+                _open_traced -= 1
+                if _open_traced == 0:
+                    set_enabled(_before_traced)
+
+
 @contextlib.contextmanager
 def query_trace(conf):
     """Whole-query profiler capture: when ``trace.enabled`` and a
     ``trace.dir`` are set, wraps execution in ``jax.profiler.trace`` so a
     collect() produces an Xprof trace (the Nsight-session analog).
 
-    The span switch is scoped to the query: the previous enabled state
-    is restored on exit, so a traced query inside an untraced session
-    (or the reverse) cannot leak its switch into the next query
-    (tests/test_tracing.py).  The switch itself remains process-global
-    (like the reference's NVTX ranges): CONCURRENT queries with
-    different trace settings still last-writer-win while overlapped —
-    the same limitation as before this scoping, which fixes the serial
-    leak only.  Per-query isolation needs a contextvar switch, a
-    redesign deferred to the multi-tenant front end (ROADMAP item 4)."""
+    The span switch is scoped to the query (``switch_scope``): the
+    previous enabled state is restored on exit, so a traced query
+    inside an untraced session (or the reverse) cannot leak its switch
+    into the next query (tests/test_tracing.py).  The switch itself
+    remains process-global (like the reference's NVTX ranges):
+    concurrent queries with DIFFERENT trace settings still
+    last-writer-win while overlapped, and a traced query sees the
+    programs of every other query in flight.  Per-request isolation
+    needs a contextvar switch (ROADMAP M9)."""
     from spark_rapids_tpu import conf as C
-    prev = is_enabled()
-    set_enabled(conf.trace_enabled)
     logdir = conf.get(C.TRACE_DIR)
-    try:
+    with switch_scope(conf.trace_enabled):
         if conf.trace_enabled and logdir and _HAVE_JAX:
             with jax.profiler.trace(logdir):
                 yield
         else:
             yield
-    finally:
-        set_enabled(prev)

@@ -121,6 +121,36 @@ def test_query_trace_restores_on_exception():
     assert not tracing.is_enabled()
 
 
+@pytest.mark.parametrize("first_out", ("a", "b"))
+def test_overlapping_traced_scopes_keep_the_switch_on(first_out):
+    """Two traced queries in flight at once (two server workers): the
+    one that finishes first must not switch the other's spans off, and
+    the last one out restores what stood before the first came in."""
+    traced = TpuConf({"spark.rapids.sql.trace.enabled": True})
+    tracing.set_enabled(False)
+    a, b = tracing.query_trace(traced), tracing.query_trace(traced)
+    a.__enter__()
+    b.__enter__()
+    first, last = (a, b) if first_out == "a" else (b, a)
+    first.__exit__(None, None, None)
+    assert tracing.is_enabled()
+    last.__exit__(None, None, None)
+    assert not tracing.is_enabled()
+
+
+def test_switch_scope_restores_on_exception_and_nests_untraced():
+    tracing.set_enabled(False)
+    with tracing.switch_scope(True):
+        with tracing.switch_scope(False):
+            assert not tracing.is_enabled()
+        assert tracing.is_enabled()
+        with pytest.raises(RuntimeError):
+            with tracing.switch_scope(True):
+                raise RuntimeError("boom")
+        assert tracing.is_enabled()
+    assert not tracing.is_enabled()
+
+
 # -- the span and node stacks the dispatch ledger reads ---------------------
 
 def test_span_stack_names_the_innermost_open_span():
