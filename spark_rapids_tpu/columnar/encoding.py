@@ -1376,8 +1376,8 @@ def _deterministic(expr) -> bool:
     return not contains_nondeterministic(expr)
 
 
-def stage_view(steps, batch, keys: Sequence[Expression] = ()
-               ) -> "StageView":
+def stage_view(steps, batch, keys: Sequence[Expression] = (),
+               dense_tail: int = 0) -> "StageView":
     """Build the code-domain view of ``steps`` (and optional trailing
     partition-key expressions) over ``batch``.
 
@@ -1395,6 +1395,11 @@ def stage_view(steps, batch, keys: Sequence[Expression] = ()
     * key expressions that are bare references to an encoded column
       hash by per-code gather tables built with the dense path's own
       hash kernel (byte-identical partition assignment).
+
+    ``dense_tail`` marks the last N outputs of the FINAL project step
+    as inputs of a value-domain consumer (the folded aggregate's input
+    projections, exec/aggregate.py): a bare encoded reference there
+    decodes in-kernel instead of leaving as codes.
 
     With no encoded columns (or compressed off) the view is the
     identity: flatten/signature/steps exactly as the dense engine
@@ -1541,12 +1546,14 @@ def stage_view(steps, batch, keys: Sequence[Expression] = ()
 
     out_steps: List[tuple] = []
     wrap: Dict[int, DictPlanes] = {}
-    for kind, exprs in steps:
+    for si, (kind, exprs) in enumerate(steps):
         if kind == "project":
             new_exprs = []
             next_dicts: Dict[int, DictPlanes] = {}
+            codes_until = len(exprs) - dense_tail \
+                if si == len(steps) - 1 else len(exprs)
             for oi, e in enumerate(exprs):
-                ne, d = rewrite(e, True)
+                ne, d = rewrite(e, oi < codes_until)
                 new_exprs.append(ne)
                 if d is not None:
                     next_dicts[oi] = d

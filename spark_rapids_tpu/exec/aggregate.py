@@ -24,6 +24,12 @@ sort: ``_try_dense_update`` reduces it by direct address over K slots
 (exec/pallas_agg.py) and its partial is K slots long, so the concat, the
 merge and everything downstream run at the domain's capacity, not the
 input's.
+
+A filter / project chain whose only consumer is this update is FOLDED
+into it by the planner (plan/fusion.py, docs/fusion.md): the steps run
+masked inside the update's own program (``exec.stage.emit_steps(...,
+compact=False)``) and the keep-mask is the update's liveness, so the
+filter costs one elementwise predicate and no compaction gather.
 """
 
 from __future__ import annotations
@@ -40,7 +46,7 @@ from spark_rapids_tpu.compile.service import engine_jit
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, bucket_capacity
 from spark_rapids_tpu.columnar.dtypes import (
-    DataType, Field, Schema, STRING, INT64, FLOAT32, FLOAT64,
+    DataType, Field, Schema, STRING, INT32, INT64, FLOAT32, FLOAT64,
 )
 from spark_rapids_tpu.exec.base import ExecContext, TpuExec
 from spark_rapids_tpu.exec.coalesce import concat_batches
@@ -48,9 +54,12 @@ from spark_rapids_tpu.exec.sortkeys import colval_sort_keys, sort_permutation
 from spark_rapids_tpu.exprs.aggregates import AggregateFunction
 from spark_rapids_tpu.exprs.base import (
     Alias, BoundReference, ColVal, EvalContext, Expression,
-    _batch_signature, _flatten_batch,
+    _batch_signature, _flatten_batch, hoisted_args,
 )
-from spark_rapids_tpu.utils.metrics import METRIC_TOTAL_TIME
+from spark_rapids_tpu.utils.metrics import (
+    METRIC_MASKED_FILTER_BATCHES, METRIC_PALLAS_AGG_BATCHES,
+    METRIC_TOTAL_TIME,
+)
 
 
 def unwrap_aggregate(e: Expression) -> Tuple[str, AggregateFunction]:
@@ -352,6 +361,48 @@ def _compile_agg(spec: _AggSpec, phase: str, input_sig, capacity: int,
     return fn
 
 
+def _compile_folded_update(h_steps, input_sig, aux_sig, capacity: int,
+                           spec: _AggSpec, radices=None):
+    """ONE update program for a batch whose filter / project chain the
+    planner folded into the aggregate: ``h_steps`` (hoisted, code-viewed;
+    the last one projects ``spec``'s keys and inputs) run MASKED, then
+    the update body reduces under the steps' liveness — the dense body
+    over ``radices`` (exec/pallas_agg.py) or, with ``radices`` None, the
+    sorted-segment body.  Literals ride in as traced scalars and
+    dictionary tables as aux inputs, so the key is literal-free: a new
+    binding of a prepared query reuses the program."""
+    from spark_rapids_tpu.exec.stage import emit_steps, stage_fingerprint
+    dense = radices is not None
+    cache_key = ("folded", stage_fingerprint(h_steps), input_sig, aux_sig,
+                 capacity, spec.key(),
+                 tuple(int(r) for r in radices) if dense else None)
+    fn = _AGG_CACHE.get(cache_key)
+    if fn is not None:
+        return fn
+    if dense:
+        from spark_rapids_tpu.exec import pallas_agg as pag
+        body = pag.make_update_body(spec, capacity, radices)
+    else:
+        body = make_agg_body(spec, "update", capacity)
+
+    def run(flat_cols, aux, num_rows, hoisted, bases):
+        cols = [ColVal(*t) for t in flat_cols]
+        # folded steps are deterministic: the partition id is unread
+        cols, live = emit_steps(h_steps, cols, num_rows, capacity,
+                                jnp.int64(0), hoisted, aux=aux,
+                                compact=False)
+        flat = tuple((c.data, c.validity, c.chars) for c in cols)
+        if dense:
+            return body(flat, num_rows, bases, live)
+        return body(flat, num_rows, live)
+
+    fn = engine_jit(run, family="aggregate",
+                    name="masked_pallas_update" if dense
+                    else "masked_update")
+    _AGG_CACHE[cache_key] = fn
+    return fn
+
+
 _EVAL_CACHE = KernelCache("aggregate.eval", 256)
 
 
@@ -382,6 +433,19 @@ def _compile_evaluate(spec: _AggSpec, input_sig, capacity: int):
     return fn
 
 
+def _fused_decode_planes(vbatch: ColumnarBatch, count: bool = True):
+    """``(flat, sig, decoder)`` of a batch for a compiled whole-batch
+    consumer: plane-compressed inputs (rle/delta/packed bool) feed the
+    kernel their compressed planes and decode INSIDE it — one dispatch,
+    no decode_plane_late on the update path (``encoding.plane_view``;
+    ``count=False`` for a probe that may not dispatch the view)."""
+    from spark_rapids_tpu.columnar import encoding
+    pv = encoding.plane_view(vbatch, count=count)
+    if pv is not None:
+        return pv
+    return _flatten_batch(vbatch), _batch_signature(vbatch), None
+
+
 def _colvals_to_batch(cvs, dtypes, n_rows: int,
                       schema: Optional[Schema] = None,
                       wrap=None) -> ColumnarBatch:
@@ -407,6 +471,10 @@ class TpuHashAggregateExec(TpuExec):
     def __init__(self, groupings: List[Expression],
                  aggregates: List[Expression], child):
         super().__init__()
+        # filter / project steps the fusion pass folded in from below
+        # (``fold_steps``); the groupings and aggregates are bound to
+        # the LAST step's output, ``children[0]`` feeds the first
+        self.pre_steps: tuple = ()
         self.groupings = list(groupings)
         # the original bound aggregate expressions, kept so the AQE
         # placement re-score can rebuild the CPU analog of this node
@@ -432,7 +500,19 @@ class TpuHashAggregateExec(TpuExec):
     def describe(self) -> str:
         gs = ", ".join(g.name for g in self.groupings)
         asx = ", ".join(n for n, _ in self.agg_pairs)
-        return f"TpuHashAggregate [keys=[{gs}], aggs=[{asx}]]"
+        pre = ""
+        if self.pre_steps:
+            from spark_rapids_tpu.exec.stage import describe_steps
+            pre = f", masked=[{describe_steps(self.pre_steps)}]"
+        return f"TpuHashAggregate [keys=[{gs}], aggs=[{asx}]{pre}]"
+
+    def fold_steps(self, steps, child) -> None:
+        """Take over a filter / project chain (plan/fusion.py): its steps
+        run masked inside this node's update program, over ``child``'s
+        batches."""
+        self.pre_steps = tuple((k, tuple(es)) for k, es in steps) \
+            + self.pre_steps
+        self.children = [child]
 
     def child_coalesce_goals(self, conf):
         from spark_rapids_tpu.exec.coalesce import TargetSize
@@ -483,19 +563,12 @@ class TpuHashAggregateExec(TpuExec):
                    conf=None):
         from spark_rapids_tpu.columnar.column import LazyRows
         with self.metrics.timed("computeAggTime"):
+            if phase == "update" and self.pre_steps:
+                return self._run_folded_update(batch, conf)
             spec, vbatch, wrap = self._agg_view(phase, batch)
-            # plane-compressed inputs (rle/delta/packed bool) feed the
-            # agg kernel their compressed planes and decode INSIDE it —
-            # one dispatch, no decode_plane_late on the update path
-            from spark_rapids_tpu.columnar import encoding as _enc
-            pv = _enc.plane_view(vbatch)
-            if pv is not None:
-                flat, sig, decoder = pv
-            else:
-                flat = _flatten_batch(vbatch)
-                sig, decoder = _batch_signature(vbatch), None
+            flat, sig, decoder = _fused_decode_planes(vbatch)
             dense = None
-            if phase == "update" and conf is not None:
+            if phase == "update":
                 dense = self._try_dense_update(spec, vbatch, wrap, conf,
                                                flat, sig, decoder)
             if dense is not None:
@@ -514,23 +587,104 @@ class TpuHashAggregateExec(TpuExec):
                 list(key_outs) + list(buf_outs), self._buffer_dtypes(),
                 LazyRows(n_groups, bound), wrap=wrap)
 
+    def _folded_spec(self, coded) -> _AggSpec:
+        """This aggregation over the output of the folded steps' last
+        projection (keys first, then one input per function); ``coded``
+        holds the key positions that arrive as dictionary codes."""
+        nk = len(self.groupings)
+        groupings = [
+            BoundReference(i, INT32 if i in coded else g.dtype,
+                           g.nullable, g.name)
+            for i, g in enumerate(self.groupings)]
+        aggs = [
+            (n, f.with_children([BoundReference(
+                nk + j, f.child.dtype, f.child.nullable, f.child.name)]))
+            for j, (n, f) in enumerate(self.agg_pairs)]
+        return _AggSpec(groupings, aggs)
+
+    def _run_folded_update(self, batch: ColumnarBatch, conf):
+        """One update over an UNFILTERED input batch: the folded steps
+        and a last projection of this node's keys and inputs go through
+        one code view (``encoding.stage_view``: predicates over
+        dictionary columns become code-set membership, bare dictionary
+        keys stay codes, as ``_agg_view`` would have them), literals
+        hoist out of the key, and the program reduces under the steps'
+        keep-mask (``_compile_folded_update``)."""
+        from spark_rapids_tpu.columnar import encoding
+        from spark_rapids_tpu.columnar.column import LazyRows
+        from spark_rapids_tpu.exec.stage import hoist_steps, norm_rows
+        nk = len(self.groupings)
+        inputs = tuple(f.child for _, f in self.agg_pairs)
+        tail = ("project", tuple(self.groupings) + inputs)
+        view = encoding.stage_view(self.pre_steps + (tail,), batch,
+                                   dense_tail=len(inputs))
+        wrap = {i: d for i, d in view.wrap.items() if i < nk}
+        spec = self._folded_spec(frozenset(wrap))
+        h_steps, values = hoist_steps(view.steps)
+        domain = self._dense_domain(
+            spec, batch, wrap, conf, lambda: self._probe_unfolded(batch))
+        if domain is not None:
+            radices, bases, bound = domain
+            self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
+        else:
+            radices, bases = None, ()
+            bound = max(1, min(batch.rows_bound, batch.capacity))
+        fn = _compile_folded_update(h_steps, view.sig, view.aux_sig,
+                                    batch.capacity, spec, radices)
+        n_groups, key_outs, buf_outs = fn(
+            view.flat, view.aux, norm_rows(batch), hoisted_args(values),
+            np.asarray(bases, np.int64))
+        self.metrics[METRIC_MASKED_FILTER_BATCHES].add(1)
+        return _colvals_to_batch(
+            list(key_outs) + list(buf_outs), self._buffer_dtypes(),
+            LazyRows(n_groups, bound), wrap=wrap)
+
+    def _probe_unfolded(self, batch: ColumnarBatch):
+        """The one-integer-key range probe for a folded update.  Filters
+        leave the column space alone, so the key's range over the
+        unfiltered batch bounds the kept rows'; past a projection the
+        key has no expression over the input, and the sorted body runs."""
+        if any(kind != "filter" for kind, _ in self.pre_steps):
+            return None
+        spec, vbatch, _wrap = self._agg_view("update", batch)
+        return self._probe_key_range(
+            spec, vbatch, *_fused_decode_planes(vbatch, count=False))
+
     def _try_dense_update(self, spec: _AggSpec, vbatch: ColumnarBatch,
                           wrap, conf, flat, sig, decoder):
         """Sort-free update over a key domain the host knows (see
         exec/pallas_agg.py): ``((n_groups, keys, buffers), bound)`` with
         ``bound`` the exact domain size, or None -> take the
         sorted-segment kernel.  ``spec``/``vbatch``/``wrap`` are the code
-        view of the batch (``_agg_view``).
+        view of the batch (``_agg_view``)."""
+        from spark_rapids_tpu.exec import pallas_agg as pag
+        domain = self._dense_domain(
+            spec, vbatch, wrap, conf,
+            lambda: self._probe_key_range(spec, vbatch, flat, sig,
+                                          decoder))
+        if domain is None:
+            return None
+        radices, bases, bound = domain
+        fn = pag.make_update(spec, sig, vbatch.capacity, radices,
+                             decoder=decoder)
+        out = fn(flat, vbatch.rows_traced, np.asarray(bases, np.int64))
+        self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
+        return out, bound
+
+    def _dense_domain(self, spec: _AggSpec, vbatch: ColumnarBatch, wrap,
+                      conf, probe):
+        """``(radices, bases, bound)`` of the dense update's key domain,
+        or None when the host does not know one the kernel takes.
 
         The domain is known without a pull when every key is a
         dictionary-code view (radix ``dict.size + 1``, digit 0 the null
         key) or there are no keys; one bare integer key learns its range
-        from a memoized probe instead, and the first batch whose range
-        does not fit disables that probe for this exec so
+        from ``probe()`` (memoized) instead, and the first batch whose
+        range does not fit disables that probe for this exec so
         high-cardinality aggs don't pay a blocking range check (kernel +
         host sync) per batch."""
         from spark_rapids_tpu.exec import pallas_agg as pag
-        if not (pag.enabled(conf) and pag.supports(spec)):
+        if conf is None or not (pag.enabled(conf) and pag.supports(spec)):
             return None
         if vbatch.capacity > pag.max_capacity(spec):
             # per-spec exactness bound (int64-sum limb decomposition)
@@ -544,7 +698,7 @@ class TpuHashAggregateExec(TpuExec):
             if bound > pag.MAX_K:
                 return None
         elif nk == 1 and not coded and vbatch.rows_bound > 0:
-            rng = self._probe_key_range(spec, vbatch, flat, sig, decoder)
+            rng = probe()
             if rng is None:
                 return None
             lo, hi = rng
@@ -552,11 +706,7 @@ class TpuHashAggregateExec(TpuExec):
             bound = min(vbatch.rows_bound, hi - lo + 2)
         else:
             return None
-        fn = pag.make_update(spec, sig, vbatch.capacity, radices,
-                             decoder=decoder)
-        out = fn(flat, vbatch.rows_traced, np.asarray(bases, np.int64))
-        self.metrics["pallasAggBatches"].add(1)
-        return out, bound
+        return radices, bases, bound
 
     def _probe_key_range(self, spec: _AggSpec, vbatch: ColumnarBatch,
                          flat, sig, decoder):

@@ -320,10 +320,10 @@ def range_radix(lo: int, hi: int) -> int:
     return _round_k(hi - lo + 2)
 
 
-def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
-                decoder=None):
-    """Jitted ``(flat_cols, num_rows, bases) -> (n_groups, keys, buffers)``
-    matching make_agg_body's update contract (group order identical).
+def make_update_body(spec, capacity: int, radices: Sequence[int]):
+    """The traceable update body ``(flat_cols, num_rows, bases,
+    live_mask=None) -> (n_groups, keys, buffers)``, matching
+    make_agg_body's update contract (group order identical).
 
     ``radices`` holds one host-known radix per grouping: key ``i`` is the
     digit ``key - bases[i] + 1`` (0 = null) and the slot is the mixed
@@ -334,27 +334,23 @@ def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
     live row hits slot 0 and there is always exactly one group (the
     empty-input rule, aggregate.scala:406-419).  The outputs are
     ``bucket_capacity(prod(radices))`` slots long — the partial has the
-    shape of its domain, not of its input.  ``decoder``
-    (encoding.plane_view) densifies plane-compressed triples inside the
-    jitted body; its marker-bearing ``input_sig`` keys the variant."""
+    shape of its domain, not of its input.  ``live_mask`` (optional)
+    overrides the contiguous row-liveness ``arange < num_rows``: a
+    filter folded in front of the update hands its keep-mask here, rows
+    in place (exec/aggregate.py, docs/fusion.md)."""
     radices = tuple(int(r) for r in radices)
     domain = math.prod(radices)
     K = _round_k(domain)
     out_cap = min(K, bucket_capacity(domain))
-    cache_key = (spec.key(), input_sig, capacity, radices)
-    fn = _UPDATE_CACHE.get(cache_key)
-    if fn is not None:
-        return fn
     groupings = list(spec.groupings)
     # stride of digit i = product of the radices after it
     strides = [math.prod(radices[i + 1:]) for i in range(len(radices))]
 
-    def run(flat_cols, num_rows, bases):
-        if decoder is not None:
-            flat_cols = decoder(flat_cols)
+    def run(flat_cols, num_rows, bases, live_mask=None):
         cols = [ColVal(*t) for t in flat_cols]
         ctx = EvalContext(cols, num_rows, capacity)
-        live = jnp.arange(capacity) < num_rows
+        live = live_mask if live_mask is not None \
+            else jnp.arange(capacity) < num_rows
         key_cvs = [g.emit(ctx) for g in groupings]
         gid = jnp.zeros((capacity,), jnp.int64)
         for i, kcv in enumerate(key_cvs):
@@ -477,6 +473,27 @@ def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
                     out = jnp.where(has_nan, nan_v, base)
                 buf_outs.append(ColVal(out, group_valid, None))
         return n_groups, tuple(key_outs), tuple(buf_outs)
+
+    return run
+
+
+def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
+                decoder=None):
+    """``make_update_body`` jitted as ``(flat_cols, num_rows, bases)``
+    and memoized.  ``decoder`` (encoding.plane_view) densifies
+    plane-compressed triples inside the jitted body; its marker-bearing
+    ``input_sig`` keys the variant."""
+    radices = tuple(int(r) for r in radices)
+    cache_key = (spec.key(), input_sig, capacity, radices)
+    fn = _UPDATE_CACHE.get(cache_key)
+    if fn is not None:
+        return fn
+    body = make_update_body(spec, capacity, radices)
+
+    def run(flat_cols, num_rows, bases):
+        if decoder is not None:
+            flat_cols = decoder(flat_cols)
+        return body(flat_cols, num_rows, bases)
 
     fn = engine_jit(run, family="aggregate", name="pallas_update")
     _UPDATE_CACHE[cache_key] = fn

@@ -48,6 +48,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import (
@@ -134,8 +135,21 @@ def stage_fingerprint(steps: Sequence[Step]) -> tuple:
                  for kind, exprs in steps)
 
 
+def describe_steps(steps: Sequence[Step]) -> str:
+    """The step list as ``explain()`` shows it."""
+    parts = []
+    for kind, exprs in steps:
+        if kind == "project":
+            parts.append(
+                "Project[" + ", ".join(e.name for e in exprs) + "]")
+        else:
+            parts.append(f"Filter[{exprs[0].name}]")
+    return " -> ".join(parts)
+
+
 def emit_steps(steps: Sequence[Step], cols: List[ColVal], num_rows,
-               capacity: int, partition_id, hoisted, aux=()):
+               capacity: int, partition_id, hoisted, aux=(),
+               compact: bool = True):
     """Trace the whole step chain over ``cols`` inside a jitted kernel.
     Projections evaluate and validity-mask exactly like the per-op
     projection kernel; filters compute the keep-mask, its population
@@ -143,6 +157,14 @@ def emit_steps(steps: Sequence[Step], cols: List[ColVal], num_rows,
     (the fused static-shape filter of exec/basic.py), after which the
     traced row count becomes the filter's count.  Returns
     ``(cols, num_rows)``.
+
+    ``compact=False`` is the MASKED mode, for a consumer that reduces
+    (the aggregate update, exec/aggregate.py): a filter only narrows
+    the liveness mask (``live = live & keep``) and every plane stays
+    where it is, a projection masks validity with ``live``, and the
+    return is ``(cols, live)`` — no position vector, no gather.  Row
+    position and the traced row count keep the INPUT's meaning, so a
+    chain that reads either (a nondeterministic step) must compact.
 
     Float rounding note (docs/fusion.md): XLA contracts mul+add chains
     (fma) inside one program, so a fused chain's float outputs can
@@ -154,6 +176,19 @@ def emit_steps(steps: Sequence[Step], cols: List[ColVal], num_rows,
     contraction inside fused loops regardless.  Non-float bytes and
     row order are identical by construction; row membership too,
     unless a float predicate boundary falls inside that last ulp."""
+    if not compact:
+        live = jnp.arange(capacity) < num_rows
+        for kind, exprs in steps:
+            ctx = EvalContext(cols, num_rows, capacity, partition_id,
+                              hoisted=hoisted, aux=aux)
+            if kind == "project":
+                outs = [e.emit(ctx) for e in exprs]
+                cols = [ColVal(o.data, o.validity & live, o.chars)
+                        for o in outs]
+            else:  # filter: the keep-mask IS the result
+                p = exprs[0].emit(ctx)
+                live = p.data & p.validity & live
+        return cols, live
     n = num_rows
     for kind, exprs in steps:
         ctx = EvalContext(cols, n, capacity, partition_id,
@@ -195,12 +230,16 @@ def norm_rows(batch: ColumnarBatch):
     """The traced row-count argument, normalized to a strong int32 so
     every dispatch (and the warmer's abstract signature) shares ONE
     aval regardless of whether the count is host-resident or a device
-    scalar from an upstream filter."""
-    return jnp.asarray(batch.rows_traced, jnp.int32)
+    scalar from an upstream filter.  A host count stays a host scalar:
+    the launch carries it over, where ``jnp.asarray`` would be an eager
+    device op of its own per batch."""
+    rows = batch.rows_traced
+    if isinstance(rows, (int, np.integer)):
+        return np.int32(rows)
+    return jnp.asarray(rows, jnp.int32)
 
 
 def _sig_avals(sig: tuple):
-    import numpy as np
     flat = []
     for dtype_name, cap, width in sig:
         # compressed compute-plane markers (columnar/encoding.py
@@ -241,7 +280,6 @@ def aval_inputs(input_sig: tuple, capacity: int, values,
     AOT compilation from a signature alone (the warmer path).
     ``aux_sig`` describes the compressed code view's dictionary gather
     tables (empty on the dense path)."""
-    import numpy as np
     n = jax.ShapeDtypeStruct((), np.int32)
     pid = jax.ShapeDtypeStruct((), np.int64)
     hoisted = tuple(jax.ShapeDtypeStruct((), device_dtype(dt))
@@ -478,14 +516,7 @@ class TpuStageExec(TpuExec):
         return self._has_filter
 
     def describe(self) -> str:
-        parts = []
-        for kind, exprs in self.steps:
-            if kind == "project":
-                parts.append(
-                    "Project[" + ", ".join(e.name for e in exprs) + "]")
-            else:
-                parts.append(f"Filter[{exprs[0].name}]")
-        return "TpuStage [" + " -> ".join(parts) + "]"
+        return "TpuStage [" + describe_steps(self.steps) + "]"
 
     # -- warmer -------------------------------------------------------------
 

@@ -342,8 +342,11 @@ def hoist_literals(exprs: Sequence[Expression]):
 
 
 def hoisted_args(values) -> tuple:
-    """Concrete traced-scalar call args for hoisted literal slots."""
-    return tuple(jnp.asarray(v, device_dtype(dt)) for v, dt in values)
+    """Concrete traced-scalar call args for hoisted literal slots: HOST
+    scalars, which the launch itself carries to the device — a
+    ``jnp.asarray`` per slot is an eager device op per slot per batch
+    (0.4 ms each on the chip: PERF.md, PR 28)."""
+    return tuple(np.asarray(v, device_dtype(dt)) for v, dt in values)
 
 
 def _infer_literal_type(value) -> DataType:

@@ -631,5 +631,13 @@ def _observe_node(node, cal: CalibrationStore) -> None:
                 exprs = getattr(node, "projections", None) or \
                     [getattr(node, "condition", None)]
             cls = step_class(cls, [e for e in exprs if e is not None])
-            cal.observe(engine, cls, in_rows, self_ns / 1e9)
+            # an aggregate that folded its filter / project chain
+            # (plan/fusion.py) ran the steps in its update program:
+            # they share its time evenly, as a stage's members do
+            folded = getattr(node, "pre_steps", ())
+            share = (self_ns / (1 + len(folded))) / 1e9
+            cal.observe(engine, cls, in_rows, share)
+            for kind, step_exprs in folded:
+                cal.observe(engine, step_class(kind, step_exprs),
+                            in_rows, share)
     return snap

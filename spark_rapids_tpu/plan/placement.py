@@ -350,6 +350,10 @@ def _remainder_classes(node, stage) -> List[str]:
                 pred = getattr(node, "pred", None)
                 exprs = [pred] if pred is not None else []
             out.append(cost.step_class(cls, exprs))
+            # the chain an aggregate folded in (plan/fusion.py) is still
+            # work the remainder does: score it with its own classes
+            out.extend(cost.step_class(kind, exprs) for kind, exprs
+                       in reversed(getattr(node, "pre_steps", ())))
         node = node.children[0]
     return out
 
@@ -359,7 +363,8 @@ def _demote_physical(node, stage):
     the CPU engine: each supported device operator becomes its CPU
     analog over the SAME bound expressions (both engines bind through
     ``bind_expression``, so the trees are engine-neutral), fused stages
-    expand back to project/filter chains, coalesce nodes drop (host
+    (and the steps an aggregate folded in) expand back to project/filter
+    chains, coalesce nodes drop (host
     batching needs no capacity contract), and the stage itself crosses
     through a ``DeviceToHostExec`` — its buffered device batches are
     pulled once, like any egress."""
@@ -378,14 +383,17 @@ def _demote_physical(node, stage):
     if node is stage:
         return DeviceToHostExec(stage)
     child = _demote_physical(node.children[0], stage)
-    if isinstance(node, TpuCoalesceBatchesExec):
-        return child
-    if isinstance(node, TpuStageExec):
-        cur = child
-        for kind, exprs in node.steps:
+
+    def expand(steps, cur):
+        for kind, exprs in steps:
             cur = cb.CpuProjectExec(list(exprs), cur) if kind == "project" \
                 else cb.CpuFilterExec(exprs[0], cur)
         return cur
+
+    if isinstance(node, TpuCoalesceBatchesExec):
+        return child
+    if isinstance(node, TpuStageExec):
+        return expand(node.steps, child)
     if isinstance(node, TpuProjectExec):
         return cb.CpuProjectExec(node.exprs, child)
     if isinstance(node, TpuFilterExec):
@@ -393,8 +401,10 @@ def _demote_physical(node, stage):
     if isinstance(node, TpuSortExec):
         return CpuSortExec(node.orders, child)
     if isinstance(node, TpuHashAggregateExec):
+        # the filter / project chain the aggregate folded in
+        # (plan/fusion.py) expands back below its CPU analog
         return CpuHashAggregateExec(node.groupings, node.aggregates,
-                                    child)
+                                    expand(node.pre_steps, child))
     if isinstance(node, TpuLocalLimitExec):
         return cb.CpuLocalLimitExec(node.limit, child)
     raise _Unconvertible(node.node_name)

@@ -128,8 +128,11 @@ def test_no_program_of_q1_or_q6_is_called_run(caplog):
     compiled = [m.group(1) for r in caplog.records for m in
                 [re.match(r"Compiling (?:jit\()?([\w<>]+)", r.getMessage())]
                 if m]
-    assert any(n.startswith("stage_") for n in compiled), compiled
-    assert any(n.startswith("aggregate_") for n in compiled), compiled
+    # the filter of both runs masked inside the aggregate's update
+    # (docs/fusion.md, "The aggregate fold"): no stage program at all
+    assert not [n for n in compiled if n.startswith("stage_")], compiled
+    assert any(n.startswith("aggregate_masked_") for n in compiled), \
+        compiled
     assert not [n for n in compiled if n in ("run", "body", "<lambda>")]
 
 
@@ -272,8 +275,9 @@ def _node_sums(node, out):
 
 
 @pytest.mark.parametrize("query,update", [
-    (_q1, "aggregate_update"),          # plain string keys: the sorted body
-    (_q6, "aggregate_pallas_update"),   # no keys: the host knows the domain
+    # the filter is folded into both updates (docs/fusion.md)
+    (_q1, "aggregate_masked_update"),   # plain string keys: the sorted body
+    (_q6, "aggregate_masked_pallas_update"),  # no keys: a known domain
 ], ids=["q1", "q6"])
 def test_node_device_time_adds_up_to_the_programs_group(query, update):
     s = tpu_session(TRACED)

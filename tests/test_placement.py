@@ -460,6 +460,38 @@ def test_aqe_demotes_remainder_on_wrong_static_estimate(aqe_parquet):
         ref.stop()
 
 
+def test_aqe_demotion_carries_an_aggregates_folded_filter(aqe_parquet):
+    """An aggregate that folded its filter (plan/fusion.py) is one node
+    of the remainder: the re-score counts the filter's class with the
+    aggregate's, and the demotion re-expands it into a CPU filter under
+    the CPU aggregate — the predicate is not lost with the device node."""
+    from spark_rapids_tpu.api import lit
+    from spark_rapids_tpu.plan import placement
+    from spark_rapids_tpu.plan.adaptive import find_adaptive
+    s = tpu_session(_aqe_conf())
+    try:
+        df = (s.read.parquet(aqe_parquet).filter(col("k") < 2)
+              .repartition(4, "k").filter(col("v") > -0.5)
+              .group_by("k").agg(F.sum(col("v")).alias("sv"),
+                                 F.count(lit(1)).alias("n")))
+        assert df.to_arrow().num_rows == 2
+        root = find_adaptive(s._last_plan_result.physical)
+        agg = root.children[0]
+        assert agg.describe().startswith("TpuHashAggregate") \
+            and "masked=[Filter[(v > -0.5)]]" in agg.describe()
+        stage = agg.children[0].children[0]
+        assert stage.node_name == "TpuQueryStageExec"
+        classes = placement._remainder_classes(agg, stage)
+        assert classes[0].startswith("hashaggregate") \
+            and [c for c in classes if c.startswith("filter")], classes
+        tree = placement._demote_physical(agg, stage).tree_string()
+        lines = [ln.strip().split(" ")[0] for ln in tree.splitlines()]
+        assert lines[:3] == ["CpuHashAggregate", "CpuFilter",
+                             "DeviceToHost"], tree
+    finally:
+        s.stop()
+
+
 def test_aqe_keeps_remainder_when_measured_bytes_large(aqe_parquet):
     """No filter -> the measured stage bytes match the static estimate
     and the remainder stays on the device (no demotion)."""

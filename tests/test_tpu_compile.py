@@ -128,6 +128,147 @@ def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
     assert {cv.data.shape for cv in key_outs + buf_outs} == {(out_cap,)}
 
 
+# -- the folded update: q1 and q6 as the planner hands them over ----------
+
+@pytest.fixture(scope="module")
+def folded_updates(tmp_path_factory):
+    """What ``TpuHashAggregateExec._run_folded_update`` asks
+    ``_compile_folded_update`` for while TPC-H q1 and q6 run over a
+    dictionary-encoded lineitem (20 k rows, on the CPU): the hoisted
+    steps, signatures, spec and radices of the real path, re-aimed by
+    the tests below at the scan's batch capacity.  Also the programs the
+    two queries launched."""
+    import spark_rapids_tpu.exec.aggregate as agg_mod
+    from spark_rapids_tpu.bench import tpch
+    from spark_rapids_tpu.compile import service
+    from tests.compare import tpu_session
+    real_compile, real_args = (agg_mod._compile_folded_update,
+                               agg_mod.hoisted_args)
+    asked, values = [], []
+
+    def compile_(*a):
+        asked.append(a)
+        return real_compile(*a)
+
+    def args_(v):
+        values.append(v)
+        return real_args(v)
+
+    paths = tpch.gen_tpch(str(tmp_path_factory.mktemp("fold")), 20_000)
+    agg_mod._compile_folded_update, agg_mod.hoisted_args = compile_, args_
+    before = service.ledger_rows()
+    try:
+        s = tpu_session({})
+        tables = tpch.load_tables(s, {"lineitem": paths["lineitem"]})
+        out = {}
+        for name in ("q1", "q6"):
+            del asked[:], values[:]
+            assert tpch.TPCH_QUERIES[name](tables).to_arrow().num_rows
+            out[name] = (asked[0], values[0])
+        s.stop()
+    finally:
+        agg_mod._compile_folded_update = real_compile
+        agg_mod.hoisted_args = real_args
+    after = service.ledger_rows()
+    out["launched"] = sorted(
+        p for p, row in after.items() if row["dispatches"]
+        > before.get(p, {"dispatches": 0})["dispatches"])
+    return out
+
+
+def _folded_at_cap(folded_updates, query, sharding=None):
+    """(program, avals) of ``query``'s folded update at ``CAP``."""
+    import spark_rapids_tpu.exec.aggregate as agg_mod
+    from spark_rapids_tpu.exec import stage
+    (h_steps, sig, aux_sig, cap, spec, radices), values = \
+        folded_updates[query]
+    assert radices is not None  # both take the dense body
+    sig = tuple((name, CAP if c == cap else c, w) for name, c, w in sig)
+    agg_mod._AGG_CACHE.clear()
+    prog = agg_mod._compile_folded_update(h_steps, sig, aux_sig, CAP,
+                                          spec, radices)
+    agg_mod._AGG_CACHE.clear()
+    flat, aux, n, _pid, hoisted = stage.aval_inputs(sig, CAP, values,
+                                                    aux_sig)
+    avals = (flat, aux, n, hoisted,
+             jax.ShapeDtypeStruct((len(radices),), jnp.int64))
+    if sharding is not None:
+        avals = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), avals)
+    return prog, avals
+
+
+def _row_plane_moves(jaxpr) -> list:
+    """Gathers and scatters of ``jaxpr``, outside any Pallas call, that
+    read, write or index a capacity-long array."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            continue
+        if name == "gather" or name.startswith("scatter"):
+            shapes = [v.aval.shape for v in eqn.invars]
+            if any(CAP in shape for shape in shapes):
+                found.append((name, shapes))
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_row_plane_moves(sub))
+    return found
+
+
+@pytest.mark.parametrize("query", ["q1", "q6"])
+def test_folded_update_moves_no_row_plane(folded_updates, query):
+    """The gathers cannot come back unseen: with the filter folded in,
+    what is left outside the Pallas call is elementwise (the predicate,
+    the slot, the masked planes) and K-slot work at the end — no gather
+    and no scatter over 2^20 rows.  And neither query launched a
+    ``stage_*`` program."""
+    prog, avals = _folded_at_cap(folded_updates, query)
+    jaxpr = prog.trace(*avals).jaxpr
+    assert "pallas_call" in str(jaxpr)
+    assert _row_plane_moves(jaxpr.jaxpr) == []
+    launched = folded_updates["launched"]
+    assert "aggregate_masked_pallas_update" in launched
+    assert not [p for p in launched if p.startswith("stage_")], launched
+
+
+def test_a_compacting_filter_is_what_the_guard_would_catch():
+    """The guard's own control: the stage compiler's filter, as every
+    other consumer still runs it, gathers every plane."""
+    from spark_rapids_tpu.columnar.dtypes import FLOAT64
+    from spark_rapids_tpu.exec import stage
+    from spark_rapids_tpu.exprs.base import BoundReference, Literal
+    from spark_rapids_tpu.exprs.predicates import GreaterThan
+    steps = (("filter", (GreaterThan(BoundReference(0, FLOAT64, True, "v"),
+                                     Literal(1.5, FLOAT64)),)),)
+    sig = (("double", CAP, 0),)
+    jaxpr = jax.jit(stage._build_stage_fn(steps, CAP)).trace(
+        *stage.aval_inputs(sig, CAP, ())).jaxpr
+    assert _row_plane_moves(jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("query", ["q1", "q6"])
+def test_folded_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
+                                        folded_updates, query):
+    """The whole program the chip runs per batch of q1 and q6 since the
+    fold — predicate, mixed-radix slot, Mosaic accumulation, K-slot
+    tail — at 2^20 rows with the device's f32 doubles; its optimized
+    HLO moves no 2^20-long plane through a gather or a scatter."""
+    from spark_rapids_tpu.columnar import dtypes
+    monkeypatch.setattr(dtypes, "_DOUBLE_AS_FLOAT", True)
+    prog, avals = _folded_at_cap(folded_updates, query, one_chip)
+    text = prog.lower(*avals).compile().as_text()
+    assert "tpu_custom_call" in text
+    moves = [ln.strip()[:160] for ln in text.splitlines()
+             if (" gather(" in ln or " scatter(" in ln)
+             and f"[{CAP}" in ln]
+    assert moves == []
+
+
 def test_pallas_agg_refuses_64bit_planes_on_the_chip(mosaic):
     """What ``supports()`` must never admit raises, typed, at trace
     time — it does not run another path."""
