@@ -7,9 +7,9 @@ Loads the cell (``workloads/<cell>.json``) and its configuration
 (``configs/<config>.json``), makes the data from ``--seed``, brings the
 engine up under the configuration's ``conf`` through the normal entry point,
 warms every program the cell's traffic uses (all of that is ``setup_s``),
-drives the window, frees the engine, and only then runs the plain reference
-and compares every answer the window returned.  It knows two kinds of
-traffic (``stream``, ``served``) and nothing about any suite, query or
+drives the window in whole rounds, frees the engine, and only then runs the
+plain reference and compares every answer the window returned.  It knows two
+kinds of traffic (``stream``, ``served``) and nothing about any suite, query or
 metric: those are files found by the names in ``BENCHMARK.json``
 (``README.md``).  One process, one chip per device the configuration asks
 for; without a TPU it exits 2 and prints no result.  Every line on standard
@@ -24,12 +24,14 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
+import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import io  # noqa: E402
 import itertools  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
 from types import SimpleNamespace  # noqa: E402
@@ -41,6 +43,8 @@ TRACE_ROOT = os.path.join(HERE, ".trace")  # ignored; emptied after reading
 FALLBACK_COUNTERS = ("ici.fallbacks", "ooc.fallbacks", "compile.aotFailures",
                      "fusion.warm_errors")
 ANSWER_WAIT_S = 60  # how long past the close a served answer is waited for
+STALL_FACTOR = 10   # a round over this many times the median is a stall
+LONGEST = 5         # how many stalls and collections the window line lists
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -143,14 +147,52 @@ def ensure_data(datagen, suite: str, rows: int, seed: int) -> dict:
     return paths
 
 
-def plan_nodes(sess) -> list:
-    """``[{name, describe, metrics}]`` down the last executed plan."""
-    out, stack = [], [sess.last_query_profile().to_dict()["plan"]]
+class GcClock:
+    """The collector's pauses while it is entered (``gc.callbacks``: two
+    clock reads a collection): ``(start, generation, seconds)`` of each.
+    The collector itself is left as a deployment runs it."""
+
+    def __init__(self):
+        self.pauses = []
+        self._began = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        now = time.perf_counter()
+        if phase == "start":
+            self._began = now
+        elif self._began is not None:
+            self.pauses.append((self._began, info["generation"],
+                                now - self._began))
+            self._began = None
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        gc.callbacks.remove(self)
+
+    def summary(self, since: float) -> dict:
+        """Collections by generation, their total seconds, and the longest
+        as ``[seconds into the window, generation, seconds]``."""
+        by_generation = [0, 0, 0]
+        for _, generation, _ in self.pauses:
+            by_generation[generation] += 1
+        worst = sorted(self.pauses, key=lambda p: -p[2])[:LONGEST]
+        return {"collections": by_generation,
+                "seconds": sum(p[2] for p in self.pauses),
+                "longest": [[round(t - since, 6), g, round(s, 6)]
+                            for t, g, s in worst]}
+
+
+def executed_nodes(sess):
+    """The nodes of the last executed plan (``OperatorProfile``: ``name``,
+    ``describe``, ``metrics``, ``children``), parents first."""
+    stack = [sess.last_query_profile().root]
     while stack:
         node = stack.pop()
-        out.append({k: node[k] for k in ("name", "describe", "metrics")})
-        stack.extend(node["children"])
-    return out
+        yield node
+        stack.extend(node.children)
 
 
 def off_device_nodes(df) -> list:
@@ -189,17 +231,71 @@ def whole_rounds(one_round, start: float, seconds: float,
             return
 
 
+def rounds_of(executions, per_round: int) -> list:
+    """The executions as the rounds ``whole_rounds`` made of them: each
+    stream's, in order, cut into rounds of ``per_round``.  ``[{stream,
+    start, seconds, queries, ok}]`` in the order they started; a round's
+    ``seconds`` are the sum of its executions' own ``end - start``, so
+    nothing the harness does between two executions is in them."""
+    by_stream = {}
+    for e in sorted(executions, key=lambda e: e["start"]):
+        by_stream.setdefault(e.get("stream", 0), []).append(e)
+    out = []
+    for stream, mine in by_stream.items():
+        for i in range(0, len(mine) - per_round + 1, per_round):
+            part = mine[i:i + per_round]
+            out.append({"stream": stream, "start": part[0]["start"],
+                        "seconds": sum(e["end"] - e["start"] for e in part),
+                        "queries": per_round,
+                        "ok": all(e["ok"] for e in part)})
+    return sorted(out, key=lambda r: r["start"])
+
+
+def rounds_summary(rounds, streams: int, since: float) -> dict:
+    """What a run says of its rounds: how many, the median, the highest
+    percentile with ten rounds beyond it, the longest, and the rounds over
+    ``STALL_FACTOR`` times the median (``[stream, seconds into the window,
+    seconds]`` of the longest few).  ``median_s_per_query`` is the median
+    round over its queries and the ``streams`` that run at once: what
+    ``query_s`` would read if every round took as long as the middle one,
+    a diagnostic that no stall moves and no bound holds."""
+    took = sorted(r["seconds"] for r in rounds if r["ok"])
+    out = {"count": len(rounds), "failed": len(rounds) - len(took)}
+    if not took:
+        return out
+    median = statistics.median(took)
+    out["median_s_per_query"] = median / (rounds[0]["queries"] * streams)
+    slow = sorted((r for r in rounds
+                   if r["ok"] and r["seconds"] > STALL_FACTOR * median),
+                  key=lambda r: -r["seconds"])
+    out.update(median_s=median, longest_s=took[-1])
+    if len(took) > 10:
+        out["tail"] = {"percentile": 100.0 * (len(took) - 10) / len(took),
+                       "seconds": took[-11]}
+    out["stalls"] = {"count": len(slow),
+                     "seconds": sum(r["seconds"] for r in slow),
+                     "longest": [[r["stream"], round(r["start"] - since, 6),
+                                  round(r["seconds"], 6)]
+                                 for r in slow[:LONGEST]]}
+    return out
+
+
 class Stream:
     """Kind ``stream``: one client replays the cell's ordered list of
     queries in whole passes."""
+
+    streams = 1
 
     def __init__(self, cell, config, sess, tables, seed):
         self.cell, self.sess, self.tables = cell, sess, tables
         self.builders = load_module("queries", query_suite(cell, config)
                                     + ".py")
         self.names = list(cell["queries"])
-        self.plans = []     # the executed plan of every timed query
-        self.executed = []  # and the DataFrame it was made from
+        self.per_round = len(self.names)
+        self.keep_plans = False  # a traced window's per-layer metrics do
+        self.plans = []      # then: the executed plan of every timed query
+        self.cpu_nodes = []  # always: every executed plan's ``Cpu*`` nodes
+        self.explained = {}  # one DataFrame of each distinct query
 
     def _execute(self, name: str) -> dict:
         from jax.profiler import TraceAnnotation
@@ -209,20 +305,31 @@ class Stream:
         with TraceAnnotation(f"bench.to_arrow:{name}"):
             table = df.to_arrow()
         end = time.perf_counter()
-        self.plans.append(plan_nodes(self.sess))
-        self.executed.append(df)
+        # what follows lies between two executions, inside the window and
+        # so inside ``query_s``: it keeps only what is read after the close.
+        # ``explain()`` plans anew from the DataFrame's logical plan and the
+        # session's conf, and a builder makes the same logical plan of the
+        # same name and tables every time: one DataFrame a query will do
+        nodes = list(executed_nodes(self.sess))
+        self.cpu_nodes += [n.describe for n in nodes
+                           if n.name.startswith("Cpu")]
+        if self.keep_plans:
+            self.plans.append([{"name": n.name, "describe": n.describe,
+                                "metrics": n.metrics} for n in nodes])
+        self.explained.setdefault(name, df)
         return {"name": name, "params": None, "start": start, "end": end,
                 "ok": True, "table": table}
 
     def warm(self) -> None:
         for name in self.names:
             self._execute(name)
-        self.plans.clear()
-        self.executed.clear()
+        self.cpu_nodes.clear()
+        self.explained.clear()
 
     def window(self, seconds: float, traced: bool) -> list:
         """Whole passes of the list (``whole_rounds``); a traced run makes
-        ``traced_passes``."""
+        ``traced_passes`` and keeps their plans."""
+        self.keep_plans = traced
         done = []
         whole_rounds(
             lambda: done.extend(self._execute(n) for n in self.names),
@@ -231,12 +338,11 @@ class Stream:
         return done
 
     def off_device(self) -> list:
-        """Nodes off the device among the plans the window executed: the
-        executed plans' own ``Cpu*`` nodes, and what ``explain()`` tags on
-        the very DataFrames that ran."""
-        return [n["describe"] for plan in self.plans for n in plan
-                if n["name"].startswith("Cpu")] + \
-            [ln for df in self.executed for ln in off_device_nodes(df)]
+        """Nodes off the device among the plans the window executed: every
+        executed plan's own ``Cpu*`` nodes, and what ``explain()`` tags on
+        each distinct query that ran."""
+        return self.cpu_nodes + [ln for df in self.explained.values()
+                                 for ln in off_device_nodes(df)]
 
     def reference(self, ref, paths, execution):
         return ref.QUERIES[execution["name"]](paths)
@@ -257,6 +363,8 @@ class Served:
         self.server = sess.server()
         self.stmts = {n: self.server.prepare(self.traffic.sql[n])
                       for n in self.traffic.templates}
+        self.streams = self.traffic.streams
+        self.per_round = len(self.traffic.cycle)
         self.plans = []  # a served request's plan is not exposed
         self.sent = set()  # every (template, binding) the window sent
 
@@ -290,8 +398,7 @@ class Served:
         start = time.perf_counter()
         traced_cycles = int(self.cell.get("traced_cycles", 1)) \
             if traced else None
-        per_cycle = len(self.traffic.cycle)
-        results = [[] for _ in range(self.traffic.streams)]
+        results = [[] for _ in range(self.streams)]
 
         def client(i: int) -> None:
             requests = self.traffic.stream(i)
@@ -299,12 +406,12 @@ class Served:
                 lambda: results[i].extend(
                     self._request(name, params, seconds + ANSWER_WAIT_S)
                     for name, params in itertools.islice(requests,
-                                                         per_cycle)),
+                                                         self.per_round)),
                 start, seconds, traced_cycles)
 
         threads = [threading.Thread(target=client, args=(i,),
                                     name=f"bench-stream-{i}")
-                   for i in range(self.traffic.streams)]
+                   for i in range(self.streams)]
         for t in threads:
             t.start()
         for t in threads:
@@ -420,6 +527,8 @@ def main(argv=None) -> int:
     cell = load_json("workloads", f"{args.workload}.json")
     config = load_json("configs", f"{entry['config']}.json")
     device = device_gate(int(entry["chips"]))
+    emit({"phase": "start", "workload": args.workload, "seed": args.seed,
+          "seconds": args.seconds, "trace": args.trace})
 
     # XLA's persistent cache at a fixed path inside the checkout: the engine
     # takes JAX_COMPILATION_CACHE_DIR where it is set and sets no other
@@ -452,6 +561,7 @@ def main(argv=None) -> int:
               "cache_dir": jax.config.jax_compilation_cache_dir})
 
         stats_before = sess.engine_stats()
+        collector = GcClock()
         window_start = time.perf_counter()
         setup_s = window_start - T_START
         if traced:
@@ -460,12 +570,13 @@ def main(argv=None) -> int:
             options.enable_hlo_proto = False
             jax.profiler.start_trace(trace_dir, profiler_options=options)
             try:
-                with jax.profiler.TraceAnnotation("bench.window"):
+                with jax.profiler.TraceAnnotation("bench.window"), collector:
                     executions = traffic.window(args.seconds, True)
             finally:
                 jax.profiler.stop_trace()
         else:
-            executions = traffic.window(args.seconds, False)
+            with collector:
+                executions = traffic.window(args.seconds, False)
         window_end = time.perf_counter()
         stats_after = sess.engine_stats()
         device["memory_peak_bytes"] = memory_peak_bytes(device)
@@ -488,10 +599,12 @@ def main(argv=None) -> int:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
 
+    rounds = rounds_of(executions, traffic.per_round)
+    last_end = max([e["end"] for e in executions] + [window_start])
     run = SimpleNamespace(
-        executions=executions, window_start=window_start,
-        window_end=max([e["end"] for e in executions] + [window_start]),
-        setup_s=setup_s, compile_times=list(clock.times),
+        executions=executions, streams=traffic.streams,
+        window_start=window_start, window_end=last_end, setup_s=setup_s,
+        compile_times=list(clock.times),
         stats_before=stats_before, stats_after=stats_after, plans=plans,
         trace=reduced, config=config, cell=cell)
     if traced:
@@ -510,9 +623,15 @@ def main(argv=None) -> int:
     emit({"phase": "window", "seconds": window_end - window_start,
           "compiles_in_window": sum(window_start <= t <= window_end
                                     for t in clock.times),
+          # all the work over all the time, as ``query_s`` reads it
+          # (``readers/seconds_per_query.py``), beside the rounds
+          "mean_s_per_query": (last_end - window_start)
+          / max(1, sum(e["ok"] for e in executions)),
+          "rounds": rounds_summary(rounds, traffic.streams, window_start),
+          "gc": collector.summary(window_start),
           "executions": [[e.get("stream", 0), e["name"],
-                          round(e["start"] - window_start, 3),
-                          round(e["end"] - e["start"], 3)]
+                          round(e["start"] - window_start, 6),
+                          round(e["end"] - e["start"], 6)]
                          for e in executions],
           "errors": [e["error"] for e in executions if not e["ok"]][:5],
           "off_device_nodes": fallbacks["off_device_nodes"][:5]})

@@ -22,8 +22,9 @@ REPO = os.path.dirname(BENCH)
 REHEARSAL_ROWS = 60_000
 CELLS = ("tpch_sf1.agg",)
 # a throw-away cell of kind ``served``, made of files and entries alone in
-# the temporary copy (``Copy.add_served_cell``): no such cell is listed yet
-SERVED = "tpch_sf1_served.q6_streams"
+# the temporary copy (``Copy.add_served_cell``), as a later PR would bring one
+SERVED = "tpch_sf1_q6served.q6_streams"
+SERVED_METRICS = ("query_s", "query_s.q6", "compiles_in_window.served")
 
 
 def load(path: str, name: str):
@@ -63,54 +64,44 @@ class Copy:
 
     def run(self, capsys, workload: str, trace: int = 0, seed: int = 7,
             seconds: float = 2.0) -> dict:
-        """One run of the command; its last line, parsed."""
+        """One run of the command; its last line, parsed, with what it
+        wrote to standard error and its phase lines by phase."""
         capsys.readouterr()
         rc = self.harness.main(["--workload", workload, "--seed", str(seed),
                                 "--seconds", str(seconds), "--trace",
                                 str(trace)])
         out, err = capsys.readouterr()
         assert rc == 0
-        result = json.loads(out.strip().splitlines()[-1])
+        lines = [json.loads(ln) for ln in out.strip().splitlines()]
+        result = lines[-1]
         result["stderr"] = err
+        result["phases"] = {ln["phase"]: ln for ln in lines if "phase" in ln}
         return result
 
     def add_served_cell(self) -> str:
         """Two closed-loop streams of the Q6 template through
-        ``session.server()``: a configuration, a cell, three metrics."""
+        ``session.server()``: a configuration, a cell, and its name in the
+        ``workloads`` of metrics that are there."""
+        config_name, traffic = SERVED.split(".")
         with open(os.path.join(self.bench, "configs", "tpch_sf1.json")) as fh:
             config = json.load(fh)
-        config.update(name="tpch_sf1_served", entry="server")
-        self.write_json("benchmark/configs/tpch_sf1_served.json", config)
+        config.update(name=config_name, entry="server")
+        self.write_json(f"benchmark/configs/{config_name}.json", config)
         self.write_json(f"benchmark/workloads/{SERVED}.json", {
-            "config": "tpch_sf1_served", "kind": "served", "streams": 2,
+            "config": config_name, "kind": "served", "streams": 2,
             "cycle": ["q6"], "traced_cycles": 2, "why": "throw-away"})
-        metrics = {
-            "served_qps": ("queries/s", "higher",
-                           {"reader": "completed_per_second", "args": {}}),
-            "latency_p95_s.served": ("s", "lower", {
-                "reader": "latency_percentile", "args": {"p": 95}}),
-            "compiles_in_window.served": ("count", "lower", {
-                "reader": "compile_events", "args": {}})}
-        for name, (_, _, spec) in metrics.items():
-            self.write_json(f"benchmark/metrics/{name}.json", spec)
 
         def add(bench):
             bench["configs"].append({
-                "name": "tpch_sf1_served", "source": "throw-away",
-                "file": "benchmark/configs/tpch_sf1_served.json",
+                "name": config_name, "source": "throw-away",
+                "file": f"benchmark/configs/{config_name}.json",
                 "reduced": [], "why": "throw-away"})
             bench["workloads"].append({
-                "name": SERVED, "config": "tpch_sf1_served",
-                "traffic": "q6_streams", "chips": 1, "why": "throw-away"})
-            unit, better, _ = metrics.pop("served_qps")
-            bench["end_to_end"].append({
-                "name": "served_qps", "unit": unit, "better": better,
-                "bound": 0.1, "source": "host_clock", "workloads": [SERVED]})
-            for name, (unit, better, _) in metrics.items():
-                bench["per_layer"].append({
-                    "name": name, "unit": unit, "better": better,
-                    "source": "host_clock", "layer": "serving",
-                    "moves": "served_qps", "workloads": [SERVED]})
+                "name": SERVED, "config": config_name,
+                "traffic": traffic, "chips": 1, "why": "throw-away"})
+            for m in bench["end_to_end"] + bench["per_layer"]:
+                if m["name"] in SERVED_METRICS:
+                    m["workloads"].append(SERVED)
 
         self.edit_json("BENCHMARK.json", add)
         return SERVED

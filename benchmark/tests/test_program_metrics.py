@@ -33,9 +33,15 @@ def test_metric_file_and_entry(name):
         os.path.join(BENCH, "readers", spec["reader"] + ".py"))
     assert spec["args"]["path"].split(".")[0] in ("programs", "phases")
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        entry = next(m for m in json.load(fh)["per_layer"]
-                     if m["name"] == name)
-    assert entry["workloads"] == [CELL] and entry["moves"] == "query_s"
+        bench = json.load(fh)
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    # held to the cells ``BENCHMARK.json`` lists, the batch cell among them,
+    # each of which reports the end-to-end metric it moves
+    moves = next(m for m in bench["end_to_end"] if m["name"] == "query_s")
+    assert entry["moves"] == "query_s" and CELL in entry["workloads"]
+    assert set(entry["workloads"]) <= set(moves["workloads"]) \
+        <= {w["name"] for w in bench["workloads"]}
+    assert len(set(entry["workloads"])) == len(entry["workloads"])
     assert entry["source"] == ("device_trace" if name ==
                                "device_time_accounted_share"
                                else "program_counter")

@@ -5,6 +5,7 @@ clause 2.4.1.3's, the reference's Q1 at the validation DELTA is
 ``reference/tpch.py``'s ``q1``, and answers swapped between two bindings
 come out not correct."""
 
+import datetime as dt
 import json
 import os
 
@@ -108,6 +109,80 @@ def test_reference_q1_at_the_validation_delta(copy, capsys):
     counts = [sum(streams.TEMPLATES["q1"](paths, (d,))
                   .column("count_order").to_pylist()) for d in (60, 90, 120)]
     assert counts[0] > counts[1] > counts[2]
+
+
+@pytest.mark.parametrize("delta", (60, 90, 120))
+def test_q1_is_a_filter_first_q1_written_out_straight(copy, capsys, delta):
+    """PR 30 took what no binding changes (group codes, products) out of the
+    reference's Q1 and filters afterwards; the answer is, cell for cell, a
+    Q1 that filters first and is written out here with nothing shared."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    config = copy.harness.load_json("configs", "tpch_sf1_served.json")
+    datagen = copy.harness.load_module("datagen", "tpch.py")
+    paths = copy.harness.ensure_data(datagen, "tpch", config["scale_rows"], 7)
+    capsys.readouterr()
+    t = pq.read_table(paths["lineitem"])
+    last = dt.date(1998, 12, 1) - dt.timedelta(days=delta)
+    keep = (t.column("l_shipdate").cast(pa.int32()).to_numpy()
+            <= (last - dt.date(1970, 1, 1)).days)
+    t = t.filter(pa.array(keep))
+    pairs = list(zip(t.column("l_returnflag").to_pylist(),
+                     t.column("l_linestatus").to_pylist()))
+    groups = sorted(set(pairs))
+    code = np.array([groups.index(p) for p in pairs])
+    qty, price, disc, tax = (
+        t.column(c).to_numpy().astype(np.float64) for c in (
+            "l_quantity", "l_extendedprice", "l_discount", "l_tax"))
+    disc_price = price * (1.0 - disc)
+
+    def total(x):
+        return np.bincount(code, weights=x, minlength=len(groups))
+
+    count = np.bincount(code, minlength=len(groups))
+    straight = pa.table({
+        "l_returnflag": [g[0] for g in groups],
+        "l_linestatus": [g[1] for g in groups],
+        "sum_qty": total(qty), "sum_base_price": total(price),
+        "sum_disc_price": total(disc_price),
+        "sum_charge": total(disc_price * (1.0 + tax)),
+        "avg_qty": total(qty) / count, "avg_price": total(price) / count,
+        "avg_disc": total(disc) / count,
+        "count_order": count.astype(np.int64)})
+    streams = copy.harness.load_module("reference", "tpch_streams.py")
+    assert streams.TEMPLATES["q1"](paths, (delta,)).equals(straight)
+
+
+def test_a_column_is_read_once_and_answers_are_as_fresh_ones(copy, capsys,
+                                                            monkeypatch):
+    """The reference reads each parquet column once a process; a memoised
+    answer is, cell for cell, the answer of a process that read anew."""
+    import pyarrow.parquet as pq
+    config = copy.harness.load_json("configs", "tpch_sf1_served.json")
+    datagen = copy.harness.load_module("datagen", "tpch.py")
+    paths = copy.harness.ensure_data(datagen, "tpch", config["scale_rows"], 7)
+    capsys.readouterr()
+    reads = []
+    sound = pq.read_table
+    monkeypatch.setattr(pq, "read_table", lambda path, columns: (
+        reads.extend(columns), sound(path, columns=columns))[1])
+    q6 = (dt.date(1993, 1, 1), dt.date(1994, 1, 1), 0.02, 0.04, 25.0)
+    bindings = [("q1", (60,)), ("q6", q6), ("q1", (120,)), ("q1", (60,))]
+    memoised = copy.harness.load_module("reference", "tpch_streams.py")
+    answers = [memoised.TEMPLATES[n](paths, p) for n, p in bindings]
+    assert sorted(reads) == sorted({
+        "l_shipdate", "l_returnflag", "l_linestatus", "l_quantity",
+        "l_extendedprice", "l_discount", "l_tax"})  # each once
+    for (name, params), answer in zip(bindings, answers):
+        fresh = copy.harness.load_module("reference", "tpch_streams.py")
+        assert fresh.TEMPLATES[name](paths, params).equals(answer)
+        low = fresh.TEMPLATES[name](paths, params, "bfloat16")
+        assert low.equals(memoised.TEMPLATES[name](paths, params, "bfloat16"))
+        assert not low.equals(answer)
+    assert len(reads) > 7  # the fresh modules did read anew
+    with pytest.raises(ValueError):  # what is shared is read-only
+        memoised._tpch._columns(paths["lineitem"], ["l_tax"])["l_tax"][0] = 1
 
 
 def test_answers_swapped_between_bindings_are_not_correct(copy, capsys,
