@@ -648,77 +648,6 @@ def decode_plane_late(col) -> DeviceColumn:
     return DeviceColumn(col.dtype, data, col.validity, col.rows_raw)
 
 
-def plane_view(batch, count: bool = True):
-    """Fused-decode view of a batch for compiled whole-batch consumers
-    (aggregate update, sort): flat triples where plane-compressed
-    columns ride their COMPRESSED planes, a signature with per-encoding
-    markers (cache keys must not collide with the dense layout), and a
-    traceable ``decode(flat_cols)`` the consumer composes INSIDE its
-    jitted body — one dispatch, decode fused, counted ``fusedDecodes``.
-    Returns None when no column is plane-compressed.  ``count=False``
-    defers the fusedDecodes bump to the caller (``count_fused_decodes``)
-    for probe paths that may not end up dispatching the view."""
-    cols = batch.columns
-    if not any(isinstance(c, _PLANE_TYPES) for c in cols):
-        return None
-    flat, sig, decs = [], [], []
-    for c in cols:
-        if isinstance(c, RleColumn):
-            rcap = int(c.run_values.shape[0])
-            flat.append((c.run_values, c.validity, c.run_ends))
-            sig.append((f"@rle:{c.dtype.name}", rcap, c.capacity))
-            decs.append(("rle", c.capacity, rcap))
-            if count:
-                _bump("fused_decodes")
-        elif isinstance(c, DeltaColumn):
-            flat.append((c.deltas, c.validity, c.base))
-            sig.append((f"@delta:{c.dtype.name}:{c.deltas.dtype}",
-                        c.capacity, 0))
-            decs.append(("delta", device_dtype(c.dtype)))
-            if count:
-                _bump("fused_decodes")
-        elif isinstance(c, PackedBoolColumn):
-            flat.append((c.packed, c.validity, None))
-            sig.append(("@packed", int(c.packed.shape[0]), c.capacity))
-            decs.append(("packed", c.capacity))
-            if count:
-                _bump("fused_decodes")
-        else:
-            width = c.string_width if c.chars is not None else 0
-            flat.append((c.data, c.validity, c.chars))
-            sig.append((c.dtype.name, c.capacity, width))
-            decs.append(None)
-    decs = tuple(decs)
-
-    def decode(flat_cols):
-        out = []
-        for t, d in zip(flat_cols, decs):
-            if d is None:
-                out.append(t)
-            elif d[0] == "rle":
-                rv, valid, re_ = t
-                out.append((_rle_dense(rv, re_, valid, d[1], d[2]),
-                            valid, None))
-            elif d[0] == "delta":
-                deltas, valid, base = t
-                out.append((_delta_dense(deltas, base, valid, d[1]),
-                            valid, None))
-            else:
-                packed, valid, _ch = t
-                out.append((_packed_dense(packed, d[1]), valid, None))
-        return tuple(out)
-
-    return tuple(flat), tuple(sig), decode
-
-
-def count_fused_decodes(batch) -> None:
-    """The deferred fusedDecodes bump for a ``plane_view(count=False)``
-    the caller decided to dispatch."""
-    for c in batch.columns:
-        if isinstance(c, _PLANE_TYPES):
-            _bump("fused_decodes")
-
-
 # ---------------------------------------------------------------------------
 # ingest: arrow -> EncodedColumn
 # ---------------------------------------------------------------------------
@@ -1707,87 +1636,8 @@ def rekey_for_join(col: EncodedColumn, build_dict: DictPlanes
 
 
 # ---------------------------------------------------------------------------
-# group-by code view (exec/aggregate.py)
+# codes-preserving flatten for plane-gathering kernels
 # ---------------------------------------------------------------------------
-
-def agg_code_view(batch, groupings, value_exprs: Sequence = ()):
-    """The aggregate UPDATE phase's code view: every grouping that is a
-    bare reference to an encoded column groups by CODES (ranks — so
-    segment boundaries, representatives, and output order are
-    byte-identical to grouping by the strings), with the key output
-    re-wrapped onto the same dictionary.  Aggregate VALUE inputs stay
-    in the value domain — a viewed column must not also feed one
-    (``value_exprs``), else the view bails to dense.
-
-    Returns ``(batch2, groupings2, wrap)`` where ``wrap`` maps grouping
-    position -> DictPlanes, or ``None`` when the view is the identity.
-    ``batch2`` substitutes a plain INT32 codes column for each viewed
-    encoded column, so `_flatten_batch`/`_batch_signature` see int32
-    planes and the sort keys are code comparisons."""
-    from spark_rapids_tpu.columnar.batch import ColumnarBatch
-    from spark_rapids_tpu.exprs.base import Alias, BoundReference
-
-    if not _ENABLED or not has_encoded(batch):
-        return None
-
-    def ref_of(g):
-        t = g.children[0] if isinstance(g, Alias) else g
-        return t if isinstance(t, BoundReference) else None
-
-    # columns a VALUE-domain expression reads (non-bare groupings and
-    # every aggregate input projection) must keep dense planes
-    candidates = set()
-    for g in groupings:
-        t = ref_of(g)
-        if t is not None:
-            candidates.add(t.ordinal)
-    other_refs = set()
-    for g in groupings:
-        t = ref_of(g)
-        if t is None or t.ordinal not in candidates:
-            other_refs |= _refs(g)
-    for e in value_exprs:
-        other_refs |= _refs(e)
-
-    viewable: Dict[int, DictPlanes] = {}
-    groupings2 = []
-    for g in groupings:
-        t = ref_of(g)
-        c = batch.columns[t.ordinal] if t is not None \
-            and t.ordinal < len(batch.columns) else None
-        if t is not None and isinstance(c, EncodedColumn) \
-                and t.ordinal not in other_refs:
-            viewable[t.ordinal] = c.dict
-            groupings2.append(BoundReference(
-                t.ordinal, INT32, t.nullable, t.col_name))
-        else:
-            groupings2.append(g)
-    # UNREFERENCED encoded columns also flatten as codes — the kernel
-    # never reads their planes, and flattening dense would force the
-    # very decode this view exists to avoid
-    passive = {i for i, c in enumerate(batch.columns)
-               if isinstance(c, EncodedColumn)
-               and i not in viewable and i not in other_refs
-               and not any(
-                   ref_of(g) is not None and ref_of(g).ordinal == i
-                   for g in groupings)}
-    if not viewable and not passive:
-        return None
-
-    cols2 = []
-    for i, c in enumerate(batch.columns):
-        if i in viewable or i in passive:
-            cols2.append(DeviceColumn(INT32, c.codes, c.validity,
-                                      c.rows_raw))
-        else:
-            cols2.append(c)
-    batch2 = ColumnarBatch(cols2, batch.rows_raw, batch.schema)
-    wrap = {gi: viewable[ref_of(g).ordinal]
-            for gi, g in enumerate(groupings)
-            if ref_of(g) is not None
-            and ref_of(g).ordinal in viewable}
-    return batch2, groupings2, wrap
-
 
 def col_planes(c, as_codes: bool) -> Tuple[tuple, tuple]:
     """THE per-column flatten convention for plane-gathering kernels:

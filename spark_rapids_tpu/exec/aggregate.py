@@ -18,18 +18,22 @@ TPU design — sort-based segmented reduction in ONE fused kernel per batch:
 The merge phase runs the same kernel shape over concatenated partials with
 the merge ops.  All shapes static; only the final group count syncs to host.
 
-An update whose key domain the host already knows (every key a dictionary
-code, no keys at all, or one integer key of small probed range) skips the
-sort: ``_try_dense_update`` reduces it by direct address over K slots
-(exec/pallas_agg.py) and its partial is K slots long, so the concat, the
-merge and everything downstream run at the domain's capacity, not the
-input's.
+Every update takes ONE route (``TpuHashAggregateExec._run_update``):
+the filter / project chain the planner folded into the node
+(plan/fusion.py, docs/fusion.md; empty when nothing was folded) plus a
+last projection of the keys and inputs go through one code view
+(``encoding.stage_view``), run MASKED inside the update's own program
+(``exec.stage.emit_steps(..., compact=False)``) and the keep-mask is the
+update's liveness, so a folded filter costs one elementwise predicate
+and no compaction gather.  ``_compile_folded_update`` is the one builder.
 
-A filter / project chain whose only consumer is this update is FOLDED
-into it by the planner (plan/fusion.py, docs/fusion.md): the steps run
-masked inside the update's own program (``exec.stage.emit_steps(...,
-compact=False)``) and the keep-mask is the update's liveness, so the
-filter costs one elementwise predicate and no compaction gather.
+The body under that mask is chosen from what the host can see of the
+batch (``_dense_domain``): a key domain the host already knows (every
+key a dictionary code, no keys at all, or one integer key of small
+probed range) skips the sort and reduces by direct address over K slots
+(exec/pallas_agg.py); its partial is K slots long, so the concat, the
+merge and everything downstream run at the domain's capacity, not the
+input's.  Anything else takes the sorted body above.
 """
 
 from __future__ import annotations
@@ -149,11 +153,6 @@ class _AggSpec:
 from spark_rapids_tpu.utils.kernel_cache import KernelCache
 
 _AGG_CACHE = KernelCache("aggregate", 256)
-
-# agg-spec -> consecutive pallas range-probe memo misses (see
-# _probe_key_range: probing costs a host sync, so specs whose inputs
-# are fresh every run stop probing after 2 misses)
-_PALLAS_FRESH_MISSES: dict = {}
 
 
 def make_agg_body(spec: _AggSpec, phase: str, capacity: int):
@@ -341,36 +340,27 @@ def make_agg_body(spec: _AggSpec, phase: str, capacity: int):
     return run
 
 
-def _compile_agg(spec: _AggSpec, phase: str, input_sig, capacity: int,
-                 decoder=None):
-    """``decoder`` (encoding.plane_view) maps compressed flat triples to
-    dense ones inside the jitted body; the marker-bearing ``input_sig``
-    keys those variants separately from the dense layout."""
+def _compile_agg(spec: _AggSpec, phase: str, input_sig, capacity: int):
     cache_key = (spec.key(), phase, input_sig, capacity)
     fn = _AGG_CACHE.get(cache_key)
     if fn is not None:
         return fn
-    body = make_agg_body(spec, phase, capacity)
-    if decoder is not None:
-        inner = body
-
-        def body(flat_cols, num_rows, _inner=inner, _dec=decoder):
-            return _inner(_dec(flat_cols), num_rows)
-    fn = engine_jit(body, family="aggregate", name=phase)
+    fn = engine_jit(make_agg_body(spec, phase, capacity),
+                    family="aggregate", name=phase)
     _AGG_CACHE[cache_key] = fn
     return fn
 
 
 def _compile_folded_update(h_steps, input_sig, aux_sig, capacity: int,
                            spec: _AggSpec, radices=None):
-    """ONE update program for a batch whose filter / project chain the
-    planner folded into the aggregate: ``h_steps`` (hoisted, code-viewed;
-    the last one projects ``spec``'s keys and inputs) run MASKED, then
-    the update body reduces under the steps' liveness — the dense body
-    over ``radices`` (exec/pallas_agg.py) or, with ``radices`` None, the
-    sorted-segment body.  Literals ride in as traced scalars and
-    dictionary tables as aux inputs, so the key is literal-free: a new
-    binding of a prepared query reuses the program."""
+    """The update program of one batch, and the only builder of one:
+    ``h_steps`` (hoisted, code-viewed: the chain the planner folded in,
+    possibly empty, then a projection of ``spec``'s keys and inputs) run
+    MASKED, then the update body reduces under the steps' liveness — the
+    dense body over ``radices`` (exec/pallas_agg.py) or, with ``radices``
+    None, the sorted-segment body.  Literals ride in as traced scalars
+    and dictionary tables as aux inputs, so the key is literal-free: a
+    new binding of a prepared query reuses the program."""
     from spark_rapids_tpu.exec.stage import emit_steps, stage_fingerprint
     dense = radices is not None
     cache_key = ("folded", stage_fingerprint(h_steps), input_sig, aux_sig,
@@ -433,19 +423,6 @@ def _compile_evaluate(spec: _AggSpec, input_sig, capacity: int):
     return fn
 
 
-def _fused_decode_planes(vbatch: ColumnarBatch, count: bool = True):
-    """``(flat, sig, decoder)`` of a batch for a compiled whole-batch
-    consumer: plane-compressed inputs (rle/delta/packed bool) feed the
-    kernel their compressed planes and decode INSIDE it — one dispatch,
-    no decode_plane_late on the update path (``encoding.plane_view``;
-    ``count=False`` for a probe that may not dispatch the view)."""
-    from spark_rapids_tpu.columnar import encoding
-    pv = encoding.plane_view(vbatch, count=count)
-    if pv is not None:
-        return pv
-    return _flatten_batch(vbatch), _batch_signature(vbatch), None
-
-
 def _colvals_to_batch(cvs, dtypes, n_rows: int,
                       schema: Optional[Schema] = None,
                       wrap=None) -> ColumnarBatch:
@@ -475,6 +452,9 @@ class TpuHashAggregateExec(TpuExec):
         # (``fold_steps``); the groupings and aggregates are bound to
         # the LAST step's output, ``children[0]`` feeds the first
         self.pre_steps: tuple = ()
+        # set by the first batch whose one-integer-key range does not
+        # fit the dense kernel: this node stops probing
+        self._pallas_off = False
         self.groupings = list(groupings)
         # the original bound aggregate expressions, kept so the AQE
         # placement re-score can rebuild the CPU analog of this node
@@ -530,67 +510,10 @@ class TpuHashAggregateExec(TpuExec):
             out.extend(f.buffer_dtypes())
         return out
 
-    def _agg_view(self, phase: str, batch: ColumnarBatch):
-        """The compressed code view of one aggregate phase
-        (columnar/encoding.py): group keys over encoded columns group
-        by CODES — ranks, so boundaries and output order are
-        byte-identical to grouping the strings — and the key output
-        stays encoded.  Returns ``(spec, batch, wrap)``; the identity
-        triple when nothing is encoded."""
-        from spark_rapids_tpu.columnar import encoding
-        if phase == "update":
-            value_exprs = [p for _, f in self.agg_pairs
-                           for p in f.input_projection()]
-            view = encoding.agg_code_view(batch, self.groupings,
-                                          value_exprs)
-            if view is None:
-                return self.spec, batch, None
-            batch2, groupings2, wrap = view
-            return _AggSpec(groupings2, self.agg_pairs), batch2, wrap
-        view = encoding.key_columns_code_view(batch,
-                                              len(self.groupings))
-        if view is None:
-            return self.spec, batch, None
-        batch2, overrides, wrap = view
-        from spark_rapids_tpu.exprs.base import BoundReference
-        groupings2 = [
-            BoundReference(ki, overrides[ki], g.nullable, g.name)
-            if ki in overrides else g
-            for ki, g in enumerate(self.groupings)]
-        return _AggSpec(groupings2, self.agg_pairs), batch2, wrap
-
-    def _run_phase(self, phase: str, batch: ColumnarBatch,
-                   conf=None):
-        from spark_rapids_tpu.columnar.column import LazyRows
-        with self.metrics.timed("computeAggTime"):
-            if phase == "update" and self.pre_steps:
-                return self._run_folded_update(batch, conf)
-            spec, vbatch, wrap = self._agg_view(phase, batch)
-            flat, sig, decoder = _fused_decode_planes(vbatch)
-            dense = None
-            if phase == "update":
-                dense = self._try_dense_update(spec, vbatch, wrap, conf,
-                                               flat, sig, decoder)
-            if dense is not None:
-                # the partial has the shape of its key domain: every
-                # possible key combination owns a slot, so the bound
-                # cannot cut a group off
-                (n_groups, key_outs, buf_outs), bound = dense
-            else:
-                fn = _compile_agg(spec, phase, sig, vbatch.capacity,
-                                  decoder)
-                n_groups, key_outs, buf_outs = fn(flat,
-                                                  vbatch.rows_traced)
-                # n_groups <= num_rows, except empty-input global agg
-                bound = max(1, min(batch.rows_bound, batch.capacity))
-            return _colvals_to_batch(
-                list(key_outs) + list(buf_outs), self._buffer_dtypes(),
-                LazyRows(n_groups, bound), wrap=wrap)
-
-    def _folded_spec(self, coded) -> _AggSpec:
-        """This aggregation over the output of the folded steps' last
+    def _update_spec(self, coded) -> _AggSpec:
+        """This aggregation over the output of the update's last
         projection (keys first, then one input per function); ``coded``
-        holds the key positions that arrive as dictionary codes."""
+        is keyed by the key positions that arrive as dictionary codes."""
         nk = len(self.groupings)
         groupings = [
             BoundReference(i, INT32 if i in coded else g.dtype,
@@ -602,140 +525,127 @@ class TpuHashAggregateExec(TpuExec):
             for j, (n, f) in enumerate(self.agg_pairs)]
         return _AggSpec(groupings, aggs)
 
-    def _run_folded_update(self, batch: ColumnarBatch, conf):
-        """One update over an UNFILTERED input batch: the folded steps
-        and a last projection of this node's keys and inputs go through
-        one code view (``encoding.stage_view``: predicates over
-        dictionary columns become code-set membership, bare dictionary
-        keys stay codes, as ``_agg_view`` would have them), literals
-        hoist out of the key, and the program reduces under the steps'
-        keep-mask (``_compile_folded_update``)."""
+    def _run_update(self, batch: ColumnarBatch, conf=None):
+        """One update over an input batch, the only route there is: the
+        folded steps (none, when the planner folded nothing) and a last
+        projection of this node's keys and inputs go through one code
+        view (``encoding.stage_view``: predicates over dictionary
+        columns become code-set membership, bare dictionary keys stay
+        codes and re-wrap on the way out, plane-compressed columns
+        decode in-kernel), literals hoist out of the key, and the
+        program reduces under the steps' keep-mask
+        (``_compile_folded_update``) -- dense over the key domain the
+        host knows, else sorted."""
         from spark_rapids_tpu.columnar import encoding
         from spark_rapids_tpu.columnar.column import LazyRows
         from spark_rapids_tpu.exec.stage import hoist_steps, norm_rows
-        nk = len(self.groupings)
-        inputs = tuple(f.child for _, f in self.agg_pairs)
-        tail = ("project", tuple(self.groupings) + inputs)
-        view = encoding.stage_view(self.pre_steps + (tail,), batch,
-                                   dense_tail=len(inputs))
-        wrap = {i: d for i, d in view.wrap.items() if i < nk}
-        spec = self._folded_spec(frozenset(wrap))
-        h_steps, values = hoist_steps(view.steps)
-        domain = self._dense_domain(
-            spec, batch, wrap, conf, lambda: self._probe_unfolded(batch))
-        if domain is not None:
-            radices, bases, bound = domain
-            self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
-        else:
-            radices, bases = None, ()
-            bound = max(1, min(batch.rows_bound, batch.capacity))
-        fn = _compile_folded_update(h_steps, view.sig, view.aux_sig,
-                                    batch.capacity, spec, radices)
-        n_groups, key_outs, buf_outs = fn(
-            view.flat, view.aux, norm_rows(batch), hoisted_args(values),
-            np.asarray(bases, np.int64))
-        self.metrics[METRIC_MASKED_FILTER_BATCHES].add(1)
-        return _colvals_to_batch(
-            list(key_outs) + list(buf_outs), self._buffer_dtypes(),
-            LazyRows(n_groups, bound), wrap=wrap)
+        with self.metrics.timed("computeAggTime"):
+            nk = len(self.groupings)
+            inputs = tuple(f.child for _, f in self.agg_pairs)
+            tail = ("project", tuple(self.groupings) + inputs)
+            view = encoding.stage_view(self.pre_steps + (tail,), batch,
+                                       dense_tail=len(inputs))
+            wrap = {i: d for i, d in view.wrap.items() if i < nk}
+            spec = self._update_spec(wrap)
+            h_steps, values = hoist_steps(view.steps)
+            domain = self._dense_domain(spec, batch, wrap, conf, view)
+            if domain is not None:
+                # the partial has the shape of its key domain: every
+                # possible key combination owns a slot, so the bound
+                # cannot cut a group off
+                radices, bases, bound = domain
+                self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
+            else:
+                radices, bases = None, ()
+                # n_groups <= num_rows, except empty-input global agg
+                bound = max(1, min(batch.rows_bound, batch.capacity))
+            fn = _compile_folded_update(h_steps, view.sig, view.aux_sig,
+                                        batch.capacity, spec, radices)
+            n_groups, key_outs, buf_outs = fn(
+                view.flat, view.aux, norm_rows(batch),
+                hoisted_args(values), np.asarray(bases, np.int64))
+            if self.pre_steps:
+                self.metrics[METRIC_MASKED_FILTER_BATCHES].add(1)
+            return _colvals_to_batch(
+                list(key_outs) + list(buf_outs), self._buffer_dtypes(),
+                LazyRows(n_groups, bound), wrap=wrap)
 
-    def _probe_unfolded(self, batch: ColumnarBatch):
-        """The one-integer-key range probe for a folded update.  Filters
-        leave the column space alone, so the key's range over the
-        unfiltered batch bounds the kept rows'; past a projection the
-        key has no expression over the input, and the sorted body runs."""
-        if any(kind != "filter" for kind, _ in self.pre_steps):
-            return None
-        spec, vbatch, _wrap = self._agg_view("update", batch)
-        return self._probe_key_range(
-            spec, vbatch, *_fused_decode_planes(vbatch, count=False))
+    def _run_merge(self, batch: ColumnarBatch):
+        """Merge concatenated partials: the sorted body with the merge
+        ops.  Encoded key columns group by their CODES (ranks, so
+        boundaries and output order are byte-identical to grouping the
+        strings) and the key output stays encoded."""
+        from spark_rapids_tpu.columnar import encoding
+        from spark_rapids_tpu.columnar.column import LazyRows
+        with self.metrics.timed("computeAggTime"):
+            spec, wrap = self.spec, None
+            view = encoding.key_columns_code_view(batch,
+                                                  len(self.groupings))
+            if view is not None:
+                batch, overrides, wrap = view
+                spec = _AggSpec([
+                    BoundReference(ki, overrides[ki], g.nullable, g.name)
+                    if ki in overrides else g
+                    for ki, g in enumerate(self.groupings)],
+                    self.agg_pairs)
+            fn = _compile_agg(spec, "merge", _batch_signature(batch),
+                              batch.capacity)
+            n_groups, key_outs, buf_outs = fn(_flatten_batch(batch),
+                                              batch.rows_traced)
+            return _colvals_to_batch(
+                list(key_outs) + list(buf_outs), self._buffer_dtypes(),
+                LazyRows(n_groups,
+                         max(1, min(batch.rows_bound, batch.capacity))),
+                wrap=wrap)
 
-    def _try_dense_update(self, spec: _AggSpec, vbatch: ColumnarBatch,
-                          wrap, conf, flat, sig, decoder):
-        """Sort-free update over a key domain the host knows (see
-        exec/pallas_agg.py): ``((n_groups, keys, buffers), bound)`` with
-        ``bound`` the exact domain size, or None -> take the
-        sorted-segment kernel.  ``spec``/``vbatch``/``wrap`` are the code
-        view of the batch (``_agg_view``)."""
-        from spark_rapids_tpu.exec import pallas_agg as pag
-        domain = self._dense_domain(
-            spec, vbatch, wrap, conf,
-            lambda: self._probe_key_range(spec, vbatch, flat, sig,
-                                          decoder))
-        if domain is None:
-            return None
-        radices, bases, bound = domain
-        fn = pag.make_update(spec, sig, vbatch.capacity, radices,
-                             decoder=decoder)
-        out = fn(flat, vbatch.rows_traced, np.asarray(bases, np.int64))
-        self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
-        return out, bound
-
-    def _dense_domain(self, spec: _AggSpec, vbatch: ColumnarBatch, wrap,
-                      conf, probe):
+    def _dense_domain(self, spec: _AggSpec, batch: ColumnarBatch, wrap,
+                      conf, view):
         """``(radices, bases, bound)`` of the dense update's key domain,
         or None when the host does not know one the kernel takes.
 
         The domain is known without a pull when every key is a
         dictionary-code view (radix ``dict.size + 1``, digit 0 the null
         key) or there are no keys; one bare integer key learns its range
-        from ``probe()`` (memoized) instead, and the first batch whose
-        range does not fit disables that probe for this exec so
-        high-cardinality aggs don't pay a blocking range check (kernel +
-        host sync) per batch."""
+        from ``_probe_key_range`` (memoized) instead."""
         from spark_rapids_tpu.exec import pallas_agg as pag
         if conf is None or not (pag.enabled(conf) and pag.supports(spec)):
             return None
-        if vbatch.capacity > pag.max_capacity(spec):
+        if batch.capacity > pag.max_capacity(spec):
             # per-spec exactness bound (int64-sum limb decomposition)
             return None
-        coded = wrap or {}
         nk = len(spec.groupings)
-        if len(coded) == nk:
-            radices = [coded[i].size + 1 for i in range(nk)]
+        if len(wrap) == nk:
+            radices = [wrap[i].size + 1 for i in range(nk)]
             bases = [0] * nk
             bound = math.prod(radices)
             if bound > pag.MAX_K:
                 return None
-        elif nk == 1 and not coded and vbatch.rows_bound > 0:
-            rng = probe()
+        elif nk == 1 and batch.rows_bound > 0:
+            rng = self._probe_key_range(view, batch)
             if rng is None:
                 return None
             lo, hi = rng
             radices, bases = [pag.range_radix(lo, hi)], [lo]
-            bound = min(vbatch.rows_bound, hi - lo + 2)
+            bound = min(batch.rows_bound, hi - lo + 2)
         else:
             return None
         return radices, bases, bound
 
-    def _probe_key_range(self, spec: _AggSpec, vbatch: ColumnarBatch,
-                         flat, sig, decoder):
-        """(lo, hi) of the one integer key when it fits the dense
-        kernel, else None."""
+    def _probe_key_range(self, view, batch: ColumnarBatch):
+        """(lo, hi) of the one integer key over the input batch when it
+        fits the dense kernel, else None.  Filters leave the column
+        space alone, so the key's range over the unfiltered batch bounds
+        the kept rows'; past a projection the key has no expression over
+        the input, and the sorted body runs.  A re-run over the scan
+        cache hits the buffer memo and pulls nothing; a fresh batch pays
+        one launch and one pull, which the first batch whose range does
+        not fit stops for this exec, so a high-cardinality aggregate
+        does not pay a blocking range check per batch."""
         from spark_rapids_tpu.exec import pallas_agg as pag
-        if getattr(self, "_pallas_off", False):
+        if self._pallas_off or \
+                any(kind != "filter" for kind, _ in self.pre_steps):
             return None
-        # The range probe is a host sync (~100ms+ over a remote link).
-        # Re-runs over device-cached scans hit the buffer memo for free,
-        # but inputs that are fresh every run (e.g. join outputs) would
-        # pay the sync each time — after 2 fresh-buffer misses for this
-        # agg spec, the probe becomes memo-only (a later memo hit still
-        # uses Pallas and resets the counter; only the PULL is gated).
-        spec_key = spec.key()
-        # at large capacities the sorted-segment fallback costs seconds
-        # (bitonic at 2^22+), so the ~100ms probe sync is always worth
-        # paying; the miss gate only governs small fast batches
-        allow_pull = _PALLAS_FRESH_MISSES.get(spec_key, 0) < 2 or \
-            vbatch.capacity >= (1 << 21)
-        info: dict = {}
-        rng = pag.key_range(spec.groupings[0], vbatch, info=info,
-                            allow_pull=allow_pull, flat=flat, sig=sig,
-                            decoder=decoder)
-        if info.get("hit"):
-            _PALLAS_FRESH_MISSES[spec_key] = 0
-        elif info.get("pulled"):
-            _PALLAS_FRESH_MISSES[spec_key] = \
-                _PALLAS_FRESH_MISSES.get(spec_key, 0) + 1
+        rng = pag.key_range(view, batch)
         if rng is not None and not pag.fits(*rng):
             self._pallas_off = True
             return None
@@ -760,8 +670,7 @@ class TpuHashAggregateExec(TpuExec):
                     # (reference RmmRapidsRetryIterator withRetry +
                     # SplitAndRetryOOM, aggregate.scala update path)
                     for part in with_retry(
-                            lambda b: self._run_phase("update", b,
-                                                      ctx.conf),
+                            lambda b: self._run_update(b, ctx.conf),
                             batch, ctx, split=split_batch_half):
                         partials.append(SpillableBatch(part, cat))
                 if not partials:
@@ -772,7 +681,7 @@ class TpuHashAggregateExec(TpuExec):
                     empty = _empty_input_batch(
                         self.children[0].output_schema)
                     partials.append(SpillableBatch(
-                        self._run_phase("update", empty), cat))  # global agg: sorted path
+                        self._run_update(empty), cat))  # sorted body
             except BaseException:
                 close_all(partials)
                 raise
@@ -782,7 +691,7 @@ class TpuHashAggregateExec(TpuExec):
             if many:
                 with self.metrics.timed("concatTime"):
                     merged = concat_batches(materialized)
-                merged = self._run_phase("merge", merged)
+                merged = self._run_merge(merged)
             elif self.groupings:
                 # single partial is already segment-reduced; merge is
                 # idempotent, skip it

@@ -5,13 +5,13 @@ kernel in exec/aggregate.py implements (cuDF ``Table.groupBy().aggregate``
 analog, aggregate.scala:731).  For the common BI shape — group keys whose
 joint value domain is small and known on the host — sorting every batch
 by its keys is wasted work.  The domain is known two ways: every key is
-a dictionary-code view (``encoding.agg_code_view``: radix ``dict.size +
-1`` per key, nothing pulled; no keys at all is the one-slot case), or
-one bare integer key whose range a memoized probe pulled (date/flag/
-status keys, a year bucket).  Either way each key is one DIGIT
-(key - base + 1, digit 0 reserved for null), the slot is the mixed radix
-of the digits (first key most significant), and this kernel streams
-lane-dense row blocks through a VMEM one-hot accumulation:
+a dictionary-code view (``encoding.stage_view`` left it as codes: radix
+``dict.size + 1`` per key, nothing pulled; no keys at all is the
+one-slot case), or one bare integer key whose range a memoized probe
+pulled (date/flag/status keys, a year bucket).  Either way each key is
+one DIGIT (key - base + 1, digit 0 reserved for null), the slot is the
+mixed radix of the digits (first key most significant), and this kernel
+streams lane-dense row blocks through a VMEM one-hot accumulation:
 
     rows live as (capacity/128, 128): one sublane row = 128 input rows
     per sublane row s:  hit = (iota_slots(K, 128) == gid[s])     # VMEM
@@ -33,6 +33,9 @@ float32 and every index the kernel touches is typed int32 explicitly
 statically from the spec and the device float policy; a spec it admits
 compiles, and a compile error propagates.  Off-TPU the same kernel runs
 in interpret mode (compile/service.py:pallas_interpret).
+
+This module builds bodies and the range probe; the one jitting caller of
+``make_update_body`` is ``exec/aggregate.py:_compile_folded_update``.
 """
 
 from __future__ import annotations
@@ -47,9 +50,7 @@ import numpy as np
 from spark_rapids_tpu.compile.service import engine_jit
 from spark_rapids_tpu.columnar.column import bucket_capacity
 from spark_rapids_tpu.columnar.dtypes import BOOLEAN, STRING, TIMESTAMP
-from spark_rapids_tpu.exprs.base import (
-    ColVal, EvalContext, _batch_signature, _flatten_batch,
-)
+from spark_rapids_tpu.exprs.base import ColVal, EvalContext
 from spark_rapids_tpu.exprs import aggregates as agf
 
 MAX_K = 1024          # largest dense key domain the kernel handles
@@ -64,7 +65,6 @@ _MAX_RESIDENT_SLOTS = 10 * 1024
 from spark_rapids_tpu.utils.kernel_cache import KernelCache
 
 _RANGE_CACHE = KernelCache("pallas.range", 128)
-_UPDATE_CACHE = KernelCache("pallas.update", 128)
 
 
 def enabled(conf) -> bool:
@@ -230,33 +230,30 @@ def _pallas_reduce_call(gid2, planes2, ops, K: int, interpret: bool):
     return [_fold_lanes(a, op) for a, op in zip(accs, ops)]
 
 
-def key_range(grouping, batch, info: Optional[dict] = None,
-              allow_pull: bool = True, flat=None, sig=None,
-              decoder=None) -> Optional[Tuple[int, int]]:
-    """(min, max) of the valid key values in the batch, or None when no
-    valid keys exist; one cached jitted kernel + one host sync (memoized
-    on buffer identity — ``info['hit']``/``info['pulled']`` report how it
-    was served).  ``allow_pull=False`` makes the probe memo-only: a miss
-    returns None without paying the link round trip.  ``flat``/``sig``/
-    ``decoder`` carry a plane-compressed view (encoding.plane_view):
-    the decode traces inside the probe kernel, and the marker-bearing
-    sig keys those variants apart from the dense layout."""
-    if flat is None:
-        flat = _flatten_batch(batch)
-        sig = _batch_signature(batch)
-    sig = (grouping.key(), sig, batch.capacity)
+def key_range(view, batch) -> Optional[Tuple[int, int]]:
+    """(min, max) of the valid values of the update's first key over
+    every row of ``batch``, or None when no valid key exists; one cached
+    jitted kernel + one host sync, memoized on buffer identity so a
+    re-run over the device scan cache never pulls.  ``view`` is the
+    update's own code view (``encoding.stage_view`` of the folded chain
+    and the last projection): the probe runs its projections (a
+    plane-decode prefix, then the key's expression alone) and none of
+    its filters, so it reads the planes the update reads."""
+    from spark_rapids_tpu.exec.stage import (
+        emit_steps, norm_rows, stage_fingerprint,
+    )
+    projects = [s for s in view.steps if s[0] == "project"]
+    steps = tuple(projects[:-1]) + (("project", projects[-1][1][:1]),)
+    cap = batch.capacity
+    sig = (stage_fingerprint(steps), view.sig, view.aux_sig, cap)
     fn = _RANGE_CACHE.get(sig)
     if fn is None:
-        cap = batch.capacity
-
-        def run(flat_cols, num_rows):
-            if decoder is not None:
-                flat_cols = decoder(flat_cols)
+        def run(flat_cols, aux, num_rows):
             cols = [ColVal(*t) for t in flat_cols]
-            ctx = EvalContext(cols, num_rows, cap)
-            cv = grouping.emit(ctx)
-            live = jnp.arange(cap) < num_rows
-            m = cv.validity & live
+            (cv,), _live = emit_steps(steps, cols, num_rows, cap,
+                                      jnp.int64(0), (), aux=aux,
+                                      compact=False)
+            m = cv.validity  # the projection masked it with liveness
             v = cv.data.astype(jnp.int64)
             lo = jnp.min(jnp.where(m, v, jnp.iinfo(jnp.int64).max))
             hi = jnp.max(jnp.where(m, v, jnp.iinfo(jnp.int64).min))
@@ -264,42 +261,26 @@ def key_range(grouping, batch, info: Optional[dict] = None,
 
         fn = engine_jit(run, family="aggregate", name="pallas_key_range")
         _RANGE_CACHE[sig] = fn
-    # one combined pull for all three scalars (each separate host read of
-    # a device scalar costs a full link round trip); memoized on buffer
-    # identity so re-running over the device scan cache never re-pulls
     from spark_rapids_tpu.utils.memo import memoized_pull
     rows = batch.rows_traced
-    arrays = [a for t in flat for a in t if a is not None]
+    arrays = [a for t in view.flat + view.aux for a in t if a is not None]
     logical = ("pallas_key_range", sig)
     if isinstance(rows, int):
         logical = logical + (rows,)
     else:
         arrays.append(rows)
 
-    from spark_rapids_tpu.utils.memo import SCALAR_MEMO
-    hit = SCALAR_MEMO.get(logical, tuple(arrays))
-    if hit is not None:
-        if info is not None:
-            info["hit"] = True
-        return hit[0]
-    if not allow_pull:
-        if info is not None:
-            info["hit"] = False
-            info["pulled"] = False
-        return None
-
     def compute():
+        # one combined pull for all three scalars: each separate host
+        # read of a device scalar is a round trip of its own
         from spark_rapids_tpu.columnar.transfer import device_pull
-        lo, hi, any_valid = device_pull(fn(flat, rows))
+        lo, hi, any_valid = device_pull(
+            fn(view.flat, view.aux, norm_rows(batch)))
         if not bool(any_valid):
             return None
         return int(lo), int(hi)
 
-    out = memoized_pull(logical, arrays, compute)
-    if info is not None:
-        info["hit"] = False
-        info["pulled"] = True
-    return out
+    return memoized_pull(logical, arrays, compute)
 
 
 def fits(lo: int, hi: int) -> bool:
@@ -475,26 +456,3 @@ def make_update_body(spec, capacity: int, radices: Sequence[int]):
         return n_groups, tuple(key_outs), tuple(buf_outs)
 
     return run
-
-
-def make_update(spec, input_sig, capacity: int, radices: Sequence[int],
-                decoder=None):
-    """``make_update_body`` jitted as ``(flat_cols, num_rows, bases)``
-    and memoized.  ``decoder`` (encoding.plane_view) densifies
-    plane-compressed triples inside the jitted body; its marker-bearing
-    ``input_sig`` keys the variant."""
-    radices = tuple(int(r) for r in radices)
-    cache_key = (spec.key(), input_sig, capacity, radices)
-    fn = _UPDATE_CACHE.get(cache_key)
-    if fn is not None:
-        return fn
-    body = make_update_body(spec, capacity, radices)
-
-    def run(flat_cols, num_rows, bases):
-        if decoder is not None:
-            flat_cols = decoder(flat_cols)
-        return body(flat_cols, num_rows, bases)
-
-    fn = engine_jit(run, family="aggregate", name="pallas_update")
-    _UPDATE_CACHE[cache_key] = fn
-    return fn

@@ -158,22 +158,6 @@ def _reset_compile_service():
                       prev_min)
 
 
-@pytest.fixture(autouse=True)
-def _reset_pallas_probe_memo():
-    # _PALLAS_FRESH_MISSES is a process-global perf memo: two
-    # fresh-buffer range-probe misses for one agg spec make the pallas
-    # probe memo-only for that spec FOREVER.  Across the suite that is
-    # cross-test poisoning — a test whose queries share an agg spec
-    # shape with a later pallas test silently flips it onto the
-    # sorted-segment path (flushed out by ISSUE 11's health tests,
-    # which aggregate the same (key, sum, count) shape the pallas
-    # multi-batch test asserts on).
-    from spark_rapids_tpu.exec import aggregate as _aggregate
-    _aggregate._PALLAS_FRESH_MISSES.clear()
-    yield
-    _aggregate._PALLAS_FRESH_MISSES.clear()
-
-
 # -- observability hygiene (docs/observability.md) --------------------------
 #
 # The journal and the histogram switch are process-global and conf-

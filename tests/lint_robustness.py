@@ -990,8 +990,7 @@ def test_native_transport_has_receive_timeouts():
 # counted ``decode_late`` / ``decode_plane_late`` seams (the
 # `lateDecodes`/`fusedDecodes` trajectory numbers) AND breaks stage
 # fusion (the decode must trace INSIDE the consuming kernel via
-# stage_view's PlaneDecode / plane_view's decoder, never dispatch on
-# its own).
+# stage_view's PlaneDecode, never dispatch on its own).
 # ---------------------------------------------------------------------------
 
 _EXPRS_DIR = os.path.join(_PACKAGE_DIR, "exprs")
@@ -1023,8 +1022,8 @@ def test_no_adhoc_materialization_in_exprs(path):
         "kernels stay on device over the flat planes they are handed; "
         "dictionary/compressed planes decode only through the counted "
         "seams (columnar/encoding.py decode_late / decode_plane_late) "
-        "or fuse via stage_view/plane_view so the lateDecodes/"
-        "fusedDecodes trajectory stays honest (docs/compressed.md)")
+        "or fuse via stage_view so the lateDecodes/fusedDecodes "
+        "trajectory stays honest (docs/compressed.md)")
 
 
 # ---------------------------------------------------------------------------

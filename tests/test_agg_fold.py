@@ -9,6 +9,12 @@ predicates, NULLs, empty results, an OOM split), the cases that must NOT
 fold (a nondeterministic step; a filter that feeds a join, sort, limit or
 exchange keeps the parent's stage program, key and bytes), the
 literal-free cache key, and what ``explain()`` shows.
+
+And the one route (``TpuHashAggregateExec._run_update``): an update
+nothing was folded into is the folded update with an empty chain, from
+the same builder under the same two program names; what a run answers
+does not depend on how often the process has run the query; a key
+column that also feeds a function stays in the code domain.
 """
 
 from __future__ import annotations
@@ -369,3 +375,109 @@ def test_describe_names_the_folded_predicate(lineitem):
     # explain() does not hide the filter
     tree = s._last_plan_result.physical.tree_string()
     assert "(ship <= 10471)" in tree and "TpuFilter" not in tree
+
+
+# -- one route: an unfolded update is a folded update with no steps ---------
+
+# float inputs whose products and sums are exact in any order (small
+# integers), so neither a contraction nor the order of addition can
+# show: masked rows reduce in place, compacted rows at the front
+def _exact_aggs():
+    return (F.sum(col("qty") * col("line")).alias("s"),
+            F.avg(col("qty")).alias("a"), F.sum(col("line")).alias("li"),
+            F.min(col("ship")).alias("lo"), F.max(col("ship")).alias("hi"),
+            F.count(col("qty")).alias("nq"), F.count(lit(1)).alias("n"))
+
+
+ONE_ROUTE = {
+    "dictionary_keys": (lambda df: df.filter(col("ship") <= 10471)
+                        .group_by("flag", "status").agg(*_exact_aggs()),
+                        "aggregate_masked_pallas_update"),
+    "keyless": (lambda df: df.filter(col("qty") < 24.0)
+                .agg(*_exact_aggs()), "aggregate_masked_pallas_update"),
+    "probed_integer_key": (lambda df: df.filter(col("ship") > 9000)
+                           .group_by("line").agg(*_exact_aggs()),
+                           "aggregate_masked_pallas_update"),
+    "sorted_key": (lambda df: df.filter(col("ship") > 9000)
+                   .group_by("okey").agg(*_exact_aggs()),
+                   "aggregate_masked_update"),
+    "no_filter_at_all": (lambda df: df.group_by("flag", "status")
+                         .agg(*_exact_aggs()),
+                         "aggregate_masked_pallas_update"),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_ROUTE))
+def test_fusion_on_and_off_launch_from_the_one_builder(lineitem, case):
+    """With the chain folded in or left to a filter below, every update
+    is ``_compile_folded_update``'s: the same program name, no
+    ``aggregate_update`` / ``aggregate_pallas_update`` row in the
+    dispatch ledger, and the same bytes out."""
+    from spark_rapids_tpu.compile import service
+    query, program = ONE_ROUTE[case]
+    tables = []
+    for fusion in (True, False):
+        before = service.ledger_rows()
+        out, s = _run(lineitem, query, fusion)
+        after = service.ledger_rows()
+        grew = {p for p, row in after.items()
+                if row["family"] == "aggregate" and row["dispatches"]
+                > before.get(p, {"dispatches": 0})["dispatches"]}
+        assert program in grew
+        assert grew <= {program, "aggregate_merge", "aggregate_evaluate",
+                        "aggregate_pallas_key_range"}, grew
+        assert not {"aggregate_update", "aggregate_pallas_update"} \
+            & set(after)
+        (agg,) = _aggregates(s)
+        assert bool(agg.pre_steps) == (fusion and case
+                                       != "no_filter_at_all")
+        # the filter counts as masked only where it was folded in
+        assert (sum_plan_metric(s, "maskedFilterBatches") > 0) == \
+            bool(agg.pre_steps)
+        tables.append(out)
+    assert tables[0].num_rows and tables[0].equals(tables[1])
+
+
+def test_answers_do_not_depend_on_how_often_the_query_ran():
+    """One int64 key over buffers that are new in every run: each run
+    probes the key's range, takes the dense body and answers the same
+    bytes.  (A process-global miss count used to make the third run of
+    a spec memo-only, and its float sums the sorted body's.)"""
+    rng = np.random.default_rng(31)
+    n = 6000
+    t = pa.table({"k": pa.array(rng.integers(-3, 40, n), pa.int64()),
+                  "v": pa.array(rng.normal(size=n)),
+                  "w": pa.array(rng.uniform(0, 1e6, n))})
+    s = tpu_session({})
+    try:
+        outs = []
+        for _ in range(4):
+            outs.append(s.create_dataframe(t).group_by("k").agg(
+                F.sum(col("v")).alias("sv"), F.avg(col("w")).alias("aw"),
+                F.count(lit(1)).alias("n")).to_arrow())
+            assert sum_plan_metric(s, "pallasAggBatches") > 0
+    finally:
+        s.stop()
+    assert outs[0].num_rows == 43
+    assert all(o.equals(outs[0]) for o in outs[1:])
+
+
+def test_key_that_feeds_a_function_stays_in_the_code_domain(lineitem):
+    """Each expression is viewed on its own: ``mode`` as a key stays
+    codes (and leaves re-wrapped on its dictionary) while ``max(mode)``
+    decodes the same column inside the update's program: no late
+    decode anywhere in the query."""
+    s = tpu_session({"spark.rapids.sql.scan.deviceCacheEnabled": "false"})
+    try:
+        before = s.engine_stats()["compressed"]
+        out = (s.read.parquet(lineitem).group_by("mode")
+               .agg(F.max(col("mode")).alias("m"),
+                    F.count(lit(1)).alias("n")).to_arrow())
+        after = s.engine_stats()["compressed"]
+    finally:
+        s.stop()
+    assert after["encodedColumns"] > before["encodedColumns"]
+    assert after["lateDecodes"] == before["lateDecodes"]
+    assert out.column("mode").to_pylist() == out.column("m").to_pylist() \
+        == ["AIR", "MAIL", "RAIL", "SHIP"]
+    assert sum(out.column("n").to_pylist()) == N

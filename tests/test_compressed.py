@@ -506,6 +506,41 @@ def test_plane_group_by_fused_decode_matches_cpu(plane_path):
     assert_tables_equal(out, cpu, approx_float=True)
 
 
+def test_plane_columns_never_reach_the_aggregate_merge(plane_path,
+                                                       monkeypatch):
+    """Only the update reads scan batches: its partials come out of a
+    kernel as dense or dictionary columns and ``concat_batches`` keeps
+    them so, which is why the merge program takes no plane decoder."""
+    from spark_rapids_tpu.api import col
+    from spark_rapids_tpu import functions as F
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    merged = []
+    real = TpuHashAggregateExec._run_merge
+
+    def spy(self, batch):
+        merged.append([type(c).__name__ for c in batch.columns
+                       if encoding.is_plane_compressed(c)])
+        return real(self, batch)
+
+    monkeypatch.setattr(TpuHashAggregateExec, "_run_merge", spy)
+
+    def q(s):
+        return s.read.parquet(plane_path).group_by("b").agg(
+            F.sum(col("q")).alias("sq"), F.max(col("r")).alias("mr"),
+            F.count(col("b")).alias("nb")).sort("b")
+
+    before = encoding.compressed_stats()
+    out = q(tpu_session({
+        **CONF_ON, **_NO_CACHE,
+        "spark.rapids.sql.reader.batchSizeRows": "1024",
+        "spark.rapids.sql.batchSizeBytes": "8192"})).to_arrow()
+    after = encoding.compressed_stats()
+    assert after["fused_decodes"] > before["fused_decodes"]
+    assert after["late_decodes"] == before["late_decodes"]
+    assert merged == [[]], "one merge, over no plane-compressed column"
+    assert_tables_equal(out, q(cpu_session()).to_arrow())
+
+
 @pytest.mark.faults
 def test_plane_encode_fault_degrades_to_plain(plane_path,
                                               encode_fault_conf):

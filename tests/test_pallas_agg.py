@@ -370,12 +370,10 @@ def test_dense_route_domain_over_max_k_falls_back(tmp_path):
     keys = []
     for on in ("true", "false"):
         aggregate._AGG_CACHE.clear()
-        pallas_agg._UPDATE_CACHE.clear()
         s = tpu_session(_SMALL_BATCHES)
         s.set_conf("spark.rapids.sql.tpu.pallas.agg.enabled", on)
         rows = build(s).to_arrow().to_pylist()
         assert _agg_exec(s).metrics["pallasAggBatches"].value == 0
-        assert len(pallas_agg._UPDATE_CACHE) == 0
         keys.append((sorted(map(repr, aggregate._AGG_CACHE._entries)),
                      rows))
     assert keys[0] == keys[1]
@@ -392,9 +390,9 @@ def test_dense_route_keyless_empty_batch_emits_initial_values(tmp_path):
     node = _agg_exec(s)
     empty = _empty_input_batch(node.children[0].output_schema)
     before = node.metrics["pallasAggBatches"].value
-    dense = node._run_phase("update", empty, s.conf)
+    dense = node._run_update(empty, s.conf)
     assert node.metrics["pallasAggBatches"].value == before + 1
-    plain = node._run_phase("update", empty)
+    plain = node._run_update(empty)
     assert dense.rows_bound == 1 and dense.capacity == 8
     assert dense.num_rows == plain.num_rows == 1
     for a, b in zip(dense.columns, plain.columns):
@@ -431,6 +429,8 @@ def test_dense_partials_keep_downstream_at_the_domains_capacity(tmp_path):
                  for batch_sig in k[0] for col_sig in batch_sig]
     assert concat_caps and merge_caps and sort_caps and pack_caps
     assert max(concat_caps + merge_caps + sort_caps + pack_caps) <= 128
-    # no update ran the sorted body at all
-    assert not [k for k in aggregate._AGG_CACHE._entries
-                if k[1] == "update"]
+    # no update ran the sorted body at all: every update program was
+    # built over radices
+    updates = [k for k in aggregate._AGG_CACHE._entries
+               if k[0] == "folded"]
+    assert updates and all(k[-1] is not None for k in updates)

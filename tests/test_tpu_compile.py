@@ -62,8 +62,9 @@ def _compile(fn, *avals):
 
 @pytest.mark.parametrize("K", [128, 1024])
 def test_pallas_agg_compiles_for_v5e(one_chip, mosaic, K):
-    """Every plane dtype x op ``make_update`` emits on the chip: int32
-    add (counts, int64-sum limbs), f32 add (sums), int32/f32 min/max."""
+    """Every plane dtype x op ``make_update_body`` emits on the chip:
+    int32 add (counts, int64-sum limbs), f32 add (sums), int32/f32
+    min/max."""
     from spark_rapids_tpu.exec import pallas_agg
     dtypes = (jnp.int32, jnp.int32, jnp.float32, jnp.int32, jnp.int32,
               jnp.int32, jnp.int32, jnp.float32, jnp.float32, jnp.int32,
@@ -85,16 +86,17 @@ def test_pallas_agg_compiles_for_v5e(one_chip, mosaic, K):
                          ids=["q1_two_code_keys", "q6_keyless"])
 def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
                                        radices):
-    """The whole ``make_update`` program of the static route at the
-    scan's batch capacity: the mixed-radix slot, q1's sixteen planes
-    (four sums and three averages with their counts, a row count) at
-    K = 128, the compaction and the per-digit key rebuild; zero digits
-    is q6's keyless shape.  Outputs are as long as the domain's bucket,
-    not as the input."""
+    """The whole update program of an aggregate nothing was folded
+    into, at the scan's batch capacity: one projection of the keys and
+    of one input per function, as ``_run_update`` builds it, then the
+    mixed-radix slot, q1's sixteen planes (four sums and three averages
+    with their counts, a row count) at K = 128, the compaction and the
+    per-digit key rebuild; zero digits is q6's keyless shape.  Outputs
+    are as long as the domain's bucket, not as the input."""
+    import spark_rapids_tpu.exec.aggregate as agg_mod
     from spark_rapids_tpu.columnar import dtypes
     from spark_rapids_tpu.columnar.dtypes import FLOAT64, INT32
-    from spark_rapids_tpu.exec import pallas_agg
-    from spark_rapids_tpu.exec.aggregate import _AggSpec
+    from spark_rapids_tpu.exec import pallas_agg, stage
     from spark_rapids_tpu.exprs import aggregates as agf
     from spark_rapids_tpu.exprs.base import BoundReference, Literal
     monkeypatch.setattr(dtypes, "_DOUBLE_AS_FLOAT", True)
@@ -102,25 +104,26 @@ def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
     keys = [BoundReference(i, INT32, True, f"k{i}") for i in range(nk)]
     vals = [BoundReference(nk + i, FLOAT64, True, f"v{i}")
             for i in range(4)]
-    aggs = [(f"s{i}", agf.Sum(v)) for i, v in enumerate(vals)]
-    aggs += [(f"a{i}", agf.Average(v)) for i, v in enumerate(vals[:3])]
-    aggs.append(("n", agf.Count(Literal(1, INT32))))
-    spec = _AggSpec(keys, aggs)
+    funcs = [(f"s{i}", agf.Sum, v) for i, v in enumerate(vals)]
+    funcs += [(f"a{i}", agf.Average, v) for i, v in enumerate(vals[:3])]
+    funcs.append(("n", agf.Count, Literal(1, INT32)))
+    tail = ("project", tuple(keys) + tuple(e for _, _, e in funcs))
+    spec = agg_mod._AggSpec(keys, [
+        (n, cls(BoundReference(nk + j, e.dtype, e.nullable, e.name)))
+        for j, (n, cls, e) in enumerate(funcs)])
     assert pallas_agg.supports(spec)
-    pallas_agg._UPDATE_CACHE.clear()
-    prog = pallas_agg.make_update(spec, ("compile-test", nk), CAP,
-                                  radices)
-
-    def col(dt):
-        return (jax.ShapeDtypeStruct((CAP,), dt, sharding=one_chip),
-                jax.ShapeDtypeStruct((CAP,), jnp.bool_,
-                                     sharding=one_chip), None)
-
-    flat = tuple([col(jnp.int32)] * nk + [col(jnp.float32)] * 4)
-    lowered = prog.lower(
-        flat, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((nk,), jnp.int64, sharding=one_chip))
-    pallas_agg._UPDATE_CACHE.clear()
+    h_steps, values = stage.hoist_steps((tail,))
+    sig = tuple([(INT32.name, CAP, 0)] * nk + [(FLOAT64.name, CAP, 0)] * 4)
+    agg_mod._AGG_CACHE.clear()
+    prog = agg_mod._compile_folded_update(h_steps, sig, (), CAP, spec,
+                                          radices)
+    agg_mod._AGG_CACHE.clear()
+    flat, aux, n, _pid, hoisted = stage.aval_inputs(sig, CAP, values)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        (flat, aux, n, hoisted, jax.ShapeDtypeStruct((nk,), jnp.int64)))
+    lowered = prog.lower(*avals)
     assert "tpu_custom_call" in lowered.compile().as_text()
     _n_groups, key_outs, buf_outs = lowered.out_info
     assert len(key_outs) == nk and len(buf_outs) == 15
@@ -132,7 +135,7 @@ def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
 
 @pytest.fixture(scope="module")
 def folded_updates(tmp_path_factory):
-    """What ``TpuHashAggregateExec._run_folded_update`` asks
+    """What ``TpuHashAggregateExec._run_update`` asks
     ``_compile_folded_update`` for while TPC-H q1 and q6 run over a
     dictionary-encoded lineitem (20 k rows, on the CPU): the hoisted
     steps, signatures, spec and radices of the real path, re-aimed by
