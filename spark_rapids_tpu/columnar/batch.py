@@ -40,11 +40,12 @@ class ColumnarBatch:
     host code that truly needs the number pays the link round trip once
     via the ``num_rows`` property (see LazyRows in columnar/column.py)."""
 
-    __slots__ = ("columns", "_rows", "schema")
+    __slots__ = ("columns", "_rows", "schema", "_size")
 
     def __init__(self, columns: List[DeviceColumn], num_rows,
                  schema: Optional[Schema] = None):
         self.columns = columns
+        self._size = None  # size_bytes(), once asked
         self._rows = num_rows if isinstance(num_rows, LazyRows) \
             else int(num_rows)
         self.schema = schema
@@ -85,7 +86,13 @@ class ColumnarBatch:
         return self.columns[i]
 
     def size_bytes(self) -> int:
-        return sum(c.size_bytes() for c in self.columns)
+        """Device bytes of the planes: a walk over every plane's shape,
+        made once (the planes of a batch do not change) — the coalesce
+        and the spill catalog each ask, for every batch of every
+        query."""
+        if self._size is None:
+            self._size = sum(c.size_bytes() for c in self.columns)
+        return self._size
 
     def gather(self, indices, num_rows) -> "ColumnarBatch":
         """All-column row gather as ONE compiled kernel — eager per-column

@@ -1305,6 +1305,38 @@ def _deterministic(expr) -> bool:
     return not contains_nondeterministic(expr)
 
 
+def stage_planes(batch) -> Tuple[tuple, tuple]:
+    """``(flat, sig)`` of ``batch`` as a code-view kernel takes it: an
+    encoded column rides as its codes, a plane-compressed one as its
+    own planes (``PlaneDecode`` has the layout), anything else dense —
+    ``_flatten_batch`` / ``_batch_signature`` when nothing is encoded.
+    ``StageView.flat`` is this; a consumer that parks a batch between
+    viewing and launching it (the aggregate's group, exec/aggregate.py)
+    drops the view's planes and reads them here again."""
+    flat: List[tuple] = []
+    sig: List[tuple] = []
+    for c in batch.columns:
+        if isinstance(c, EncodedColumn):
+            flat.append((c.codes, c.validity, None))
+            sig.append((INT32.name, c.capacity, 0))
+        elif isinstance(c, RleColumn):
+            flat.append((c.run_values, c.validity, c.run_ends))
+            sig.append((f"@rle:{c.dtype.name}",
+                        int(c.run_values.shape[0]), c.capacity))
+        elif isinstance(c, DeltaColumn):
+            flat.append((c.deltas, c.validity, c.base))
+            sig.append((f"@delta:{c.dtype.name}:{c.deltas.dtype}",
+                        c.capacity, 0))
+        elif isinstance(c, PackedBoolColumn):
+            flat.append((c.packed, c.validity, None))
+            sig.append(("@packed", int(c.packed.shape[0]), c.capacity))
+        else:
+            flat.append((c.data, c.validity, c.chars))
+            width = c.string_width if c.chars is not None else 0
+            sig.append((c.dtype.name, c.capacity, width))
+    return tuple(flat), tuple(sig)
+
+
 def stage_view(steps, batch, keys: Sequence[Expression] = (),
                dense_tail: int = 0) -> "StageView":
     """Build the code-domain view of ``steps`` (and optional trailing
@@ -1333,9 +1365,7 @@ def stage_view(steps, batch, keys: Sequence[Expression] = (),
     With no encoded columns (or compressed off) the view is the
     identity: flatten/signature/steps exactly as the dense engine
     builds them, so kernel cache keys cannot drift."""
-    from spark_rapids_tpu.exprs.base import (
-        Alias, BoundReference, _batch_signature, _flatten_batch,
-    )
+    from spark_rapids_tpu.exprs.base import Alias, BoundReference
 
     enc: Dict[int, EncodedColumn] = {
         i: c for i, c in enumerate(batch.columns)
@@ -1343,34 +1373,10 @@ def stage_view(steps, batch, keys: Sequence[Expression] = (),
     comp: Dict[int, DeviceColumn] = {
         i: c for i, c in enumerate(batch.columns)
         if isinstance(c, _PLANE_TYPES)}
+    flat, sig = stage_planes(batch)
     if not enc and not comp:
-        return StageView(tuple(steps), _flatten_batch(batch),
-                         _batch_signature(batch), (), (), {},
+        return StageView(tuple(steps), flat, sig, (), (), {},
                          tuple(keys) if keys else None, True)
-
-    flat: List[tuple] = []
-    sig: List[tuple] = []
-    for i, c in enumerate(batch.columns):
-        if i in enc:
-            flat.append((c.codes, c.validity, None))
-            sig.append((INT32.name, c.capacity, 0))
-        elif i in comp:
-            if isinstance(c, RleColumn):
-                flat.append((c.run_values, c.validity, c.run_ends))
-                sig.append((f"@rle:{c.dtype.name}",
-                            int(c.run_values.shape[0]), c.capacity))
-            elif isinstance(c, DeltaColumn):
-                flat.append((c.deltas, c.validity, c.base))
-                sig.append((f"@delta:{c.dtype.name}:{c.deltas.dtype}",
-                            c.capacity, 0))
-            else:
-                flat.append((c.packed, c.validity, None))
-                sig.append(("@packed", int(c.packed.shape[0]),
-                            c.capacity))
-        else:
-            flat.append((c.data, c.validity, c.chars))
-            width = c.string_width if c.chars is not None else 0
-            sig.append((c.dtype.name, c.capacity, width))
 
     if comp:
         # fuse every compressed plane's decode into THIS kernel: a
@@ -1514,7 +1520,7 @@ def stage_view(steps, batch, keys: Sequence[Expression] = (),
                 new_keys.append(nk)
 
     _bump("code_stages")
-    return StageView(tuple(out_steps), tuple(flat), tuple(sig),
+    return StageView(tuple(out_steps), flat, sig,
                      tuple(aux_flat), tuple(aux_sig), wrap,
                      tuple(new_keys) if new_keys is not None else
                      (tuple(keys) if keys else None), False)

@@ -18,14 +18,18 @@ TPU design — sort-based segmented reduction in ONE fused kernel per batch:
 The merge phase runs the same kernel shape over concatenated partials with
 the merge ops.  All shapes static; only the final group count syncs to host.
 
-Every update takes ONE route (``TpuHashAggregateExec._run_update``):
-the filter / project chain the planner folded into the node
-(plan/fusion.py, docs/fusion.md; empty when nothing was folded) plus a
-last projection of the keys and inputs go through one code view
-(``encoding.stage_view``), run MASKED inside the update's own program
-(``exec.stage.emit_steps(..., compact=False)``) and the keep-mask is the
-update's liveness, so a folded filter costs one elementwise predicate
-and no compaction gather.  ``_compile_folded_update`` is the one builder.
+Every update takes ONE route (``TpuHashAggregateExec._stage`` ->
+``_update_group``): the filter / project chain the planner folded into
+the node (plan/fusion.py, docs/fusion.md; empty when nothing was
+folded) plus a last projection of the keys and inputs go through one
+code view (``encoding.stage_view``), run MASKED inside the update's own
+program (``exec.stage.emit_steps(..., compact=False)``) and the
+keep-mask is the update's liveness, so a folded filter costs one
+elementwise predicate and no compaction gather.
+``_compile_folded_update`` is the one builder.  Input batches whose
+dense updates share a program are updated as a GROUP, up to
+``GROUP_MEMBERS`` in one launch that returns one partial a batch: what a
+launch costs the host is paid once for all of them.
 
 The body under that mask is chosen from what the host can see of the
 batch (``_dense_domain``): a key domain the host already knows (every
@@ -61,8 +65,8 @@ from spark_rapids_tpu.exprs.base import (
     _batch_signature, _flatten_batch, hoisted_args,
 )
 from spark_rapids_tpu.utils.metrics import (
-    METRIC_MASKED_FILTER_BATCHES, METRIC_PALLAS_AGG_BATCHES,
-    METRIC_TOTAL_TIME,
+    METRIC_GROUPED_UPDATE_BATCHES, METRIC_MASKED_FILTER_BATCHES,
+    METRIC_PALLAS_AGG_BATCHES, METRIC_TOTAL_TIME,
 )
 
 
@@ -351,21 +355,44 @@ def _compile_agg(spec: _AggSpec, phase: str, input_sig, capacity: int):
     return fn
 
 
+# most input batches one update launch takes (``execute_columnar``): a
+# launch's host price is paid once for all of them, and a program is
+# compiled per member count it was asked for
+GROUP_MEMBERS = 8
+
+
 def _compile_folded_update(h_steps, input_sig, aux_sig, capacity: int,
-                           spec: _AggSpec, radices=None):
-    """The update program of one batch, and the only builder of one:
+                           spec: _AggSpec, radices=None, members: int = 1):
+    """The update program of a group of ``members`` input batches that
+    share everything but their planes, and the only builder of one:
     ``h_steps`` (hoisted, code-viewed: the chain the planner folded in,
     possibly empty, then a projection of ``spec``'s keys and inputs) run
-    MASKED, then the update body reduces under the steps' liveness — the
-    dense body over ``radices`` (exec/pallas_agg.py) or, with ``radices``
-    None, the sorted-segment body.  Literals ride in as traced scalars
-    and dictionary tables as aux inputs, so the key is literal-free: a
-    new binding of a prepared query reuses the program."""
+    MASKED over each member, then the update body reduces it under the
+    steps' liveness — the dense body over ``radices``
+    (exec/pallas_agg.py) or, with ``radices`` None, the sorted-segment
+    body.  It returns one ``(n_groups, keys, buffers, valid)`` a member
+    (a buffer whose validity is ``None`` is valid where ``valid`` says):
+    what that many launches of the one-member program return.  Literals ride
+    in as traced scalars and dictionary tables as aux inputs, so the key
+    is literal-free: a new binding of a prepared query reuses the
+    program.
+
+    ``run(members_flat, members_aux, rows, hoisted, bases)``: ``rows``
+    is ``int32[members]`` (or, where a count lives on the device, a
+    tuple of scalars), ``bases`` ``int64[members, keys]``, and a hoisted
+    slot is one scalar for every member or a vector with one value a
+    member — the host's arguments do not grow with the group.  One
+    member is traced alone; more are joined end to end inside the
+    program and mapped (``lax.map``), so the body compiles once whatever
+    the count
+    (CHANGES.md, PR 32, has what that and an unrolled trace compiled
+    in)."""
     from spark_rapids_tpu.exec.stage import emit_steps, stage_fingerprint
     dense = radices is not None
     cache_key = ("folded", stage_fingerprint(h_steps), input_sig, aux_sig,
                  capacity, spec.key(),
-                 tuple(int(r) for r in radices) if dense else None)
+                 tuple(int(r) for r in radices) if dense else None,
+                 members)
     fn = _AGG_CACHE.get(cache_key)
     if fn is not None:
         return fn
@@ -375,22 +402,126 @@ def _compile_folded_update(h_steps, input_sig, aux_sig, capacity: int,
     else:
         body = make_agg_body(spec, "update", capacity)
 
-    def run(flat_cols, aux, num_rows, hoisted, bases):
+    def one(flat_cols, aux, num_rows, hoisted, bases):
         cols = [ColVal(*t) for t in flat_cols]
         # folded steps are deterministic: the partition id is unread
         cols, live = emit_steps(h_steps, cols, num_rows, capacity,
                                 jnp.int64(0), hoisted, aux=aux,
                                 compact=False)
         flat = tuple((c.data, c.validity, c.chars) for c in cols)
-        if dense:
-            return body(flat, num_rows, bases, live)
-        return body(flat, num_rows, live)
+        n_groups, key_outs, buf_outs = body(flat, num_rows, bases, live) \
+            if dense else body(flat, num_rows, live)
+        # a partial's buffers are all valid where its groups are: that
+        # plane leaves once, not once a buffer (``None`` stands for it).
+        # An output buffer is the dearest thing in a launch, 46 us of
+        # host time each on the chip (PERF.md, PR 32)
+        valid = buf_outs[0].validity if buf_outs else None
+        return n_groups, key_outs, tuple(
+            ColVal(b.data, None if b.validity is valid else b.validity,
+                   b.chars) for b in buf_outs), valid
+
+    def run(members_flat, members_aux, rows, hoisted, bases):
+        if members == 1:  # every slot is a scalar: nothing to bind by
+            return (one(members_flat[0], members_aux[0], rows[0], hoisted,
+                        bases[0]),)
+        # joined and mapped, not unrolled: the body compiles once
+        # whatever the count.  Members are joined end to end along the
+        # rows, so that each is a run of whole tiles: stacked under a
+        # new leading axis that axis is tiled too, and a member is read
+        # in strides through it (PERF.md, PR 32)
+        def join(*planes):
+            return jnp.concatenate(planes)
+
+        def part(m):
+            def of(joined):
+                n = joined.shape[0] // members
+                return jax.lax.dynamic_slice_in_dim(joined, m * n, n)
+            return of
+
+        flat = jax.tree_util.tree_map(join, *members_flat)
+        aux = jax.tree_util.tree_map(join, *members_aux)
+        rows = rows if hasattr(rows, "ndim") else jnp.stack(rows)
+
+        def member(m):
+            return one(jax.tree_util.tree_map(part(m), flat),
+                       jax.tree_util.tree_map(part(m), aux), rows[m],
+                       tuple(h if h.ndim == 0 else h[m] for h in hoisted),
+                       bases[m])
+
+        outs = jax.lax.map(member, jnp.arange(members, dtype=jnp.int32))
+        return tuple(jax.tree_util.tree_map(lambda a: a[m], outs)
+                     for m in range(members))
 
     fn = engine_jit(run, family="aggregate",
                     name="masked_pallas_update" if dense
                     else "masked_update")
     _AGG_CACHE[cache_key] = fn
     return fn
+
+
+def _hoisted_by_member(values: Sequence[tuple]) -> tuple:
+    """``hoisted_args`` for a group whose members bind other literals
+    to the same slots (``values``: one ``hoist_steps`` tuple a member):
+    a slot every member binds alike goes once, as ``hoisted_args`` sends
+    it; any other as one vector, a value a member."""
+    from spark_rapids_tpu.columnar.dtypes import device_dtype
+    out = []
+    for slot in zip(*values):
+        vals = [v for v, _ in slot]
+        same = all(v == vals[0] for v in vals[1:])
+        out.append(np.asarray(vals[0] if same else vals,
+                              device_dtype(slot[0][1])))
+    return tuple(out)
+
+
+class _Member:
+    """One input batch's update as the host staged it
+    (``TpuHashAggregateExec._stage``), without the batch's planes: the
+    batch may wait for its group as a spillable handle, and
+    ``encoding.stage_planes`` reads the planes again at the launch."""
+
+    __slots__ = ("steps", "steps_key", "sig", "aux", "aux_sig", "capacity",
+                 "wrap", "spec", "radices", "bases", "bound", "_hoisted")
+
+    def __init__(self, view, capacity: int, wrap, spec: _AggSpec, radices,
+                 bases, bound: int):
+        from spark_rapids_tpu.exec.stage import stage_fingerprint
+        self.steps = view.steps
+        # literals and all: equal keys bind equal values
+        self.steps_key = stage_fingerprint(view.steps)
+        self.sig, self.aux, self.aux_sig = view.sig, view.aux, view.aux_sig
+        self.capacity = capacity
+        self.wrap = wrap          # {key position -> DictPlanes}
+        self.spec = spec
+        self.radices = None if radices is None \
+            else tuple(int(r) for r in radices)
+        self.bases = tuple(bases)
+        self.bound = bound
+        self._hoisted = None
+
+    def hoisted(self):
+        """``(h_steps, values)``: the steps with their literals hoisted
+        into slots, and the values this member binds to them."""
+        if self._hoisted is None:
+            from spark_rapids_tpu.exec.stage import hoist_steps
+            self._hoisted = hoist_steps(self.steps)
+        return self._hoisted
+
+    def shares_program(self, other: "_Member") -> bool:
+        """Whether ``other``'s update can ride this member's launch:
+        both dense, and everything ``_compile_folded_update`` keys on
+        equal — signatures, capacity, spec (the coded key positions
+        decide it), radices and the hoisted steps.  Steps that differ
+        in a literal only still share: the slot binds by member."""
+        from spark_rapids_tpu.exec.stage import stage_fingerprint
+        if self.radices is None or other.radices != self.radices \
+                or (other.sig, other.aux_sig, other.capacity) \
+                != (self.sig, self.aux_sig, self.capacity) \
+                or sorted(other.wrap) != sorted(self.wrap):
+            return False
+        return other.steps_key == self.steps_key or \
+            stage_fingerprint(other.hoisted()[0]) \
+            == stage_fingerprint(self.hoisted()[0])
 
 
 _EVAL_CACHE = KernelCache("aggregate.eval", 256)
@@ -525,20 +656,16 @@ class TpuHashAggregateExec(TpuExec):
             for j, (n, f) in enumerate(self.agg_pairs)]
         return _AggSpec(groupings, aggs)
 
-    def _run_update(self, batch: ColumnarBatch, conf=None):
-        """One update over an input batch, the only route there is: the
-        folded steps (none, when the planner folded nothing) and a last
-        projection of this node's keys and inputs go through one code
-        view (``encoding.stage_view``: predicates over dictionary
-        columns become code-set membership, bare dictionary keys stay
-        codes and re-wrap on the way out, plane-compressed columns
-        decode in-kernel), literals hoist out of the key, and the
-        program reduces under the steps' keep-mask
-        (``_compile_folded_update``) -- dense over the key domain the
-        host knows, else sorted."""
+    def _stage(self, batch: ColumnarBatch, conf=None) -> "_Member":
+        """What the host decides about one input batch's update, and
+        the only route to one: the folded steps (none, when the planner
+        folded nothing) and a last projection of this node's keys and
+        inputs go through one code view (``encoding.stage_view``:
+        predicates over dictionary columns become code-set membership,
+        bare dictionary keys stay codes and re-wrap on the way out,
+        plane-compressed columns decode in-kernel), and the body is
+        dense over the key domain the host knows, else sorted."""
         from spark_rapids_tpu.columnar import encoding
-        from spark_rapids_tpu.columnar.column import LazyRows
-        from spark_rapids_tpu.exec.stage import hoist_steps, norm_rows
         with self.metrics.timed("computeAggTime"):
             nk = len(self.groupings)
             inputs = tuple(f.child for _, f in self.agg_pairs)
@@ -547,28 +674,97 @@ class TpuHashAggregateExec(TpuExec):
                                        dense_tail=len(inputs))
             wrap = {i: d for i, d in view.wrap.items() if i < nk}
             spec = self._update_spec(wrap)
-            h_steps, values = hoist_steps(view.steps)
             domain = self._dense_domain(spec, batch, wrap, conf, view)
             if domain is not None:
                 # the partial has the shape of its key domain: every
                 # possible key combination owns a slot, so the bound
                 # cannot cut a group off
                 radices, bases, bound = domain
-                self.metrics[METRIC_PALLAS_AGG_BATCHES].add(1)
             else:
                 radices, bases = None, ()
                 # n_groups <= num_rows, except empty-input global agg
                 bound = max(1, min(batch.rows_bound, batch.capacity))
-            fn = _compile_folded_update(h_steps, view.sig, view.aux_sig,
-                                        batch.capacity, spec, radices)
-            n_groups, key_outs, buf_outs = fn(
-                view.flat, view.aux, norm_rows(batch),
-                hoisted_args(values), np.asarray(bases, np.int64))
+            return _Member(view, batch.capacity, wrap, spec, radices,
+                           bases, bound)
+
+    def _update_group(self, members: Sequence["_Member"],
+                      batches: Sequence[ColumnarBatch]
+                      ) -> List[ColumnarBatch]:
+        """One launch over ``batches`` as ``members`` staged them (they
+        share a program: ``_Member.shares_program``), one partial a
+        member: literals hoist out of the key once for the group, and
+        the program reduces each member under its steps' keep-mask
+        (``_compile_folded_update``)."""
+        from spark_rapids_tpu.columnar.column import LazyRows
+        from spark_rapids_tpu.columnar.encoding import stage_planes
+        from spark_rapids_tpu.exec.stage import norm_rows
+        with self.metrics.timed("computeAggTime"):
+            head, n = members[0], len(members)
+            h_steps, values = head.hoisted()
+            fn = _compile_folded_update(h_steps, head.sig, head.aux_sig,
+                                        head.capacity, head.spec,
+                                        head.radices, n)
+            if all(m.steps_key == head.steps_key for m in members[1:]):
+                hoisted = hoisted_args(values)
+            else:
+                hoisted = _hoisted_by_member(
+                    [m.hoisted()[1] for m in members])
+            rows = [norm_rows(b) for b in batches]
+            # one host array, unless a count lives on the device (an
+            # eager stack would be a device op of its own)
+            rows = np.asarray(rows, np.int32) \
+                if all(isinstance(r, np.integer) for r in rows) \
+                else tuple(rows)
+            outs = fn(tuple(stage_planes(b)[0] for b in batches),
+                      tuple(m.aux for m in members), rows, hoisted,
+                      np.asarray([m.bases for m in members], np.int64))
+            if head.radices is not None:
+                self.metrics[METRIC_PALLAS_AGG_BATCHES].add(n)
             if self.pre_steps:
-                self.metrics[METRIC_MASKED_FILTER_BATCHES].add(1)
-            return _colvals_to_batch(
-                list(key_outs) + list(buf_outs), self._buffer_dtypes(),
-                LazyRows(n_groups, bound), wrap=wrap)
+                self.metrics[METRIC_MASKED_FILTER_BATCHES].add(n)
+            if n > 1:
+                self.metrics[METRIC_GROUPED_UPDATE_BATCHES].add(n)
+            return [
+                _colvals_to_batch(
+                    list(key_outs) + [
+                        b if b.validity is not None
+                        else ColVal(b.data, valid, b.chars)
+                        for b in buf_outs],
+                    self._buffer_dtypes(), LazyRows(n_groups, m.bound),
+                    wrap=m.wrap)
+                for m, (n_groups, key_outs, buf_outs, valid)
+                in zip(members, outs)]
+
+    def _run_update(self, batch: ColumnarBatch, conf=None):
+        """One update over one input batch: a group of one."""
+        return self._update_group([self._stage(batch, conf)], [batch])[0]
+
+    def _update_with_retry(self, members, batches, ctx):
+        """OOM -> spill-retry, then split rows and retry (reference
+        RmmRapidsRetryIterator withRetry + SplitAndRetryOOM,
+        aggregate.scala update path).  A group retries whole after the
+        spill; one that still does not fit is given up, and its members
+        go one a launch, where the rows split to the full depth."""
+        from spark_rapids_tpu.utils.retry import (
+            is_device_oom, split_batch_half, with_retry,
+        )
+        if len(members) > 1:
+            try:
+                (parts,) = with_retry(
+                    lambda bs: self._update_group(members, bs), batches,
+                    ctx)
+                return parts
+            except Exception as e:
+                if not is_device_oom(e):
+                    raise
+        parts = []
+        for m, b in zip(members, batches):
+            # a half is another batch: it is staged anew
+            parts.extend(with_retry(
+                lambda x: self._update_group(
+                    [m if x is b else self._stage(x, ctx.conf)], [x])[0],
+                b, ctx, split=split_batch_half))
+        return parts
 
     def _run_merge(self, batch: ColumnarBatch):
         """Merge concatenated partials: the sorted body with the merge
@@ -661,18 +857,37 @@ class TpuHashAggregateExec(TpuExec):
             # catalog (reference: partials are spillable between update
             # and merge, aggregate.scala:366-391)
             partials = []
+            # the open group: batches whose updates share a program wait
+            # here for one launch, spillable while they wait as the
+            # coalesce's pending batches are (exec/coalesce.py), their
+            # staging beside them
+            members, waiting = [], []
+
+            def update(ms, batches):
+                for part in self._update_with_retry(ms, batches, ctx):
+                    partials.append(SpillableBatch(part, cat))
+
+            def close_group():
+                if members:
+                    ms, handles = members[:], waiting[:]
+                    del members[:], waiting[:]
+                    update(ms, materialize_all(handles, ctx))
+
             try:
-                from spark_rapids_tpu.utils.retry import (
-                    split_batch_half, with_retry,
-                )
                 for batch in self.children[0].execute_columnar(ctx):
-                    # OOM -> spill-retry, then split rows and retry
-                    # (reference RmmRapidsRetryIterator withRetry +
-                    # SplitAndRetryOOM, aggregate.scala update path)
-                    for part in with_retry(
-                            lambda b: self._run_update(b, ctx.conf),
-                            batch, ctx, split=split_batch_half):
-                        partials.append(SpillableBatch(part, cat))
+                    m = self._stage(batch, ctx.conf)
+                    if members and not members[0].shares_program(m):
+                        close_group()
+                    if m.radices is None:
+                        # the sorted body: a launch is device work, not
+                        # host price, and nothing waits for a group
+                        update([m], [batch])
+                        continue
+                    members.append(m)
+                    waiting.append(SpillableBatch(batch, cat))
+                    if len(members) >= GROUP_MEMBERS:
+                        close_group()
+                close_group()
                 if not partials:
                     if self.groupings:
                         return  # grouped agg of empty input -> no rows
@@ -683,6 +898,7 @@ class TpuHashAggregateExec(TpuExec):
                     partials.append(SpillableBatch(
                         self._run_update(empty), cat))  # sorted body
             except BaseException:
+                close_all(waiting)
                 close_all(partials)
                 raise
             many = len(partials) > 1

@@ -288,6 +288,7 @@ class SpillableBatch:
                                                  self.num_rows,
                                                  chars=ch))
                 out = ColumnarBatch(cols, self.num_rows, self.schema)
+                out._size = self.size  # the same planes: no second walk
         finally:
             with cat._lock:
                 self.pinned = was_pinned

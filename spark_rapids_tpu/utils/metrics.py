@@ -89,6 +89,7 @@ METRIC_SEM_WAIT_MS = "semWaitMs"
 METRIC_DATA_SIZE = "dataSize"
 METRIC_PALLAS_AGG_BATCHES = "pallasAggBatches"
 METRIC_MASKED_FILTER_BATCHES = "maskedFilterBatches"
+METRIC_GROUPED_UPDATE_BATCHES = "groupedUpdateBatches"
 METRIC_FK_FAST_PATH_BATCHES = "fkFastPathBatches"
 METRIC_BAND_JOIN_PROBES = "bandJoinProbes"
 METRIC_SCAN_CACHE_HITS = "scanCacheHits"

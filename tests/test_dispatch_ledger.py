@@ -16,7 +16,7 @@ import pytest
 from spark_rapids_tpu import functions as F
 from spark_rapids_tpu.compile import service
 from spark_rapids_tpu.utils import tracing
-from tests.compare import tpu_session
+from tests.compare import sum_plan_metric, tpu_session
 
 TRACED = {"spark.rapids.sql.trace.enabled": "true"}
 
@@ -28,16 +28,23 @@ def _restore_switch():
     tracing.set_enabled(prev)
 
 
-def _lineitem(s, n=3000):
+def _lineitem(s, n=3000, chunks=1):
+    """``chunks`` > 1: as many record batches, so as many scan batches."""
     rng = np.random.default_rng(5)
-    return s.create_dataframe(pa.table({
+    return s.create_dataframe(_chunked(chunks, pa.table({
         "flag": pa.array(rng.choice(["A", "N", "R"], n)),
         "status": pa.array(rng.choice(["F", "O"], n)),
         "qty": pa.array(rng.integers(1, 50, n).astype(np.float64)),
         "price": pa.array(rng.uniform(900, 100000, n)),
         "disc": pa.array(rng.integers(0, 11, n) / 100.0),
         "ship": pa.array(rng.integers(8000, 10600, n), pa.int32()),
-    }))
+    })))
+
+
+def _chunked(chunks: int, t: pa.Table) -> pa.Table:
+    size = t.num_rows // chunks
+    return pa.concat_tables([t.slice(i * size, size)
+                             for i in range(chunks)])
 
 
 def _q1(s):
@@ -49,8 +56,8 @@ def _q1(s):
         F.count(F.col("qty")).alias("n")).order_by("flag", "status")
 
 
-def _q6(s):
-    return _lineitem(s).filter(
+def _q6(s, chunks=1):
+    return _lineitem(s, chunks=chunks).filter(
         (F.col("ship") >= 8766) & (F.col("ship") < 9131)
         & (F.col("disc") >= 0.05) & (F.col("qty") < 24)).agg(
         F.sum(F.col("price") * F.col("disc")).alias("revenue"))
@@ -304,6 +311,28 @@ def test_node_device_time_adds_up_to_the_programs_group(query, update):
     assert sum(r["untimed"] for r in rows) == 0
     assert "device=" in txt and "dispatches=" in txt
     assert "Programs:" in txt and f"{update}: dispatches=" in txt
+
+
+def test_a_group_of_batches_is_one_dispatch_of_the_update():
+    """Six input batches whose updates share a program ride one launch
+    (exec/aggregate.py, ``GROUP_MEMBERS``): the update's row counts one
+    dispatch a query where it counted six, the node's dispatches add up
+    as before, and the node's batch counters still count batches."""
+    s = tpu_session({**TRACED,
+                     "spark.rapids.sql.reader.batchSizeRows": "512",
+                     "spark.rapids.sql.batchSizeBytes": "8192"})
+    _q6(s, chunks=6).collect()
+    before = s.engine_stats()
+    _q6(s, chunks=6).collect()
+    after = s.engine_stats()
+    prof = s.last_query_profile().to_dict()
+    by_program = {r["program"]: r["dispatches"] for r in prof["programs"]}
+    assert by_program["aggregate_masked_pallas_update"] == 1
+    nodes = _node_sums(prof["plan"], {"device_ns": 0, "dispatches": 0})
+    assert nodes["dispatches"] == sum(by_program.values()) \
+        == _growth(before, after)["dispatches"]
+    assert sum_plan_metric(s, "pallasAggBatches") == 6
+    assert sum_plan_metric(s, "groupedUpdateBatches") == 6
 
 
 def test_phases_count_plan_execute_and_blocking_reads():

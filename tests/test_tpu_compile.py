@@ -118,17 +118,23 @@ def test_dense_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
     prog = agg_mod._compile_folded_update(h_steps, sig, (), CAP, spec,
                                           radices)
     agg_mod._AGG_CACHE.clear()
-    flat, aux, n, _pid, hoisted = stage.aval_inputs(sig, CAP, values)
+    flat, aux, _n, _pid, hoisted = stage.aval_inputs(sig, CAP, values)
     avals = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                        sharding=one_chip),
-        (flat, aux, n, hoisted, jax.ShapeDtypeStruct((nk,), jnp.int64)))
+        ((flat,), (aux,), jax.ShapeDtypeStruct((1,), jnp.int32), hoisted,
+         jax.ShapeDtypeStruct((1, nk), jnp.int64)))
     lowered = prog.lower(*avals)
     assert "tpu_custom_call" in lowered.compile().as_text()
-    _n_groups, key_outs, buf_outs = lowered.out_info
+    ((_n_groups, key_outs, buf_outs, _valid),) = lowered.out_info
     assert len(key_outs) == nk and len(buf_outs) == 15
     out_cap = 16 if radices else 8
     assert {cv.data.shape for cv in key_outs + buf_outs} == {(out_cap,)}
+    # the buffers' validity leaves once (an output buffer is what a
+    # launch costs the host): a count, two planes a key, one a buffer
+    assert all(cv.validity is None for cv in buf_outs)
+    assert len(jax.tree_util.tree_leaves(lowered.out_info)) == \
+        1 + 2 * nk + 15 + 1
 
 
 # -- the folded update: q1 and q6 as the planner hands them over ----------
@@ -179,22 +185,24 @@ def folded_updates(tmp_path_factory):
     return out
 
 
-def _folded_at_cap(folded_updates, query, sharding=None):
-    """(program, avals) of ``query``'s folded update at ``CAP``."""
+def _folded_at_cap(folded_updates, query, sharding=None, members=1):
+    """(program, avals) of ``query``'s folded update over ``members``
+    batches of ``CAP`` rows (lineitem at SF1 is six)."""
     import spark_rapids_tpu.exec.aggregate as agg_mod
     from spark_rapids_tpu.exec import stage
-    (h_steps, sig, aux_sig, cap, spec, radices), values = \
+    (h_steps, sig, aux_sig, cap, spec, radices, _one), values = \
         folded_updates[query]
     assert radices is not None  # both take the dense body
     sig = tuple((name, CAP if c == cap else c, w) for name, c, w in sig)
     agg_mod._AGG_CACHE.clear()
     prog = agg_mod._compile_folded_update(h_steps, sig, aux_sig, CAP,
-                                          spec, radices)
+                                          spec, radices, members)
     agg_mod._AGG_CACHE.clear()
-    flat, aux, n, _pid, hoisted = stage.aval_inputs(sig, CAP, values,
-                                                    aux_sig)
-    avals = (flat, aux, n, hoisted,
-             jax.ShapeDtypeStruct((len(radices),), jnp.int64))
+    flat, aux, _n, _pid, hoisted = stage.aval_inputs(sig, CAP, values,
+                                                     aux_sig)
+    avals = ((flat,) * members, (aux,) * members,
+             jax.ShapeDtypeStruct((members,), jnp.int32), hoisted,
+             jax.ShapeDtypeStruct((members, len(radices)), jnp.int64))
     if sharding is not None:
         avals = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
@@ -223,14 +231,16 @@ def _row_plane_moves(jaxpr) -> list:
     return found
 
 
+@pytest.mark.parametrize("members", [1, 6], ids=["one", "group"])
 @pytest.mark.parametrize("query", ["q1", "q6"])
-def test_folded_update_moves_no_row_plane(folded_updates, query):
+def test_folded_update_moves_no_row_plane(folded_updates, query, members):
     """The gathers cannot come back unseen: with the filter folded in,
     what is left outside the Pallas call is elementwise (the predicate,
     the slot, the masked planes) and K-slot work at the end — no gather
-    and no scatter over 2^20 rows.  And neither query launched a
-    ``stage_*`` program."""
-    prog, avals = _folded_at_cap(folded_updates, query)
+    and no scatter over 2^20 rows, in the group's program neither (its
+    stack is a concatenation, its map a loop of slices).  And neither
+    query launched a ``stage_*`` program."""
+    prog, avals = _folded_at_cap(folded_updates, query, members=members)
     jaxpr = prog.trace(*avals).jaxpr
     assert "pallas_call" in str(jaxpr)
     assert _row_plane_moves(jaxpr.jaxpr) == []
@@ -254,22 +264,29 @@ def test_a_compacting_filter_is_what_the_guard_would_catch():
     assert _row_plane_moves(jaxpr.jaxpr)
 
 
+@pytest.mark.parametrize("members", [1, 6], ids=["one", "group"])
 @pytest.mark.parametrize("query", ["q1", "q6"])
 def test_folded_update_compiles_for_v5e(one_chip, mosaic, monkeypatch,
-                                        folded_updates, query):
+                                        folded_updates, query, members):
     """The whole program the chip runs per batch of q1 and q6 since the
     fold — predicate, mixed-radix slot, Mosaic accumulation, K-slot
-    tail — at 2^20 rows with the device's f32 doubles; its optimized
-    HLO moves no 2^20-long plane through a gather or a scatter."""
+    tail — at 2^20 rows with the device's f32 doubles, and the program
+    that updates all six batches of lineitem at SF1 in one launch (the
+    same body under ``lax.map`` over a stack of the members); neither's
+    optimized HLO moves a 2^20-long plane through a gather or a
+    scatter, and the group's temporaries (the stack, a member's planes)
+    stay a small part of the chip."""
     from spark_rapids_tpu.columnar import dtypes
     monkeypatch.setattr(dtypes, "_DOUBLE_AS_FLOAT", True)
-    prog, avals = _folded_at_cap(folded_updates, query, one_chip)
-    text = prog.lower(*avals).compile().as_text()
+    prog, avals = _folded_at_cap(folded_updates, query, one_chip, members)
+    compiled = prog.lower(*avals).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     moves = [ln.strip()[:160] for ln in text.splitlines()
              if (" gather(" in ln or " scatter(" in ln)
-             and f"[{CAP}" in ln]
+             and f"{CAP}]" in ln]
     assert moves == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_pallas_agg_refuses_64bit_planes_on_the_chip(mosaic):
