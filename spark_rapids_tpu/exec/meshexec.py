@@ -144,9 +144,11 @@ def _bump_fallback(code: str) -> None:
 def ici_stats() -> dict:
     """Process-wide ICI snapshot, merged with the gather-egress
     counters (parallel/mesh.py: per-chip parallel result pulls and the
-    link wall time the fan-out reclaimed) and the sharded-scan ingest
-    counters (parallel/shardscan.py) so bench.py and the acceptance
-    tests read ONE dict."""
+    link wall time the fan-out reclaimed, and ``ingest_us`` /
+    ``collective_us`` / ``gather_us``: the microseconds mesh fragments
+    spent in each of their three phases, the ``ici.*`` spans) and the
+    sharded-scan ingest counters (parallel/shardscan.py) so bench.py
+    and the acceptance tests read ONE dict."""
     from spark_rapids_tpu.parallel import mesh as _mesh
     from spark_rapids_tpu.parallel import shardscan as _shardscan
     with _ICI_LOCK:
@@ -383,10 +385,13 @@ def _concat_from_handles(handles, ctx: ExecContext):
     the copy) and fuse into the ONE batch the SPMD pipelines consume;
     None when the stream was empty."""
     from spark_rapids_tpu.memory.spill import materialize_all
+    from spark_rapids_tpu.parallel.mesh import phase
     if not handles:
         return None
-    batches = materialize_all(handles, ctx)
-    return batches[0] if len(batches) == 1 else concat_batches(batches)
+    with phase("ingest_us"):
+        batches = materialize_all(handles, ctx)
+        return batches[0] if len(batches) == 1 \
+            else concat_batches(batches)
 
 
 def _drain_single_batch(child, ctx: ExecContext):
@@ -529,9 +534,11 @@ def _attempt_sharded(node: TpuExec, ctx: ExecContext, idx: int):
         # health-on: the chip set the pipeline was built over is the
         # set the gate must consult/credit
         node._health_chips = node._dist_n
+    from spark_rapids_tpu.parallel.mesh import phase
     try:
-        return shardscan.ingest_child(spec, ctx, dist.mesh,
-                                      metrics=node.metrics), None
+        with phase("ingest_us"):
+            return shardscan.ingest_child(spec, ctx, dist.mesh,
+                                          metrics=node.metrics), None
     except InjectedFault as e:
         if e.site != shardscan.FAULT_SITE_INGEST:
             raise  # another site's fault keeps its own recovery path
@@ -868,6 +875,54 @@ def _realias(name, func):
     return Alias(func, name)
 
 
+def _over_read_columns(node):
+    """``node`` (a grouped ``TpuHashAggregateExec``) rebuilt over a
+    projection of the child columns its groupings and aggregates read,
+    or ``node`` itself where it reads them all.  A scan hands over every
+    column of its table and the single-chip aggregate just leaves the
+    others alone, but a mesh fragment drains, splits and uploads its
+    WHOLE input: an aggregate straight over lineitem would move sixteen
+    columns to keep two, and at SF1 that is over
+    ``spark.rapids.shuffle.ici.maxStageBytes`` where the two are a
+    tenth of it."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.basic import TpuProjectExec
+    from spark_rapids_tpu.exprs.base import BoundReference
+    child = node.children[0]
+    fields = child.output_schema.fields
+    exprs = list(node.groupings) + list(node.aggregates)
+    read = set()
+
+    def collect(e):
+        if isinstance(e, BoundReference):
+            read.add(e.ordinal)
+        for c in e.children:
+            collect(c)
+
+    for e in exprs:
+        collect(e)
+    if node.pre_steps or not read or len(read) == len(fields):
+        return node
+    kept = sorted(read)
+    moved = {o: i for i, o in enumerate(kept)}
+
+    def rebind(e):
+        if isinstance(e, BoundReference):
+            return BoundReference(moved[e.ordinal], e.dtype, e.nullable,
+                                  e.col_name)
+        if not e.children:
+            return e
+        return e.with_children([rebind(c) for c in e.children])
+
+    project = TpuProjectExec(
+        [BoundReference(o, fields[o].dtype, fields[o].nullable,
+                        fields[o].name) for o in kept], child)
+    n_keys = len(node.groupings)
+    rebound = [rebind(e) for e in exprs]
+    return TpuHashAggregateExec(rebound[:n_keys], rebound[n_keys:],
+                                project)
+
+
 def _lower_fragments(plan, n: int, guarded: bool):
     """Rewrite single-chip aggregate/sort/join execs to the
     mesh-parallel forms.  ``guarded`` = the ICI production mode: the
@@ -887,6 +942,7 @@ def _lower_fragments(plan, n: int, guarded: bool):
         node.children = [rewrite(c) for c in node.children]
         if isinstance(node, TpuHashAggregateExec) and node.groupings:
             # grouping-set flavors route through Expand and still match
+            node = _over_read_columns(node)
             new = TpuMeshAggregateExec(
                 node.groupings,
                 [_realias(n_, f_) for n_, f_ in node.agg_pairs],

@@ -37,7 +37,9 @@ from spark_rapids_tpu.exec.aggregate import (
     _AggSpec, make_agg_body, unwrap_aggregate,
 )
 from spark_rapids_tpu.exprs.base import ColVal, Expression
-from spark_rapids_tpu.parallel.mesh import DATA_AXIS, data_mesh, shard_table
+from spark_rapids_tpu.parallel.mesh import (
+    DATA_AXIS, data_mesh, phase, shard_table,
+)
 
 
 def _hash_pids(key_cvs: Sequence[ColVal], key_dtypes, n_dev: int,
@@ -209,7 +211,7 @@ class DistributedAggregate:
         fn = self._step_cache.get(cap)
         if fn is None:
             fn = engine_jit(self._build_step(cap),
-                            family="exchange", name="dist_agg")
+                            family="exchange", name="mesh_aggregate")
             self._step_cache[cap] = fn
         return fn
 
@@ -235,9 +237,10 @@ class DistributedAggregate:
         (parallel/shardscan.py, docs/sharded_scan.md) — the latter land
         here with every shard committed to its own chip, so the
         exchange program consumes them without any host re-split."""
-        n_groups, out_cols = self._step(cap)(tuple(stacked), counts,
-                                             extra)
-        return np.asarray(n_groups), out_cols
+        with phase("collective_us"):
+            n_groups, out_cols = self._step(cap)(tuple(stacked), counts,
+                                                 extra)
+            return np.asarray(n_groups), out_cols
 
     def gather(self, n_groups: np.ndarray, out_cols,
                parallel_pull: bool = False) -> ColumnarBatch:

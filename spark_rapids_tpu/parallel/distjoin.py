@@ -310,7 +310,7 @@ class DistributedHashJoin:
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
             out_specs=P(DATA_AXIS)),
-            family="exchange", name="dist_join_count")
+            family="exchange", name="mesh_join_count")
         self._count_cache[key] = fn
         return fn
 
@@ -474,7 +474,7 @@ class DistributedHashJoin:
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
             out_specs=(P(DATA_AXIS), P(DATA_AXIS))),
-            family="exchange", name="dist_join")
+            family="exchange", name="mesh_join")
         self._join_cache[key] = fn
         return fn
 
@@ -516,12 +516,14 @@ class DistributedHashJoin:
         from the sharded scan ingest (parallel/shardscan.py), including
         mixed — each side's arrays just feed the same SPMD programs."""
         from spark_rapids_tpu.columnar.column import bucket_capacity
-        totals = np.asarray(self._count_step(lcap, rcap)(
-            tuple(sl), jl, tuple(sr), jr))
-        out_cap = bucket_capacity(max(1, int(totals.max())))
-        ns, blocks = self._join_step(lcap, rcap, out_cap)(
-            tuple(sl), jl, tuple(sr), jr)
-        return np.asarray(ns), blocks  # ns: (n_dev, n_blocks)
+        from spark_rapids_tpu.parallel.mesh import phase
+        with phase("collective_us"):
+            totals = np.asarray(self._count_step(lcap, rcap)(
+                tuple(sl), jl, tuple(sr), jr))
+            out_cap = bucket_capacity(max(1, int(totals.max())))
+            ns, blocks = self._join_step(lcap, rcap, out_cap)(
+                tuple(sl), jl, tuple(sr), jr)
+            return np.asarray(ns), blocks  # ns: (n_dev, n_blocks)
 
     def gather(self, ns: np.ndarray, blocks,
                parallel_pull: bool = False) -> ColumnarBatch:

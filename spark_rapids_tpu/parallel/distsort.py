@@ -39,7 +39,9 @@ from spark_rapids_tpu.exprs.base import (
     ColVal, EvalContext, Expression, _batch_signature, _flatten_batch,
 )
 from spark_rapids_tpu.parallel.distagg import _bucket_scatter
-from spark_rapids_tpu.parallel.mesh import DATA_AXIS, data_mesh, shard_table
+from spark_rapids_tpu.parallel.mesh import (
+    DATA_AXIS, data_mesh, phase, shard_table,
+)
 
 
 def _emit_keys(orders, flat_cols, num_rows, cap: int, pad: int):
@@ -166,7 +168,7 @@ class DistributedSort:
         fn = self._step_cache.get((cap, pad))
         if fn is None:
             fn = engine_jit(self._build_step(cap, pad),
-                            family="exchange", name="dist_sort")
+                            family="exchange", name="mesh_sort")
             self._step_cache[(cap, pad)] = fn
         return fn
 
@@ -220,10 +222,11 @@ class DistributedSort:
         device-resident global arrays) with pre-computed ``bounds`` —
         ``_bounds`` for a drained batch, ``sample_bounds_sharded`` for
         per-shard device-resident views."""
-        jb = tuple(jnp.asarray(b) for b in bounds)
-        n_local, out_cols = self._step(cap, pad)(tuple(stacked), counts,
-                                                 jb)
-        return np.asarray(n_local), out_cols
+        with phase("collective_us"):
+            jb = tuple(jnp.asarray(b) for b in bounds)
+            n_local, out_cols = self._step(cap, pad)(tuple(stacked),
+                                                     counts, jb)
+            return np.asarray(n_local), out_cols
 
     def sample_bounds_sharded(self, views: List[ColumnarBatch],
                               sample_max: int = 10_000):

@@ -7,7 +7,9 @@ only compute parallelism; here one logical operator can span chips).
 
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -18,15 +20,22 @@ from jax.sharding import Mesh
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, bucket_capacity
 from spark_rapids_tpu.columnar.dtypes import Schema
+from spark_rapids_tpu.utils import tracing
 
 DATA_AXIS = "data"
 
-# process-wide gather-egress counters (merged into
+# process-wide counters of the mesh path (merged into
 # exec/meshexec.py:ici_stats() so bench.py and the sharded-scan tests
 # read one snapshot): parallel per-chip result pulls issued and the
-# link wall time the fan-out reclaimed (docs/sharded_scan.md)
+# link wall time the fan-out reclaimed (docs/sharded_scan.md), and the
+# microseconds a mesh fragment spent in each of its three phases
+# (``phase``; docs/ici_shuffle.md, "Phases")
 _GATHER_LOCK = threading.Lock()
-_GATHER = {"gather_pulls": 0, "gather_overlap_ms": 0}
+_GATHER = {"gather_pulls": 0, "gather_overlap_ms": 0,
+           "ingest_us": 0, "collective_us": 0, "gather_us": 0}
+_PHASE_SPANS = {"ingest_us": tracing.SPAN_ICI_INGEST,
+                "collective_us": tracing.SPAN_ICI_COLLECTIVE,
+                "gather_us": tracing.SPAN_ICI_GATHER}
 
 
 def gather_stats() -> dict:
@@ -44,6 +53,25 @@ def _bump_gather(pulls: int, overlap_ms: int) -> None:
     with _GATHER_LOCK:
         _GATHER["gather_pulls"] += int(pulls)
         _GATHER["gather_overlap_ms"] += int(overlap_ms)
+
+
+@contextlib.contextmanager
+def phase(counter: str):
+    """One phase of a mesh fragment: its span (``ici.ingest``,
+    ``ici.collective`` or ``ici.gather``, under the trace switch) and
+    its always-on microseconds in ``ici_stats()`` (``ingest_us``: the
+    drained child made one batch and split over the mesh, or scanned
+    shard by shard onto it; ``collective_us``: the ``shard_map``
+    programs from launch to their sync; ``gather_us``: the result
+    pulled back and made one batch).  Bumped once a fragment and phase,
+    never per row.  Also a decorator: ``@phase("gather_us")``."""
+    start = time.perf_counter_ns()
+    try:
+        with tracing.trace_range(_PHASE_SPANS[counter]):
+            yield
+    finally:
+        with _GATHER_LOCK:
+            _GATHER[counter] += (time.perf_counter_ns() - start) // 1000
 
 
 def data_mesh(n_devices: Optional[int] = None,
@@ -90,6 +118,7 @@ def _per_device_trees(out_cols, n_dev: int):
     return per
 
 
+@phase("gather_us")
 def gather_stacked(out_cols, counts: np.ndarray, dtypes,
                    schema: Optional[Schema] = None,
                    parallel_pull: bool = False) -> ColumnarBatch:
@@ -179,13 +208,15 @@ def gather_stacked(out_cols, counts: np.ndarray, dtypes,
     return ColumnarBatch(cols, total, schema)
 
 
+@phase("ingest_us")
 def shard_table(batch: ColumnarBatch, n_dev: int
                 ) -> Tuple[list, np.ndarray, int]:
     """Split one host-visible batch into ``n_dev`` equal-capacity row
     shards, stacked on a new leading device axis.
 
     Returns (stacked flat cols [(data, validity, chars), ...] with leading
-    axis n_dev, per-shard row counts (n_dev,), shard capacity).
+    axis n_dev, per-shard row counts (n_dev,), shard capacity).  The
+    split is part of the fragment's ingest (``phase``).
     """
     n = batch.num_rows
     per = -(-max(n, 1) // n_dev)

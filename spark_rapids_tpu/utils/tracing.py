@@ -66,6 +66,16 @@ SPAN_SERVER_EXECUTE = "server.execute"
 SPAN_SERVER_RESOLVE = "server.resolve"
 SPAN_SERVER_CACHE_KEY = "server.cache_key"
 
+# the three phases of a mesh fragment (exec/meshexec.py,
+# parallel/mesh.py ``phase``; docs/ici_shuffle.md): the drained child
+# made one batch and split over the mesh, or scanned shard by shard
+# onto it; the ``shard_map`` programs from launch to their sync; the
+# result pulled or stacked back into one batch.  Their microseconds are
+# the ``ici.ingest_us`` / ``collective_us`` / ``gather_us`` counters
+SPAN_ICI_INGEST = "ici.ingest"
+SPAN_ICI_COLLECTIVE = "ici.collective"
+SPAN_ICI_GATHER = "ici.gather"
+
 # Always-on phase counters (the ``phases`` group of ``engine_stats()``,
 # docs/observability.md): microseconds a query spent planning and
 # executing, microseconds a thread sat in a blocking device read, and
