@@ -146,7 +146,9 @@ def ici_stats() -> dict:
     counters (parallel/mesh.py: per-chip parallel result pulls and the
     link wall time the fan-out reclaimed, and ``ingest_us`` /
     ``collective_us`` / ``gather_us``: the microseconds mesh fragments
-    spent in each of their three phases, the ``ici.*`` spans) and the
+    spent in each of their three phases, the ``ici.*`` spans, and
+    ``program_lookups`` / ``program_hits``: mesh programs asked for and
+    found compiled, ``mesh.mesh_program``) and the
     sharded-scan ingest counters (parallel/shardscan.py) so bench.py
     and the acceptance tests read ONE dict."""
     from spark_rapids_tpu.parallel import mesh as _mesh

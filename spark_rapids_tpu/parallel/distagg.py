@@ -38,8 +38,10 @@ from spark_rapids_tpu.exec.aggregate import (
 )
 from spark_rapids_tpu.exprs.base import ColVal, Expression
 from spark_rapids_tpu.parallel.mesh import (
-    DATA_AXIS, data_mesh, phase, shard_table,
+    DATA_AXIS, data_mesh, mesh_key, mesh_program, phase, planes_signature,
+    shard_table,
 )
+from spark_rapids_tpu.utils.kernel_cache import KernelCache
 
 
 def _hash_pids(key_cvs: Sequence[ColVal], key_dtypes, n_dev: int,
@@ -93,7 +95,15 @@ class DistributedAggregate:
     (new_flat_cols, live_mask)``.  ``extra`` is a tuple of REPLICATED
     arrays (same full value on every device, in_spec ``P()``) — the
     mesh-sharded broadcast join rides this hook, with the broadcast build
-    table as the replicated extra."""
+    table as the replicated extra.
+
+    The jitted step lives in the process-wide memo of mesh programs
+    (``mesh.mesh_program``) under the mesh's chips, ``_AggSpec.key()``,
+    the shard capacity and the input planes' signature, so an object
+    built by a later plan runs the program an earlier plan compiled.  A
+    ``prelude`` is a closure and has no key by value: an object that has
+    one stays out of that memo and keeps its steps in one of its own,
+    which lives as long as the object does."""
 
     def __init__(self, groupings: Sequence[Expression],
                  aggregates: Sequence[Expression], mesh=None,
@@ -107,7 +117,8 @@ class DistributedAggregate:
         fields = [Field(g.name, g.dtype, g.nullable) for g in self.groupings]
         fields += [Field(n, f.dtype, f.nullable) for n, f in self.agg_pairs]
         self.output_schema = Schema(fields)
-        self._step_cache: dict = {}
+        self._prelude_steps = None if prelude is None else KernelCache(
+            "mesh_aggregate_prelude", 4, register=False)
 
     # -- compiled step ------------------------------------------------------
 
@@ -207,13 +218,15 @@ class DistributedAggregate:
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P()),
             out_specs=(P(DATA_AXIS), P(DATA_AXIS)))
 
-    def _step(self, cap: int):
-        fn = self._step_cache.get(cap)
-        if fn is None:
-            fn = engine_jit(self._build_step(cap),
-                            family="exchange", name="mesh_aggregate")
-            self._step_cache[cap] = fn
-        return fn
+    def _step(self, cap: int, planes_sig: tuple):
+        key = (cap, planes_sig)
+        if self.prelude is None:
+            key = ("aggregate", mesh_key(self.mesh), self.spec.key()) + key
+        return mesh_program(
+            key, lambda: engine_jit(self._build_step(cap),
+                                    family="exchange",
+                                    name="mesh_aggregate"),
+            self._prelude_steps)
 
     # -- host driver --------------------------------------------------------
 
@@ -238,8 +251,8 @@ class DistributedAggregate:
         here with every shard committed to its own chip, so the
         exchange program consumes them without any host re-split."""
         with phase("collective_us"):
-            n_groups, out_cols = self._step(cap)(tuple(stacked), counts,
-                                                 extra)
+            step = self._step(cap, planes_signature(stacked))
+            n_groups, out_cols = step(tuple(stacked), counts, extra)
             return np.asarray(n_groups), out_cols
 
     def gather(self, n_groups: np.ndarray, out_cols,

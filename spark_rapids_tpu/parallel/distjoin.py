@@ -25,17 +25,26 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from spark_rapids_tpu.compile.service import engine_jit
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
-from spark_rapids_tpu.columnar.dtypes import Schema
+from spark_rapids_tpu.columnar.column import bucket_capacity
+from spark_rapids_tpu.columnar.dtypes import Field, Schema
 from spark_rapids_tpu.exec.joins import (
     _compile_build, _hash_keys, _keys_equal,
 )
 from spark_rapids_tpu.exprs.base import (
     ColVal, EvalContext, Expression, _batch_signature, _flatten_batch,
 )
-from spark_rapids_tpu.parallel.distagg import DistributedAggregate
+from spark_rapids_tpu.parallel.distagg import (
+    DistributedAggregate, _bucket_scatter,
+)
+from spark_rapids_tpu.parallel.mesh import (
+    DATA_AXIS, data_mesh, gather_stacked, mesh_key, mesh_program, phase,
+    planes_signature, shard_table,
+)
 
 # hash-collision probe window: candidates examined per stream row; with
 # unique build keys only hash collisions ever add candidates
@@ -167,6 +176,76 @@ class DistributedBroadcastJoinAggregate(DistributedAggregate):
 # Repartition (shuffled) hash join over the mesh
 # ---------------------------------------------------------------------------
 
+# The traced pieces are functions of their arguments alone, so a program in
+# the process-wide memo holds no ``DistributedHashJoin`` and reads nothing
+# its key does not name.
+
+
+def _exchange_side(n_dev: int, flat_cols, num_rows, key_exprs, cap):
+    """Per-device: hash-partition the local shard by join-key hash
+    and all_to_all it; returns (merged col planes, live mask, key
+    hash, keys-valid) at n_dev*cap rows."""
+    cols = [ColVal(*t) for t in flat_cols]
+    ctx = EvalContext(cols, num_rows, cap)
+    h, kvalid, _ = _hash_keys(key_exprs, ctx)
+    live = jnp.arange(cap) < num_rows
+    pid = (h.astype(jnp.uint64) % jnp.uint64(n_dev)).astype(jnp.int32)
+    pid = jnp.where(live, pid, n_dev)
+    arrs: List[jnp.ndarray] = [h, kvalid]
+    layout = []
+    for cv in cols:
+        arrs.append(cv.data)
+        arrs.append(cv.validity)
+        layout.append(cv.chars is not None)
+        if cv.chars is not None:
+            arrs.append(cv.chars)
+    bufs, live_buf = _bucket_scatter(arrs, pid, n_dev, cap)
+    recv = [jax.lax.all_to_all(b, DATA_AXIS, split_axis=0,
+                               concat_axis=0, tiled=True)
+            for b in bufs]
+    recv_live = jax.lax.all_to_all(live_buf, DATA_AXIS, split_axis=0,
+                                   concat_axis=0, tiled=True)
+    flat = [r.reshape((n_dev * cap,) + r.shape[2:]) for r in recv]
+    mask = recv_live.reshape(-1)
+    h_m = flat[0]
+    kv_m = flat[1] & mask
+    out_cols = []
+    i = 2
+    for has_chars in layout:
+        data = flat[i]; i += 1
+        valid = flat[i] & mask; i += 1
+        chars = None
+        if has_chars:
+            chars = flat[i]; i += 1
+        out_cols.append((data, valid, chars))
+    return out_cols, mask, h_m, kv_m
+
+
+def _local_probe(h_l, kv_l, mask_l, h_r, kv_r, mask_r):
+    """Build over received right hashes, count candidates per left
+    row; returns (counts int64, lo, sorted_h, perm, run_len)."""
+    from spark_rapids_tpu.exec.sortkeys import bitonic_lex_sort
+    from spark_rapids_tpu.exec.joins import _left_search, _run_lengths
+    hb = jnp.where(mask_r & kv_r, h_r, jnp.iinfo(jnp.int64).max)
+    # pad to a power of two for the bitonic network: recv size is
+    # n_dev * cap and the mesh width need not be a power of two
+    pad_n = bucket_capacity(hb.shape[0])
+    if pad_n != hb.shape[0]:
+        hb = jnp.concatenate(
+            [hb, jnp.full(pad_n - hb.shape[0],
+                          jnp.iinfo(jnp.int64).max, hb.dtype)])
+    sorted_h, perm = bitonic_lex_sort([hb])
+    run_len = _run_lengths(sorted_h)
+    lo = _left_search(sorted_h, h_l)
+    n = sorted_h.shape[0]
+    loc = jnp.clip(lo, 0, n - 1)
+    present = (lo < n) & (jnp.take(sorted_h, loc) == h_l)
+    runs = jnp.where(present, jnp.take(run_len, loc), 0)
+    usable = mask_l & kv_l
+    counts = jnp.where(usable, runs, 0).astype(jnp.int64)
+    return counts, lo, sorted_h, perm
+
+
 class DistributedHashJoin:
     """Both sides hash-partitioned over the mesh with ``all_to_all``,
     then each device joins its key range locally — the fact-fact join
@@ -181,6 +260,13 @@ class DistributedHashJoin:
     Because a key's rows all land on one device, outer/semi/anti
     semantics are locally complete: unmatched rows are emitted by the
     device that owns the key.
+
+    Both jitted programs live in the process-wide memo of mesh programs
+    (``mesh.mesh_program``) under the mesh's chips, both sides' key
+    expressions, the join type, the capacities and both sides' plane
+    signatures: the object holds the schemas and their names, the memo
+    the programs, and a join built by a later plan runs what an earlier
+    plan compiled.
     """
 
     def __init__(self, left_keys: Sequence[Expression],
@@ -188,7 +274,6 @@ class DistributedHashJoin:
                  left_schema: Schema, right_schema: Schema,
                  join_type: str = "inner", mesh=None,
                  n_devices: int = None):
-        from spark_rapids_tpu.parallel.mesh import data_mesh
         if join_type not in ("inner", "left", "right", "full", "semi",
                              "anti"):
             raise ValueError(f"unsupported join type {join_type}")
@@ -199,7 +284,6 @@ class DistributedHashJoin:
         self.left_schema = left_schema
         self.right_schema = right_schema
         self.join_type = join_type
-        from spark_rapids_tpu.columnar.dtypes import Field
         lf = list(left_schema.fields)
         rf = list(right_schema.fields)
         if join_type in ("right", "full"):
@@ -210,86 +294,22 @@ class DistributedHashJoin:
             self.output_schema = left_schema
         else:
             self.output_schema = Schema(lf + rf)
-        self._count_cache: dict = {}
-        self._join_cache: dict = {}
 
-    # -- traced pieces ------------------------------------------------------
+    def _program(self, name: str, build, caps: tuple, planes_sig: tuple,
+                 *also):
+        """The program ``name`` at ``caps`` from the memo of mesh
+        programs; ``also`` is what its trace reads besides the keys, the
+        capacities and the planes (the join program: the join type; the
+        count program counts alike for every type)."""
+        key = (name, mesh_key(self.mesh),
+               tuple(e.key() for e in self.left_keys),
+               tuple(e.key() for e in self.right_keys),
+               caps, planes_sig) + also
+        return mesh_program(key, lambda: engine_jit(
+            build(*caps), family="exchange", name=name))
 
-    def _exchange_side(self, flat_cols, num_rows, key_exprs, cap):
-        """Per-device: hash-partition the local shard by join-key hash
-        and all_to_all it; returns (merged col planes, live mask, key
-        hash, keys-valid) at n_dev*cap rows."""
-        from spark_rapids_tpu.parallel.distagg import _bucket_scatter
-        from spark_rapids_tpu.parallel.mesh import DATA_AXIS
+    def _build_count_step(self, lcap: int, rcap: int):
         n_dev = self.n_dev
-        cols = [ColVal(*t) for t in flat_cols]
-        ctx = EvalContext(cols, num_rows, cap)
-        h, kvalid, _ = _hash_keys(key_exprs, ctx)
-        live = jnp.arange(cap) < num_rows
-        pid = (h.astype(jnp.uint64) % jnp.uint64(n_dev)).astype(jnp.int32)
-        pid = jnp.where(live, pid, n_dev)
-        arrs: List[jnp.ndarray] = [h, kvalid]
-        layout = []
-        for cv in cols:
-            arrs.append(cv.data)
-            arrs.append(cv.validity)
-            layout.append(cv.chars is not None)
-            if cv.chars is not None:
-                arrs.append(cv.chars)
-        bufs, live_buf = _bucket_scatter(arrs, pid, n_dev, cap)
-        recv = [jax.lax.all_to_all(b, DATA_AXIS, split_axis=0,
-                                   concat_axis=0, tiled=True)
-                for b in bufs]
-        recv_live = jax.lax.all_to_all(live_buf, DATA_AXIS, split_axis=0,
-                                       concat_axis=0, tiled=True)
-        flat = [r.reshape((n_dev * cap,) + r.shape[2:]) for r in recv]
-        mask = recv_live.reshape(-1)
-        h_m = flat[0]
-        kv_m = flat[1] & mask
-        out_cols = []
-        i = 2
-        for has_chars in layout:
-            data = flat[i]; i += 1
-            valid = flat[i] & mask; i += 1
-            chars = None
-            if has_chars:
-                chars = flat[i]; i += 1
-            out_cols.append((data, valid, chars))
-        return out_cols, mask, h_m, kv_m
-
-    def _local_probe(self, h_l, kv_l, mask_l, h_r, kv_r, mask_r):
-        """Build over received right hashes, count candidates per left
-        row; returns (counts int64, lo, sorted_h, perm, run_len)."""
-        from spark_rapids_tpu.exec.sortkeys import bitonic_lex_sort
-        from spark_rapids_tpu.exec.joins import _left_search, _run_lengths
-        from spark_rapids_tpu.columnar.column import bucket_capacity
-        hb = jnp.where(mask_r & kv_r, h_r, jnp.iinfo(jnp.int64).max)
-        # pad to a power of two for the bitonic network: recv size is
-        # n_dev * cap and the mesh width need not be a power of two
-        pad_n = bucket_capacity(hb.shape[0])
-        if pad_n != hb.shape[0]:
-            hb = jnp.concatenate(
-                [hb, jnp.full(pad_n - hb.shape[0],
-                              jnp.iinfo(jnp.int64).max, hb.dtype)])
-        sorted_h, perm = bitonic_lex_sort([hb])
-        run_len = _run_lengths(sorted_h)
-        lo = _left_search(sorted_h, h_l)
-        n = sorted_h.shape[0]
-        loc = jnp.clip(lo, 0, n - 1)
-        present = (lo < n) & (jnp.take(sorted_h, loc) == h_l)
-        runs = jnp.where(present, jnp.take(run_len, loc), 0)
-        usable = mask_l & kv_l
-        counts = jnp.where(usable, runs, 0).astype(jnp.int64)
-        return counts, lo, sorted_h, perm
-
-    def _count_step(self, lcap: int, rcap: int):
-        key = (lcap, rcap)
-        fn = self._count_cache.get(key)
-        if fn is not None:
-            return fn
-        from spark_rapids_tpu.parallel.mesh import DATA_AXIS
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
         lkeys, rkeys = self.left_keys, self.right_keys
 
         def device_step(l_flat, l_rows, r_flat, r_rows):
@@ -297,31 +317,21 @@ class DistributedHashJoin:
                       for t in l_flat]
             r_flat = [tuple(None if a is None else a[0] for a in t)
                       for t in r_flat]
-            _, mask_l, h_l, kv_l = self._exchange_side(
-                l_flat, l_rows[0], lkeys, lcap)
-            _, mask_r, h_r, kv_r = self._exchange_side(
-                r_flat, r_rows[0], rkeys, rcap)
-            counts, _, _, _ = self._local_probe(
+            _, mask_l, h_l, kv_l = _exchange_side(
+                n_dev, l_flat, l_rows[0], lkeys, lcap)
+            _, mask_r, h_r, kv_r = _exchange_side(
+                n_dev, r_flat, r_rows[0], rkeys, rcap)
+            counts, _, _, _ = _local_probe(
                 h_l, kv_l, mask_l, h_r, kv_r, mask_r)
             return jnp.sum(counts)[None]
 
-        fn = engine_jit(shard_map(
+        return shard_map(
             device_step, mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
-            out_specs=P(DATA_AXIS)),
-            family="exchange", name="mesh_join_count")
-        self._count_cache[key] = fn
-        return fn
+            out_specs=P(DATA_AXIS))
 
-    def _join_step(self, lcap: int, rcap: int, out_cap: int):
-        key = (lcap, rcap, out_cap)
-        fn = self._join_cache.get(key)
-        if fn is not None:
-            return fn
-        from spark_rapids_tpu.parallel.mesh import DATA_AXIS
-        from jax import shard_map
-        from jax.sharding import PartitionSpec as P
+    def _build_join_step(self, lcap: int, rcap: int, out_cap: int):
         from spark_rapids_tpu.utils.pscan import (
             masked_positions, prefix_sum,
         )
@@ -336,11 +346,11 @@ class DistributedHashJoin:
                       for t in l_flat]
             r_flat = [tuple(None if a is None else a[0] for a in t)
                       for t in r_flat]
-            l_cols, mask_l, h_l, kv_l = self._exchange_side(
-                l_flat, l_rows[0], lkeys, lcap)
-            r_cols, mask_r, h_r, kv_r = self._exchange_side(
-                r_flat, r_rows[0], rkeys, rcap)
-            counts, lo, sorted_h, perm = self._local_probe(
+            l_cols, mask_l, h_l, kv_l = _exchange_side(
+                n_dev, l_flat, l_rows[0], lkeys, lcap)
+            r_cols, mask_r, h_r, kv_r = _exchange_side(
+                n_dev, r_flat, r_rows[0], rkeys, rcap)
+            counts, lo, sorted_h, perm = _local_probe(
                 h_l, kv_l, mask_l, h_r, kv_r, mask_r)
 
             inclusive = prefix_sum(counts)
@@ -469,14 +479,11 @@ class DistributedHashJoin:
             ns = jnp.stack([b[0].astype(jnp.int32) for b in blocks])
             return (ns[None], tuple(lead(b[1]) for b in blocks))
 
-        fn = engine_jit(shard_map(
+        return shard_map(
             device_step, mesh=self.mesh,
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
                       P(DATA_AXIS)),
-            out_specs=(P(DATA_AXIS), P(DATA_AXIS))),
-            family="exchange", name="mesh_join")
-        self._join_cache[key] = fn
-        return fn
+            out_specs=(P(DATA_AXIS), P(DATA_AXIS)))
 
     # -- host driver --------------------------------------------------------
 
@@ -487,7 +494,6 @@ class DistributedHashJoin:
         the still-device-resident stacked output blocks — both
         ``all_to_all`` exchanges run with zero ``device_pull``s; only
         ``gather`` crosses the link."""
-        from spark_rapids_tpu.parallel.mesh import shard_table
         sl, cl, lcap = shard_table(left, self.n_dev)
         sr, cr, rcap = shard_table(right, self.n_dev)
         return self.run_stacked(sl, jnp.asarray(cl, jnp.int32), lcap,
@@ -498,7 +504,6 @@ class DistributedHashJoin:
         (host-split here via ``shard_table`` — the sanctioned drained
         fallback split) or an already-stacked ``(planes, counts, cap)``
         triple from the sharded scan ingest."""
-        from spark_rapids_tpu.parallel.mesh import shard_table
 
         def side(x):
             if isinstance(x, tuple):
@@ -515,14 +520,16 @@ class DistributedHashJoin:
         side may arrive host-split (``shard_table``) or device-resident
         from the sharded scan ingest (parallel/shardscan.py), including
         mixed — each side's arrays just feed the same SPMD programs."""
-        from spark_rapids_tpu.columnar.column import bucket_capacity
-        from spark_rapids_tpu.parallel.mesh import phase
         with phase("collective_us"):
-            totals = np.asarray(self._count_step(lcap, rcap)(
-                tuple(sl), jl, tuple(sr), jr))
+            sig = (planes_signature(sl), planes_signature(sr))
+            count = self._program("mesh_join_count",
+                                  self._build_count_step, (lcap, rcap), sig)
+            totals = np.asarray(count(tuple(sl), jl, tuple(sr), jr))
             out_cap = bucket_capacity(max(1, int(totals.max())))
-            ns, blocks = self._join_step(lcap, rcap, out_cap)(
-                tuple(sl), jl, tuple(sr), jr)
+            join = self._program("mesh_join", self._build_join_step,
+                                 (lcap, rcap, out_cap), sig,
+                                 self.join_type)
+            ns, blocks = join(tuple(sl), jl, tuple(sr), jr)
             return np.asarray(ns), blocks  # ns: (n_dev, n_blocks)
 
     def gather(self, ns: np.ndarray, blocks,
@@ -532,7 +539,6 @@ class DistributedHashJoin:
         concurrent pull per chip per block with ``parallel_pull``) and
         concatenate in block order."""
         from spark_rapids_tpu.exec.coalesce import concat_batches
-        from spark_rapids_tpu.parallel.mesh import gather_stacked
         jt = self.join_type
         l_dtypes = [f.dtype for f in self.left_schema]
         r_dtypes = [f.dtype for f in self.right_schema]

@@ -40,7 +40,8 @@ from spark_rapids_tpu.exprs.base import (
 )
 from spark_rapids_tpu.parallel.distagg import _bucket_scatter
 from spark_rapids_tpu.parallel.mesh import (
-    DATA_AXIS, data_mesh, phase, shard_table,
+    DATA_AXIS, data_mesh, mesh_key, mesh_program, phase, planes_signature,
+    shard_table,
 )
 
 
@@ -76,7 +77,9 @@ def _range_pids(keys, bounds, live, n_dev: int) -> jnp.ndarray:
 
 
 class DistributedSort:
-    """Compile + run a global sort sharded over a 1-D data mesh."""
+    """Compile + run a global sort sharded over a 1-D data mesh.  The
+    jitted step lives in the process-wide memo of mesh programs
+    (``mesh.mesh_program``), not in the object."""
 
     def __init__(self, orders: Sequence[Tuple[Expression, bool, bool]],
                  schema: Schema, mesh=None, n_devices: int = None,
@@ -89,7 +92,6 @@ class DistributedSort:
         # pad from this (never from a previous run's observation, which
         # would ratchet the width down across runs)
         self.pad_max = pad_width
-        self._step_cache: dict = {}
 
     def _build_step(self, cap: int, pad: int):
         n_dev = self.n_dev
@@ -162,15 +164,15 @@ class DistributedSort:
             in_specs=(P(DATA_AXIS), P(DATA_AXIS), P()),
             out_specs=(P(DATA_AXIS), P(DATA_AXIS)))
 
-    def _step(self, cap: int, pad: int):
+    def _step(self, cap: int, pad: int, planes_sig: tuple):
         # keyed on (capacity, pad): a cached step compiled for one pad
         # must never serve bounds computed at another
-        fn = self._step_cache.get((cap, pad))
-        if fn is None:
-            fn = engine_jit(self._build_step(cap, pad),
-                            family="exchange", name="mesh_sort")
-            self._step_cache[(cap, pad)] = fn
-        return fn
+        key = ("sort", mesh_key(self.mesh),
+               tuple((e.key(), asc, nf) for e, asc, nf in self.orders),
+               cap, pad, planes_sig)
+        return mesh_program(
+            key, lambda: engine_jit(self._build_step(cap, pad),
+                                    family="exchange", name="mesh_sort"))
 
     # -- host driver --------------------------------------------------------
 
@@ -224,8 +226,8 @@ class DistributedSort:
         per-shard device-resident views."""
         with phase("collective_us"):
             jb = tuple(jnp.asarray(b) for b in bounds)
-            n_local, out_cols = self._step(cap, pad)(tuple(stacked),
-                                                     counts, jb)
+            step = self._step(cap, pad, planes_signature(stacked))
+            n_local, out_cols = step(tuple(stacked), counts, jb)
             return np.asarray(n_local), out_cols
 
     def sample_bounds_sharded(self, views: List[ColumnarBatch],

@@ -21,6 +21,7 @@ from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, bucket_capacity
 from spark_rapids_tpu.columnar.dtypes import Schema
 from spark_rapids_tpu.utils import tracing
+from spark_rapids_tpu.utils.kernel_cache import KernelCache
 
 DATA_AXIS = "data"
 
@@ -29,10 +30,12 @@ DATA_AXIS = "data"
 # read one snapshot): parallel per-chip result pulls issued and the
 # link wall time the fan-out reclaimed (docs/sharded_scan.md), and the
 # microseconds a mesh fragment spent in each of its three phases
-# (``phase``; docs/ici_shuffle.md, "Phases")
+# (``phase``; docs/ici_shuffle.md, "Phases"), and the mesh programs
+# asked for and found compiled (``mesh_program``)
 _GATHER_LOCK = threading.Lock()
 _GATHER = {"gather_pulls": 0, "gather_overlap_ms": 0,
-           "ingest_us": 0, "collective_us": 0, "gather_us": 0}
+           "ingest_us": 0, "collective_us": 0, "gather_us": 0,
+           "program_lookups": 0, "program_hits": 0}
 _PHASE_SPANS = {"ingest_us": tracing.SPAN_ICI_INGEST,
                 "collective_us": tracing.SPAN_ICI_COLLECTIVE,
                 "gather_us": tracing.SPAN_ICI_GATHER}
@@ -72,6 +75,62 @@ def phase(counter: str):
     finally:
         with _GATHER_LOCK:
             _GATHER[counter] += (time.perf_counter_ns() - start) // 1000
+
+
+# The jitted ``shard_map`` programs of distagg.py, distjoin.py and
+# distsort.py, for the whole process: a plan built anew finds the program
+# an earlier plan compiled (docs/ici_shuffle.md, "Where a mesh program
+# lives").  The bound is entries, and an entry pins one loaded executable
+# on every chip of its mesh.  At SF1 on a v5e:2x2 the three kinds are 85,
+# 53 and 76 MB of code a chip (PERF.md section 6, PR 33): 71 MB the mean,
+# 87 MB the largest seen.  24 entries are 1.7 GB a chip at that mean and
+# 2.1 GB if every one were the largest, of 16 GB; Q3 asks for 5 programs
+# and Q18 for 8, so both queries of the mesh configuration stay resident
+# with room for as many again.
+_MESH_PROGRAMS = KernelCache("mesh_programs", 24)
+
+
+def mesh_key(mesh: Mesh) -> tuple:
+    """A mesh as a program's cache key reads it: its chips in order, not
+    its width.  A mesh that lost a chip and re-formed at the same width
+    (exec/meshexec.py: ``_mesh_key_and_builder``) is another mesh, and no
+    program compiled for the old one may run over the new."""
+    return (mesh.axis_names,
+            tuple((d.platform, d.id) for d in mesh.devices.flat))
+
+
+def planes_signature(stacked) -> tuple:
+    """What a trace reads of stacked input planes ``[(data, validity,
+    chars | None), ...]`` besides the shard capacity: each column's dtype,
+    the shape under its rows, and its string width."""
+    return tuple(
+        (data.dtype.name, tuple(data.shape[2:]),
+         None if chars is None else int(chars.shape[2]))
+        for data, _valid, chars in stacked)
+
+
+def mesh_program(key: tuple, build, programs: KernelCache = None):
+    """The jitted program under ``key``, built by ``build()`` where no
+    plan of this process has asked for it yet, and the two counters that
+    say so: ``ici.program_lookups`` and ``ici.program_hits``, once a
+    program asked for.  ``key`` holds everything the traced body reads:
+    ``mesh_key``, the expressions by ``key()``, the join type, the
+    capacities and ``planes_signature`` of the inputs.  ``programs`` is
+    the memo looked in: the process-wide one unless the caller keeps its
+    own (a ``DistributedAggregate`` with a ``prelude``)."""
+    missed = []
+
+    def build_and_note():
+        missed.append(True)
+        return build()
+
+    if programs is None:
+        programs = _MESH_PROGRAMS
+    fn = programs.get_or_build(key, build_and_note)
+    with _GATHER_LOCK:
+        _GATHER["program_lookups"] += 1
+        _GATHER["program_hits"] += not missed
+    return fn
 
 
 def data_mesh(n_devices: Optional[int] = None,

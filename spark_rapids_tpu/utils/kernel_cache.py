@@ -26,9 +26,14 @@ _REGISTRY_LOCK = threading.Lock()
 
 
 class KernelCache:
-    """Named, LRU-bounded, counter-instrumented kernel memo."""
+    """Named, LRU-bounded, counter-instrumented kernel memo.
 
-    def __init__(self, name: str, max_entries: int = 256):
+    ``register=False`` keeps the cache out of the process-wide registry
+    (``all_stats`` / ``find``): for a memo that belongs to one object and
+    dies with it, which the registry would otherwise pin forever."""
+
+    def __init__(self, name: str, max_entries: int = 256,
+                 register: bool = True):
         if max_entries <= 0:
             raise ValueError(f"KernelCache {name!r} needs a positive bound")
         self.name = name
@@ -38,8 +43,9 @@ class KernelCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        with _REGISTRY_LOCK:
-            _REGISTRY.append(self)
+        if register:
+            with _REGISTRY_LOCK:
+                _REGISTRY.append(self)
 
     def get(self, key, default=None):
         with self._lock:

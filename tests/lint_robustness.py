@@ -39,7 +39,12 @@ test suite itself:
    (the ``_FILTER_CACHE`` bug class).  Caches must be
    ``utils/kernel_cache.KernelCache`` instances (LRU-bounded by
    construction, hit/miss/evict counted) or another structure that is
-   bounded by construction.
+   bounded by construction.  Under ``parallel/`` the same holds for an
+   OBJECT's memo (``self._step_cache = {}``): a mesh program kept on
+   its ``Distributed*`` object dies with the plan that built the
+   object, and the next plan traces, lowers and loads it again (13 s a
+   hot Q3 on four chips; docs/ici_shuffle.md, "Where a mesh program
+   lives").  Programs go through ``parallel/mesh.py: mesh_program``.
 
 Run as part of the normal suite (pytest.ini collects ``lint_*.py``).
 """
@@ -324,6 +329,71 @@ def test_module_level_caches_are_bounded(path):
         "unbounded module-level cache dict(s) — compiled-kernel leak "
         "(use utils/kernel_cache.KernelCache, LRU-bounded + counted): "
         f"{offenders}")
+
+
+_PARALLEL_DIR = os.path.join(_PACKAGE_DIR, "parallel")
+# what an attribute that memoises programs is called
+_MEMO_WORDS = ("cache", "memo", "step", "program", "compiled", "jit")
+
+
+def _parallel_sources() -> List[str]:
+    out = [p for p in _package_sources()
+           if p.startswith(_PARALLEL_DIR + os.sep)]
+    assert out, f"memo lint found no sources under {_PARALLEL_DIR}"
+    return out
+
+
+def _object_memo_offenders(tree: ast.AST, rel: str) -> List[str]:
+    """``self.<memo-like name> = {}`` (or ``dict()``, ``OrderedDict()``,
+    ``defaultdict(...)``), annotated or not, anywhere in the file."""
+    offenders = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.AnnAssign):
+            targets, value = [node.target], node.value
+        elif isinstance(node, ast.Assign):
+            targets, value = node.targets, node.value
+        else:
+            continue
+        if value is None or not _is_unbounded_cache_ctor(value):
+            continue
+        for t in targets:
+            if isinstance(t, ast.Attribute) and any(
+                    w in t.attr.lower() for w in _MEMO_WORDS):
+                offenders.append(f"{rel}:{node.lineno} ({t.attr})")
+    return offenders
+
+
+@pytest.mark.parametrize("path", _parallel_sources(),
+                         ids=lambda p: os.path.relpath(p, _REPO))
+def test_no_per_object_program_memos_in_parallel(path):
+    """A ``Distributed*`` object keeps no dict of jitted programs: the
+    object dies with its plan, and a memo that dies with a plan is a
+    retrace, a lowering and an executable reload in every hot query
+    (ROADMAP M5).  ``mesh.mesh_program`` holds them for the process; an
+    object that cannot be keyed by value (a ``prelude``) keeps a
+    ``KernelCache(..., register=False)`` of its own."""
+    offenders = _object_memo_offenders(
+        _parsed(path), os.path.relpath(path, _REPO))
+    assert not offenders, (
+        "per-object program memo(s) under parallel/ — the programs die "
+        "with the plan (use parallel/mesh.py: mesh_program): "
+        f"{offenders}")
+
+
+def test_the_memo_lint_catches_what_it_is_for():
+    """The four dicts this rule was written against, as they stood."""
+    was = ast.parse(
+        "class D:\n"
+        "    def __init__(self):\n"
+        "        self._step_cache: dict = {}\n"
+        "        self._count_cache: dict = {}\n"
+        "        self._join_cache = dict()\n"
+        "        self._programs = collections.OrderedDict()\n"
+        "        self.rows = {}\n"
+        "        self._steps = KernelCache('x', 4, register=False)\n")
+    assert _object_memo_offenders(was, "was.py") == [
+        "was.py:3 (_step_cache)", "was.py:4 (_count_cache)",
+        "was.py:5 (_join_cache)", "was.py:6 (_programs)"]
 
 
 # ---------------------------------------------------------------------------

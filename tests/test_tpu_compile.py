@@ -396,6 +396,8 @@ def test_distributed_aggregate_compiles_for_four_chips(topo):
     stacked = ((plane(jnp.int64), plane(jnp.bool_), None),
                (plane(jnp.float32), plane(jnp.bool_), None))
     counts = jax.ShapeDtypeStruct((n_dev,), jnp.int32, sharding=rows)
-    compiled = dist._step(cap).lower(stacked, counts, ()).compile()
+    from spark_rapids_tpu.parallel.mesh import planes_signature
+    compiled = dist._step(cap, planes_signature(stacked)).lower(
+        stacked, counts, ()).compile()
     assert "all-to-all" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
