@@ -23,7 +23,8 @@ static shapes):
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Tuple
+import threading
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -44,7 +45,39 @@ from spark_rapids_tpu.exprs.base import (
     _batch_signature, _flatten_batch,
 )
 from spark_rapids_tpu.exprs.predicates import string_compare
+from spark_rapids_tpu.utils import tracing
 from spark_rapids_tpu.utils.metrics import METRIC_TOTAL_TIME
+
+# Always-on counters of the one-chip hash join (the ``join`` group of
+# ``engine_stats()``, docs/observability.md), bumped once a join, never
+# a batch or a row: ``joins`` executed, each under the ONE route that
+# produced its rows (``generic``: probe, one count pulled, expand;
+# ``band``: the same over a band-narrowed probe; ``fk``: the one
+# sync-free program over unique build keys; ``fk_dense``: its
+# direct-address form), ``broadcast`` where the build side came from a
+# ``TpuBroadcastExchangeExec`` whatever the route, and the rows a join
+# saw as the host knows them without a device read: an exact count
+# where one was pulled already, else a ``LazyRows`` bound.
+# ``out_slots`` is the capacity of the batches handed on: work
+# downstream is done at capacity, not at rows (ROADMAP D14).
+# ``build_us`` / ``probe_us`` sum the node metrics ``buildTime`` /
+# ``joinTime``.
+JOIN_ROUTES = ("generic", "band", "fk", "fk_dense")
+_JOIN_LOCK = threading.Lock()
+_JOIN = dict.fromkeys(
+    ("joins", *JOIN_ROUTES, "broadcast", "build_rows", "stream_rows",
+     "out_rows", "out_slots", "build_us", "probe_us"), 0)
+
+
+def join_stats() -> Dict[str, int]:
+    with _JOIN_LOCK:
+        return dict(_JOIN)
+
+
+def reset_join_stats() -> None:
+    with _JOIN_LOCK:
+        for k in _JOIN:
+            _JOIN[k] = 0
 
 
 # ---------------------------------------------------------------------------
@@ -928,25 +961,58 @@ class TpuHashJoinExec(TpuExec):
         return self._count_output(self._run(ctx))
 
     def _run(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+        """The join (``_join``), counted once into the ``join`` group
+        when it ends, however it ends."""
+        from spark_rapids_tpu.exec.broadcast import TpuBroadcastExchangeExec
+        seen = {"joins": 1, "route": "generic", "build_rows": 0,
+                "stream_rows": 0, "out_rows": 0, "out_slots": 0,
+                "broadcast": int(isinstance(self.children[1],
+                                            TpuBroadcastExchangeExec))}
+        clocks = {"build_us": self.metrics["buildTime"],
+                  "probe_us": self.metrics["joinTime"]}
+        before = {k: m.value for k, m in clocks.items()}
+        try:
+            for out in self._join(ctx, seen):
+                seen["out_rows"] += out.rows_bound
+                seen["out_slots"] += out.capacity
+                yield out
+        finally:
+            seen[seen.pop("route")] = 1
+            for k, m in clocks.items():
+                seen[k] = (m.value - before[k]) // 1000
+            with _JOIN_LOCK:
+                for k, v in seen.items():
+                    _JOIN[k] += v
+
+    def _join(self, ctx: ExecContext, seen: dict
+              ) -> Iterator[ColumnarBatch]:
+        """``seen`` is ``_run``'s tally: the route taken and the rows of
+        each side, written here as they become known on the host."""
         from spark_rapids_tpu.columnar import encoding as _enc
         schema = self.output_schema
         is_cross = self.join_type == "cross"
         # BUILD: coalesce right side to one batch
         # (RequireSingleBatch goal, GpuShuffledHashJoinExec.scala:83)
         b_batches = list(self.children[1].execute_columnar(ctx))
-        if b_batches:
-            b_batch = concat_batches(b_batches)
-        else:
-            b_batch = _empty_batch(self.children[1].output_schema)
-        # equi-join keys compare as CODES where both sides reference
-        # encoded columns (docs/compressed.md): the view keeps the
-        # build side's codes, re-keys each stream batch into the build
-        # code space, and rewrites the key expressions to INT32 refs —
-        # a stream batch arriving dense drops to the dense-keys variant
-        jv = _enc.JoinCodeView(
-            b_batch, self.left_keys, self.right_keys,
-            len(self.children[0].output_schema.fields),
-            condition=self.condition)
+        # the join's own work on its build side (the child's is the
+        # child's): the concat here, the uniqueness probe below
+        with tracing.trace_range(tracing.SPAN_JOIN_BUILD,
+                                 self.metrics["buildTime"]):
+            if b_batches:
+                b_batch = concat_batches(b_batches)
+            else:
+                b_batch = _empty_batch(self.children[1].output_schema)
+            # equi-join keys compare as CODES where both sides reference
+            # encoded columns (docs/compressed.md): the view keeps the
+            # build side's codes, re-keys each stream batch into the
+            # build code space, and rewrites the key expressions to
+            # INT32 refs — a stream batch arriving dense drops to the
+            # dense-keys variant
+            jv = _enc.JoinCodeView(
+                b_batch, self.left_keys, self.right_keys,
+                len(self.children[0].output_schema.fields),
+                condition=self.condition)
+        seen["build_rows"] = b_batch.rows_bound
         b_batch = jv.build_batch
         b_flat, b_sig = _enc.flat_and_sig(b_batch)
         keys_key = (tuple(e.key() for e in self.left_keys),
@@ -960,7 +1026,8 @@ class TpuHashJoinExec(TpuExec):
             # remote runtime and cost a link round trip per execution).
             # One pull answers uniqueness AND the single-int-key range
             # (the dense direct-address fast path's precondition).
-            with self.metrics.timed("buildTime"):
+            with tracing.trace_range(tracing.SPAN_JOIN_BUILD,
+                                     self.metrics["buildTime"]):
                 build_fn = _compile_build(keys_key, jv.rkeys_code,
                                           b_sig, b_batch.capacity)
                 _sh, _pb, _rl, max_run, klo, khi = build_fn(
@@ -989,6 +1056,8 @@ class TpuHashJoinExec(TpuExec):
         dense_cap = 0
         if fk and khi >= klo and khi - klo + 1 <= (1 << 24):
             dense_cap = bucket_capacity(max(8, khi - klo + 1))
+        if fk:
+            seen["route"] = "fk_dense" if dense_cap else "fk"
         from spark_rapids_tpu.utils.retry import (
             split_batch_half, with_retry,
         )
@@ -998,7 +1067,8 @@ class TpuHashJoinExec(TpuExec):
                 # after a catalog-wide spill, then on row-split halves
                 # (reference RmmRapidsRetryIterator withRetry around the
                 # probe, GpuHashJoin doJoin)
-                with self.metrics.timed("joinTime"):
+                with tracing.trace_range(tracing.SPAN_JOIN_PROBE,
+                                         self.metrics["joinTime"]):
                     sv = jv.for_stream(sb)
                     vb_flat, vb_sig = _enc.flat_and_sig(sv.b_batch)
                     s_flat, s_sig = _enc.flat_and_sig(sv.s_batch)
@@ -1018,6 +1088,7 @@ class TpuHashJoinExec(TpuExec):
                             s_flat, sb.rows_traced, vb_flat,
                             b_batch.rows_traced, jnp.int64(klo))
                     else:
+                        seen["route"] = "fk"
                         fk_fn = _compile_fk_join(
                             kk, sv.lkeys, sv.rkeys,
                             s_sig, vb_sig, sb.capacity,
@@ -1037,6 +1108,7 @@ class TpuHashJoinExec(TpuExec):
                         schema, extra_wrap=wrap)
 
             for s_batch in self.children[0].execute_columnar(ctx):
+                seen["stream_rows"] += s_batch.rows_bound
                 yield from with_retry(process_fk, s_batch, ctx,
                                       split=split_batch_half)
             return
@@ -1051,6 +1123,7 @@ class TpuHashJoinExec(TpuExec):
                 list(self.children[1].output_schema.fields))
             if band is not None:
                 self.metrics["bandJoinProbes"].add(1)
+                seen["route"] = "band"
 
         m_build_total = jnp.zeros(b_batch.capacity, jnp.int32)
 
@@ -1061,7 +1134,8 @@ class TpuHashJoinExec(TpuExec):
             # matched build rows
             outs = []
             mb = None
-            with self.metrics.timed("joinTime"):
+            with tracing.trace_range(tracing.SPAN_JOIN_PROBE,
+                                     self.metrics["joinTime"]):
                 sv = jv.for_stream(sb)
                 s_flat, s_sig = _enc.flat_and_sig(sv.s_batch)
                 vb_flat, vb_sig = _enc.flat_and_sig(sv.b_batch)
@@ -1136,6 +1210,7 @@ class TpuHashJoinExec(TpuExec):
             return outs, mb
 
         for s_batch in self.children[0].execute_columnar(ctx):
+            seen["stream_rows"] += s_batch.rows_bound
             for outs, mb in with_retry(process_stream, s_batch, ctx,
                                        split=split_batch_half):
                 if mb is not None:

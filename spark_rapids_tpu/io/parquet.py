@@ -308,9 +308,17 @@ def pruning_outcome(pred: Optional[Expression], rg_shard):
 # Always-on counters of the device scan cache (the ``scan`` group of
 # ``engine_stats()``, docs/observability.md): a served request leaves no
 # plan to read ``scanCacheHits`` from, so the cache counts for itself.
-# Bumped once per scan, never per batch.
+# Bumped once per scan, never per batch.  The last three are what a
+# miss cost on the host, from the scan node's own clocks
+# (io/hostio.py pipelined_scan): microseconds decoding, microseconds
+# dispatching uploads, host bytes uploaded; a hit moves none of them.
 _SCAN_LOCK = threading.Lock()
-_SCAN = {"cache_lookups": 0, "cache_hits": 0, "decoded_bytes": 0}
+_SCAN = {"cache_lookups": 0, "cache_hits": 0, "decoded_bytes": 0,
+         "decode_us": 0, "upload_us": 0, "upload_bytes": 0}
+# counter -> (the node metric it sums, what divides that metric)
+_MISS_COSTS = {"decode_us": ("decodeTime", 1000),
+               "upload_us": ("uploadTime", 1000),
+               "upload_bytes": ("uploadBytes", 1)}
 
 
 def _scan_add(counter: str, amount: int = 1) -> None:
@@ -336,8 +344,8 @@ def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
     zero-arg callable producing the fresh batch iterator; the named
     scan metrics are snapshotted with the entry and replayed on a hit so
     observability (row-group pruning counters etc.) survives caching.
-    Counts every lookup, every hit, and the device bytes a miss decoded
-    and uploaded (``scan_stats``)."""
+    Counts every lookup, every hit, the device bytes a miss decoded
+    and uploaded, and what the miss cost on the host (``scan_stats``)."""
     from spark_rapids_tpu.memory.spill import SpillableBatch
     cache = ctx.runtime.scan_cache
     if key is None or not ctx.conf.scan_device_cache_enabled:
@@ -358,16 +366,27 @@ def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
     from spark_rapids_tpu.memory.spill import PRIORITY_RECREATABLE
     handles = []
     schema = None
-    before = {n: metrics[n].value for n in metric_names} \
-        if metrics is not None else {}
-    for b in gen():
-        schema = b.schema
-        # re-creatable from the file: first in line to spill
-        h = SpillableBatch(b, ctx.runtime.catalog,
-                           priority=PRIORITY_RECREATABLE)
-        h.suppress_leak_warning = True
-        handles.append(h)
-        yield b
+    watched = (*metric_names, *(m for m, _ in _MISS_COSTS.values())) \
+        if metrics is not None else ()
+    before = {n: metrics[n].value for n in watched}
+    batches = gen()
+    try:
+        for b in batches:
+            schema = b.schema
+            # re-creatable from the file: first in line to spill
+            h = SpillableBatch(b, ctx.runtime.catalog,
+                               priority=PRIORITY_RECREATABLE)
+            h.suppress_leak_warning = True
+            handles.append(h)
+            yield b
+    finally:
+        # a scan cut short (LIMIT) paid for what it decoded: stop its
+        # decode thread first, so the clocks have stopped
+        if hasattr(batches, "close"):
+            batches.close()
+        if metrics is not None:
+            for counter, (m, per) in _MISS_COSTS.items():
+                _scan_add(counter, (metrics[m].value - before[m]) // per)
     snap = {n: metrics[n].value - before[n] for n in metric_names} \
         if metrics is not None else {}
     _scan_add("decoded_bytes", sum(h.size for h in handles))
@@ -458,13 +477,10 @@ class TpuParquetScanExec(TpuExec):
                 for rb in coalesce_host_batches(it, rows):
                     yield fi, rb
 
-        # upload span: the analog of the reference's buffer-copy NVTX
-        # span (GpuParquetScan.scala:317); covers only the dispatch, not
-        # consumer time.  Staging admission happens in pipelined_scan.
+        # the upload's span, its clock and staging admission are
+        # pipelined_scan's
         upload = make_uploader(ctx, self._file_schema, self.part_schema,
-                               fvals, span="ParquetScan.upload",
-                               span_metric=self.metrics["uploadTime"],
-                               metrics=self.metrics)
+                               fvals, metrics=self.metrics)
 
         def gen():
             return pipelined_scan(ctx, self.metrics, host_gen(), upload,

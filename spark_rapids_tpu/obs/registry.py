@@ -175,7 +175,7 @@ def snapshot() -> dict:
     from spark_rapids_tpu import health, lifecycle
     from spark_rapids_tpu.columnar import encoding, transfer
     from spark_rapids_tpu.compile import service as compile_service
-    from spark_rapids_tpu.exec import aqe, meshexec, stage
+    from spark_rapids_tpu.exec import aqe, joins, meshexec, stage
     from spark_rapids_tpu.io import parquet as scan_io
     from spark_rapids_tpu.io import prefetch
     from spark_rapids_tpu.fleet import stats as fleet_stats
@@ -187,9 +187,14 @@ def snapshot() -> dict:
         "prefetch": prefetch.global_stats(),
         "d2h": transfer.d2h_stats(),
         # the device scan cache, counted where it is looked up
-        # (io/parquet.py cached_device_scan): lookups, hits, and the
-        # device bytes the misses decoded and uploaded
+        # (io/parquet.py cached_device_scan): lookups, hits, the device
+        # bytes the misses decoded and uploaded, and their host cost
         "scan": scan_io.scan_stats(),
+        # the one-chip hash join, counted once a join where it ends
+        # (exec/joins.py TpuHashJoinExec._run): joins by the route that
+        # produced their rows, broadcast build sides, rows and handed-on
+        # capacity as the host knows them, build and probe microseconds
+        "join": joins.join_stats(),
         # compressed-domain execution trajectory (docs/compressed.md):
         # `encodedColumns` (columns ingested as codes), `lateDecodes`
         # (separate decode dispatches — the escape hatch), and

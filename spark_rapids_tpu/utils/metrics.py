@@ -85,6 +85,11 @@ METRIC_JOIN_TIME = "joinTime"
 METRIC_BROADCAST_TIME = "broadcastTime"
 METRIC_SAMPLE_TIME = "sampleTime"
 METRIC_UPLOAD_TIME = "uploadTime"
+# the other half of a scan that missed the device scan cache
+# (io/hostio.py pipelined_scan): nanoseconds decoding files to host
+# batches, wherever that ran, and the host bytes handed to the upload
+METRIC_DECODE_TIME = "decodeTime"
+METRIC_UPLOAD_BYTES = "uploadBytes"
 METRIC_SEM_WAIT_MS = "semWaitMs"
 METRIC_DATA_SIZE = "dataSize"
 METRIC_PALLAS_AGG_BATCHES = "pallasAggBatches"

@@ -76,6 +76,19 @@ SPAN_ICI_INGEST = "ici.ingest"
 SPAN_ICI_COLLECTIVE = "ici.collective"
 SPAN_ICI_GATHER = "ici.gather"
 
+# the two sections of a one-chip hash join (exec/joins.py): the build
+# side made one batch and probed for unique keys, and a stream batch
+# joined against it (probe, expand, gather, or the one FK program).
+# Their microseconds are the ``join.build_us`` / ``probe_us`` counters
+SPAN_JOIN_BUILD = "join.build"
+SPAN_JOIN_PROBE = "join.probe"
+# a scan that missed the device scan cache (io/hostio.py): a host batch
+# decoded from its file (on the prefetch thread where there is one), and
+# a host batch uploaded.  Their microseconds are the ``scan.decode_us``
+# / ``upload_us`` counters
+SPAN_SCAN_DECODE = "scan.decode"
+SPAN_SCAN_UPLOAD = "scan.upload"
+
 # Always-on phase counters (the ``phases`` group of ``engine_stats()``,
 # docs/observability.md): microseconds a query spent planning and
 # executing, microseconds a thread sat in a blocking device read, and

@@ -69,7 +69,13 @@ import pytest  # noqa: E402
 # deserializations, so the cost is small and the long-process failure
 # mode disappears.
 
-_KERNEL_PRESSURE_ENTRIES = 700
+# 100, not the 700 it was: tests/test_window.py leaves 177 entries and a
+# worker that went on to tests/test_ooc.py with them still loaded
+# aborted inside the persistent cache's deserialization, on the parent
+# of PR 35 too; which worker runs which file after which is xdist's to
+# choose, and a new test file was enough to deal that pair to one
+# worker in four whole runs of five (PERF.md section 6, PR 35)
+_KERNEL_PRESSURE_ENTRIES = 100
 _last_test_module = [None]
 
 
