@@ -105,6 +105,20 @@ def discover(roots: Sequence[str], files: Sequence[str]):
     return part_schema, file_values
 
 
+def narrow(part_schema: Optional[Schema], file_values, names):
+    """``discover``'s result cut to the partition columns among ``names``:
+    a scan the planner pruned appends only the columns its plan reads."""
+    if part_schema is None:
+        return None, []
+    keep = [i for i, f in enumerate(part_schema) if f.name in names]
+    if len(keep) == len(part_schema.fields):
+        return part_schema, file_values
+    if not keep:
+        return None, []
+    return (Schema([part_schema.fields[i] for i in keep]),
+            [tuple(v[i] for i in keep) for v in file_values])
+
+
 def prune_files(part_schema: Schema, file_values, files, pred):
     """Files whose partition values can satisfy the pushed-down simple
     predicates (the PartitioningAwareFileIndex pruning analog)."""

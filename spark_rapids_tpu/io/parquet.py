@@ -312,9 +312,13 @@ def pruning_outcome(pred: Optional[Expression], rg_shard):
 # miss cost on the host, from the scan node's own clocks
 # (io/hostio.py pipelined_scan): microseconds decoding, microseconds
 # dispatching uploads, host bytes uploaded; a hit moves none of them.
+# ``columns_read`` / ``columns_total``: the file columns a parquet scan
+# asked its reader for, and its table's before the planner pruned them
+# (planner.py prune_scan_columns); bumped once a parquet scan, hit or miss.
 _SCAN_LOCK = threading.Lock()
 _SCAN = {"cache_lookups": 0, "cache_hits": 0, "decoded_bytes": 0,
-         "decode_us": 0, "upload_us": 0, "upload_bytes": 0}
+         "decode_us": 0, "upload_us": 0, "upload_bytes": 0,
+         "columns_read": 0, "columns_total": 0}
 # counter -> (the node metric it sums, what divides that metric)
 _MISS_COSTS = {"decode_us": ("decodeTime", 1000),
                "upload_us": ("uploadTime", 1000),
@@ -393,7 +397,37 @@ def cached_device_scan(ctx: ExecContext, key, gen, metrics=None,
     cache.put(key, handles, schema, snap)
 
 
-class TpuParquetScanExec(TpuExec):
+class _ParquetScan:
+    """What the TPU and CPU parquet scans share: the files, the hive
+    partition columns the schema reads, and the file columns the reader
+    is asked for (``_file_schema``) out of the table's (``columns_total``,
+    before the planner's column pruning: ``full_schema``)."""
+
+    def _init_scan(self, paths, schema: Schema,
+                   full_schema: Optional[Schema]) -> None:
+        from spark_rapids_tpu.io import hivepart
+        self.roots = list(paths) if isinstance(paths, (list, tuple)) \
+            else [paths]
+        self.paths = expand_paths(paths)
+        part_schema, part_values = hivepart.discover(self.roots, self.paths)
+        part_names = set(part_schema.names) if part_schema else set()
+        self.columns_total = sum(f.name not in part_names
+                                 for f in (full_schema or schema))
+        self.part_schema, self.part_values = hivepart.narrow(
+            part_schema, part_values, schema.names)
+        self._schema = schema
+        self._file_schema = Schema(
+            [f for f in schema if f.name not in part_names])
+
+    def _count_columns(self) -> None:
+        _scan_add("columns_read", len(self._file_schema.fields))
+        _scan_add("columns_total", self.columns_total)
+
+    def _columns_text(self) -> str:
+        return f"{len(self._file_schema.fields)}/{self.columns_total} columns"
+
+
+class TpuParquetScanExec(_ParquetScan, TpuExec):
     """Parquet -> device batches (reference GpuParquetScan.scala:65).
     Hive-partitioned layouts (col=value/ dirs) contribute partition-value
     columns per file and prune files on partition predicates
@@ -401,19 +435,10 @@ class TpuParquetScanExec(TpuExec):
 
     def __init__(self, paths, schema: Schema,
                  pred: Optional[Expression] = None,
-                 batch_rows: Optional[int] = None):
+                 batch_rows: Optional[int] = None,
+                 full_schema: Optional[Schema] = None):
         super().__init__()
-        from spark_rapids_tpu.io import hivepart
-        self.roots = list(paths) if isinstance(paths, (list, tuple)) \
-            else [paths]
-        self.paths = expand_paths(paths)
-        self.part_schema, self.part_values = hivepart.discover(
-            self.roots, self.paths)
-        self._schema = schema
-        part_names = set(self.part_schema.names) if self.part_schema \
-            else set()
-        self._file_schema = Schema(
-            [f for f in schema if f.name not in part_names])
+        self._init_scan(paths, schema, full_schema)
         self.pred = pred
         self.batch_rows = batch_rows
         self.children = []
@@ -429,7 +454,8 @@ class TpuParquetScanExec(TpuExec):
         extra = f", pushdown={self.pred.name}" if self.pred else ""
         if self.part_schema:
             extra += f", partitioned by {self.part_schema.names}"
-        return f"TpuParquetScan [{len(self.paths)} files{extra}]"
+        return (f"TpuParquetScan [{len(self.paths)} files, "
+                f"{self._columns_text()}{extra}]")
 
     def execute_columnar(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         from spark_rapids_tpu.io import hivepart
@@ -440,6 +466,7 @@ class TpuParquetScanExec(TpuExec):
         if self.part_schema:
             self.metrics["numFilesTotal"].add(len(self.paths))
             self.metrics["numFilesRead"].add(len(files))
+        self._count_columns()
 
         dump_prefix = ctx.conf.get_raw(
             "spark.rapids.sql.parquet.debug.dumpPrefix", "") or ""
@@ -494,22 +521,13 @@ class TpuParquetScanExec(TpuExec):
             metric_names=("numRowGroupsTotal", "numRowGroupsRead")))
 
 
-class CpuParquetScanExec(CpuExec):
+class CpuParquetScanExec(_ParquetScan, CpuExec):
     def __init__(self, paths, schema: Schema,
                  pred: Optional[Expression] = None,
-                 batch_rows: Optional[int] = None):
+                 batch_rows: Optional[int] = None,
+                 full_schema: Optional[Schema] = None):
         super().__init__()
-        from spark_rapids_tpu.io import hivepart
-        roots = list(paths) if isinstance(paths, (list, tuple)) \
-            else [paths]
-        self.paths = expand_paths(paths)
-        self.part_schema, self.part_values = hivepart.discover(
-            roots, self.paths)
-        self._schema = schema
-        part_names = set(self.part_schema.names) if self.part_schema \
-            else set()
-        self._file_schema = Schema(
-            [f for f in schema if f.name not in part_names])
+        self._init_scan(paths, schema, full_schema)
         self.pred = pred
         self.batch_rows = batch_rows
         self.children = []
@@ -519,7 +537,8 @@ class CpuParquetScanExec(CpuExec):
         return self._schema
 
     def describe(self) -> str:
-        return f"CpuParquetScan [{len(self.paths)} files]"
+        return f"CpuParquetScan [{len(self.paths)} files, " \
+            f"{self._columns_text()}]"
 
     def execute_host(self, ctx: ExecContext) -> Iterator[pa.RecordBatch]:
         # _count_output: placement-calibration hook, a passthrough
@@ -531,6 +550,7 @@ class CpuParquetScanExec(CpuExec):
         rows = self.batch_rows or ctx.conf.reader_batch_size_rows
         files, fvals = hivepart.prune_files(
             self.part_schema, self.part_values, self.paths, self.pred)
+        self._count_columns()
         for fi, path in enumerate(files):
             reader = ParquetPartitionReader(
                 path, self._file_schema, columns=self._file_schema.names,

@@ -60,13 +60,18 @@ class LocalRelation(LogicalPlan):
 
 class ParquetRelation(LogicalPlan):
     def __init__(self, paths, schema: Schema,
-                 pushed: Optional[Expression] = None):
+                 pushed: Optional[Expression] = None,
+                 full_schema: Optional[Schema] = None):
         self.paths = paths
         self.schema = schema
         # Predicate pushed down from an enclosing Filter by the planner's
         # pushdown pass; used for footer min/max row-group pruning only
         # (conservative), so the Filter stays in the plan.
         self.pushed = pushed
+        # the table's schema before the planner's column pruning narrowed
+        # ``schema`` to the columns the plan reads (planner.py
+        # prune_scan_columns)
+        self.full_schema = full_schema or schema
         self.children = []
 
     def output_schema(self) -> Schema:

@@ -345,7 +345,8 @@ class PlanMeta:
         if isinstance(n, lp.ParquetRelation):
             from spark_rapids_tpu.io.parquet import TpuParquetScanExec
             return TpuParquetScanExec(
-                n.paths, n.schema, pred=self._bind_pushed(n))
+                n.paths, n.schema, pred=self._bind_pushed(n),
+                full_schema=n.full_schema)
         if isinstance(n, lp.CsvRelation):
             from spark_rapids_tpu.io.csv import TpuCsvScanExec
             return TpuCsvScanExec(n.paths, n.schema, n.header, n.sep)
@@ -519,7 +520,8 @@ class PlanMeta:
         if isinstance(n, lp.ParquetRelation):
             from spark_rapids_tpu.io.parquet import CpuParquetScanExec
             return CpuParquetScanExec(
-                n.paths, n.schema, pred=self._bind_pushed(n))
+                n.paths, n.schema, pred=self._bind_pushed(n),
+                full_schema=n.full_schema)
         if isinstance(n, lp.CsvRelation):
             from spark_rapids_tpu.io.csv import CpuCsvScanExec
             return CpuCsvScanExec(n.paths, n.schema, n.header, n.sep)
@@ -804,19 +806,13 @@ def push_scan_filters(node: lp.LogicalPlan) -> lp.LogicalPlan:
         child = new_children[0]
         for rel_cls in (lp.ParquetRelation, lp.OrcRelation):
             if isinstance(child, rel_cls):
-                return lp.Filter(node.pred, rel_cls(
-                    child.paths, child.schema,
-                    pushed=_and_pushed(child.pushed, node.pred)))
+                return lp.Filter(node.pred, _with_pushed(child, node.pred))
             # stacked filters: the bottom-up pass already pushed the
             # inner predicate, so AND this one into the same scan
             if isinstance(child, lp.Filter) and \
                     isinstance(child.children[0], rel_cls):
-                rel = child.children[0]
-                new_rel = rel_cls(
-                    rel.paths, rel.schema,
-                    pushed=_and_pushed(rel.pushed, node.pred))
-                return lp.Filter(node.pred,
-                                 lp.Filter(child.pred, new_rel))
+                return lp.Filter(node.pred, lp.Filter(
+                    child.pred, _with_pushed(child.children[0], node.pred)))
     if any(a is not b for a, b in zip(new_children, node.children)):
         node = copy.copy(node)
         node.children = new_children
@@ -824,12 +820,215 @@ def push_scan_filters(node: lp.LogicalPlan) -> lp.LogicalPlan:
     return node
 
 
-def _and_pushed(existing: Optional[Expression],
-                pred: Expression) -> Expression:
-    if existing is None:
-        return pred
+def _with_pushed(rel, pred: Expression):
+    """A copy of the scan relation ``rel`` with ``pred`` ANDed into its
+    pushed predicate; every other attribute (the pruned schema too) as
+    it was."""
     from spark_rapids_tpu.exprs import predicates as _pr
-    return _pr.And(existing, pred)
+    rel = copy.copy(rel)
+    rel.pushed = pred if rel.pushed is None else _pr.And(rel.pushed, pred)
+    return rel
+
+
+def prune_scan_columns(root: lp.LogicalPlan) -> lp.LogicalPlan:
+    """Column pruning (Catalyst's ColumnPruning, which Spark runs ahead of
+    every file scan; the reference's GpuParquetScan reads its clipped read
+    schema): every ``ParquetRelation`` narrowed to the columns the plan
+    above it reads, in file order, so its scan decodes, uploads and
+    carries no other.  Walks top-down with the output ordinals each node
+    must hand on: a Project keeps only the expressions its parent reads
+    and reads what they reference; Filter, Sort and a Join add what their
+    predicate, order and keys read (a Join sends each ordinal to its
+    side); Limit passes its parent's set on; an Aggregate reads its
+    groupings and aggregate inputs; every other node reads all of its
+    children's output.  A relation keeps its pushed predicate's columns
+    and, where nothing is read (``count(*)``), its narrowest file column.
+    Ordinal references above a narrowed node are rebound.  Nodes are
+    rebuilt, never mutated (logical plans are shared between
+    DataFrames); CSV, ORC and local relations are left whole."""
+    return _prune(root, None)[0]
+
+
+def _refs(exprs, schema: Schema) -> set:
+    """The ordinals of ``schema`` that ``exprs`` read: a name's first
+    field, which is the one it binds to, and a bound reference's own."""
+    from spark_rapids_tpu.exprs.base import UnresolvedAttribute
+    first = {}
+    for i, n in enumerate(schema.names):
+        first.setdefault(n, i)
+    out = set()
+
+    def walk(e):
+        if isinstance(e, UnresolvedAttribute):
+            if e.col_name in first:
+                out.add(first[e.col_name])
+        elif isinstance(e, BoundReference):
+            out.add(e.ordinal)
+        for c in e.children:
+            walk(c)
+    for e in exprs:
+        walk(e)
+    return out
+
+
+def _rebind(e: Expression, remap: Optional[dict]) -> Expression:
+    """``e`` with every bound reference moved by ``remap`` (old ordinal
+    -> new); ``remap`` None leaves it as it is."""
+    if remap is None:
+        return e
+    if isinstance(e, BoundReference):
+        return BoundReference(remap[e.ordinal], e.dtype, e.nullable,
+                              e.col_name)
+    kids = [_rebind(c, remap) for c in e.children]
+    if all(a is b for a, b in zip(kids, e.children)):
+        return e
+    return e.with_children(kids)
+
+
+def _narrowest(fields) -> int:
+    """Index of the narrowest of ``fields`` (fixed widths first, then by
+    bytes; the first of equals)."""
+    return min(range(len(fields)), key=lambda i: (
+        not fields[i].dtype.fixed_width, fields[i].dtype.byte_width))
+
+
+def _prune(node: lp.LogicalPlan, need: Optional[set]):
+    """``node`` rebuilt to hand on at least its output ordinals ``need``
+    (None: all of them), and where it hands on fewer the map from its old
+    output ordinals to the new (None: its output is unchanged)."""
+    if isinstance(node, lp.ParquetRelation):
+        return _prune_relation(node, need)
+    if isinstance(node, lp.Project):
+        return _prune_project(node, need)
+    if isinstance(node, (lp.Filter, lp.Sort, lp.Limit)):
+        return _prune_pass_through(node, need)
+    if isinstance(node, lp.Aggregate):
+        child = node.children[0]
+        new_child, cmap = _prune(child, _refs(
+            node.groupings + node.aggregates, child.output_schema()))
+        if new_child is child:
+            return node, None
+        return lp.Aggregate([_rebind(e, cmap) for e in node.groupings],
+                            [_rebind(e, cmap) for e in node.aggregates],
+                            new_child), None
+    if isinstance(node, lp.Join):
+        return _prune_join(node, need)
+    # Union, Expand, Window, Generate, Repartition and every leaf but a
+    # parquet relation: all of each child's output
+    new_children = [_prune(c, None)[0] for c in node.children]
+    if all(a is b for a, b in zip(new_children, node.children)):
+        return node, None
+    node = copy.copy(node)
+    node.children = new_children
+    node.__dict__.pop("_schema_cache", None)
+    return node, None
+
+
+def _prune_project(node: lp.Project, need: Optional[set]):
+    keep = list(range(len(node.exprs))) if need is None else sorted(need)
+    if not keep:
+        keep = [_narrowest(node.output_schema().fields)]
+    exprs = [node.exprs[i] for i in keep]
+    child = node.children[0]
+    new_child, cmap = _prune(child, _refs(exprs, child.output_schema()))
+    whole = len(keep) == len(node.exprs)
+    if new_child is child and whole:
+        return node, None
+    return (lp.Project([_rebind(e, cmap) for e in exprs], new_child),
+            None if whole else {o: i for i, o in enumerate(keep)})
+
+
+def _prune_pass_through(node: lp.LogicalPlan, need: Optional[set]):
+    """Filter, Sort and Limit hand on their child's output: the child
+    hands on what the parent reads and what the predicate or order does."""
+    child = node.children[0]
+    if isinstance(node, lp.Filter):
+        exprs = [node.pred]
+    elif isinstance(node, lp.Sort):
+        exprs = [e for e, _, _ in node.orders]
+    else:
+        exprs = []
+    new_child, cmap = _prune(child, None if need is None else
+                             need | _refs(exprs, child.output_schema()))
+    if new_child is child:
+        return node, None
+    if isinstance(node, lp.Filter):
+        return lp.Filter(_rebind(node.pred, cmap), new_child), cmap
+    if isinstance(node, lp.Sort):
+        return lp.Sort([(_rebind(e, cmap), asc, nf)
+                        for e, asc, nf in node.orders], new_child), cmap
+    return lp.Limit(node.n, new_child), cmap
+
+
+def _prune_join(node: lp.Join, need: Optional[set]):
+    """Each wanted ordinal of the join's output goes to its side, with
+    the side's keys; a semi or anti join hands on its left side alone, so
+    its right side keeps only its keys whatever the parent reads."""
+    left, right = node.children
+    ls, rs, out = (left.output_schema(), right.output_schema(),
+                   node.output_schema())
+    nl = len(ls.fields)
+    left_only = node.join_type in ("semi", "anti")
+    want = set(range(len(out.fields))) if need is None else set(need)
+    if node.condition is not None:
+        want |= _refs([node.condition], out)
+    lneed = None if need is None else \
+        {i for i in want if i < nl} | _refs(node.left_keys, ls)
+    rneed = None if need is None and not left_only else \
+        {i - nl for i in want if i >= nl} | _refs(node.right_keys, rs)
+    new_left, lmap = _prune(left, lneed)
+    new_right, rmap = _prune(right, rneed)
+    if new_left is left and new_right is right:
+        return node, None
+    remap = lmap
+    if not left_only and (lmap or rmap):
+        lmap = lmap or {i: i for i in range(nl)}
+        rmap = rmap or {i: i for i in range(len(rs.fields))}
+        new_nl = len(new_left.output_schema().fields)
+        remap = dict(lmap)
+        remap.update((nl + o, new_nl + n) for o, n in rmap.items())
+    return lp.Join(new_left, new_right,
+                   [_rebind(e, lmap) for e in node.left_keys],
+                   [_rebind(e, rmap) for e in node.right_keys],
+                   node.join_type,
+                   condition=None if node.condition is None else
+                   _rebind(node.condition, remap)), remap
+
+
+def _prune_relation(rel: lp.ParquetRelation, need: Optional[set]):
+    if need is None:
+        return rel, None
+    fields = rel.schema.fields
+    keep = set(need)
+    if rel.pushed is not None:
+        keep |= _refs([rel.pushed], rel.schema)
+    file_idx = _file_column_indices(rel)
+    if not keep & set(file_idx):
+        # a scan reads rows through a file column: the narrowest
+        keep.add(file_idx[_narrowest([fields[i] for i in file_idx])])
+    if len(keep) == len(fields):
+        return rel, None
+    keep = sorted(keep)
+    narrowed = lp.ParquetRelation(
+        rel.paths, Schema([fields[i] for i in keep]), pushed=rel.pushed,
+        full_schema=rel.full_schema)
+    return narrowed, {o: i for i, o in enumerate(keep)}
+
+
+def _file_column_indices(rel: lp.ParquetRelation) -> List[int]:
+    """Ordinals of ``rel``'s schema that its files hold: all but the
+    hive partition columns, which exist only under a directory root."""
+    import os
+    roots = rel.paths if isinstance(rel.paths, (list, tuple)) \
+        else [rel.paths]
+    part = set()
+    if any(os.path.isdir(r) for r in roots):
+        from spark_rapids_tpu.io import hivepart
+        from spark_rapids_tpu.io.parquet import expand_paths
+        part_schema, _ = hivepart.discover(roots, expand_paths(rel.paths))
+        part = set(part_schema.names) if part_schema else set()
+    return [i for i, f in enumerate(rel.schema.fields)
+            if f.name not in part] or list(range(len(rel.schema.fields)))
 
 
 def insert_coalesce(plan: PhysicalPlan, conf: TpuConf) -> PhysicalPlan:
@@ -859,6 +1058,7 @@ def plan_query(root: lp.LogicalPlan, conf: TpuConf) -> PlanResult:
     if conf.get_bool(
             "spark.rapids.sql.format.parquet.filterPushdown.enabled", True):
         root = push_scan_filters(root)
+    root = prune_scan_columns(root)
     meta = PlanMeta(root, conf)
     # analysis-time placement check — runs on BOTH engine paths (neither
     # threads a partition id outside Project)

@@ -108,8 +108,9 @@ def _restrict_to_split(plan, idx: int, n: int):
         # stripe itself for scan types that don't retain them)
         if getattr(s, "part_schema", None):
             from spark_rapids_tpu.io import hivepart
-            s.part_schema, s.part_values = hivepart.discover(
-                getattr(s, "roots", stripe), stripe)
+            s.part_schema, s.part_values = hivepart.narrow(
+                *hivepart.discover(getattr(s, "roots", stripe), stripe),
+                s.output_schema.names)
     return plan
 
 

@@ -266,3 +266,33 @@ def test_csv_and_orc_key_as_before(tmp_path, scan_counters, fmt):
         assert counters["cache_hits"] == (2 if fmt == "csv" else 1)
     finally:
         s.stop()
+
+
+@pytest.mark.parametrize("second,hits", [
+    # the same two columns asked for in the other order: one key
+    (("l_discount", "l_quantity"), 1),
+    # another pair of the same table: another key
+    (("l_quantity", "l_extendedprice"), 0),
+])
+def test_the_key_is_the_columns_read_not_their_order(tmp_path, scan_counters,
+                                                     second, hits):
+    """The planner narrows a scan to the columns its query reads, in file
+    order (ISSUE 36), and the key names them: a narrowed scan is its own
+    entry, never served another column set's planes."""
+    path = str(tmp_path / "lineitem.parquet")
+    pq.write_table(_lineitem(), path, row_group_size=GROUP_ROWS)
+    s = tpu_session({})
+    try:
+        df = s.read.parquet(path)
+        first = df.select("l_quantity", "l_discount").to_arrow()
+        again = df.select(*second).to_arrow()
+        counters = scan_counters()
+        assert (counters["cache_lookups"], counters["cache_hits"]) == \
+            (2, hits)
+        assert (counters["columns_read"], counters["columns_total"]) == \
+            (4, 8)
+        assert again.column_names == list(second)
+        for name in set(second) & set(first.column_names):
+            assert again.column(name).equals(first.column(name))
+    finally:
+        s.stop()

@@ -6,12 +6,15 @@ default engine), at 60 k lineitem rows, two passes of the cell's stream.
 Every node of every plan is a ``Tpu*`` node and nothing falls back; the
 answers equal the benchmark's plain reference (``reference/tpch_power.py``)
 cell for cell on three seeds, and the bfloat16 control fails ``q5.revenue``.
-A hot pass of ``[q3, q5, q18]`` asks the device scan cache for ten scan
-shapes thirteen times and is answered four times from its eight entries; a
-hot pass of the cell's own stream, ``[q3, q5]`` (Q18 was cut for time), goes
-round nine shapes and is answered never: the arithmetic the cell's ``why``
-states, pinned here so that a change to the cache's key or policy shows in a
-test before it shows on the chip.  The
+A hot pass of ``[q3, q5, q18]`` asks the device scan cache for twelve scan
+shapes thirteen times and is answered once from its eight entries (every
+scan reads only the columns its query uses, so no scan of q5 is one of
+q18's); a hot pass of the cell's own stream, ``[q3, q5]`` (Q18 was cut for
+time), goes round nine shapes and is answered never: the arithmetic the
+cell's ``why`` states, pinned here so that a change to the cache's key or
+policy shows in a test before it shows on the chip.  A pass of ``[q3, q5]``
+asks its readers for 26 of its tables' 80 columns (``scan.columns_read`` /
+``columns_total``), hit or miss.  The
 ``join`` group of ``engine_stats()`` counts each join once under the route
 that produced its rows; ``scan.decode_us``, ``upload_us`` and
 ``upload_bytes`` move on a miss and stand still on a hit; the four spans
@@ -42,7 +45,12 @@ PASSES = ("cold", "hot")
 # joins in each query's text, and scans (Q18 names lineitem twice)
 JOINS = {"q3": 2, "q5": 5, "q18": 3}
 SCANS = {"q3": 3, "q5": 6, "q18": 4}
-HOT_HITS = {"q3": 0, "q5": 1, "q18": 3}
+HOT_HITS = {"q3": 0, "q5": 0, "q18": 1}
+# file columns each query's scans read, of their tables' (planner.py
+# prune_scan_columns); Q18 reads lineitem twice
+COLUMNS = {"q3": (2 + 4 + 4, 8 + 9 + 16),
+           "q5": (2 + 3 + 4 + 2 + 3 + 2, 8 + 9 + 16 + 7 + 4 + 3),
+           "q18": (2 + 4 + 2 + 2, 8 + 9 + 16 + 16)}
 FALLBACKS = ("ici.fallbacks", "ooc.fallbacks", "compile.aotFailures",
              "fusion.warm_errors")
 MISS_COSTS = ("decode_us", "upload_us", "upload_bytes")
@@ -206,15 +214,42 @@ def test_the_bfloat16_control_fails_q5_revenue(runs, config, seed):
 
 
 @pytest.mark.parametrize("seed", SEEDS)
-def test_a_hot_pass_reads_13_lookups_and_4_hits(runs, seed):
-    """Ten scan shapes a pass against ``runtime._ScanCache(max_entries=8)``
-    under LRU: q3 none of 3, q5 lineitem of 6, q18 all but orders of 4."""
+def test_a_hot_pass_reads_13_lookups_and_1_hit(runs, seed):
+    """Twelve scan shapes a pass against ``runtime._ScanCache(max_entries=8)``
+    under LRU: q3 none of 3, q5 none of 6, q18 its second read of lineitem,
+    which asks for the first's two columns."""
     scans = {q: runs[seed, "hot", q]["scan"] for q in QUERIES}
     assert {q: s["cache_lookups"] for q, s in scans.items()} == SCANS
     assert {q: s["cache_hits"] for q, s in scans.items()} == HOT_HITS
     assert sum(s["cache_lookups"] for s in scans.values()) == 13
-    assert sum(s["cache_hits"] for s in scans.values()) == 4
+    assert sum(s["cache_hits"] for s in scans.values()) == 1
     assert all(s["decoded_bytes"] > 0 for s in scans.values())
+
+
+@pytest.mark.parametrize("query,when", [
+    (q, w) for w in PASSES for q in QUERIES] + [
+    (q, w) for w in ("cut_cold", "cut") for q in CELL_QUERIES])
+@pytest.mark.parametrize("seed", SEEDS)
+def test_scans_read_only_the_columns_their_query_uses(runs, seed, query,
+                                                      when):
+    """The column counters move once a scan, on a miss (``cut``: none
+    hits) as on a hit (Q18's second lineitem read in ``hot``)."""
+    run = runs[seed, when, query]
+    scan = run["scan"] if "scan" in run else run
+    assert (scan["columns_read"], scan["columns_total"]) == COLUMNS[query]
+
+
+def test_a_pass_of_the_cells_stream_reads_26_of_80_columns(runs):
+    scans = [runs[SEEDS[0], "cut", q] for q in CELL_QUERIES]
+    assert sum(s["columns_read"] for s in scans) == 26
+    assert sum(s["columns_total"] for s in scans) == 80
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_hit_counts_its_columns(runs, seed):
+    hit = runs[seed, "hit"]
+    assert (hit["cache_hits"], hit["columns_read"],
+            hit["columns_total"]) == (1, 3, 3)
 
 
 @pytest.mark.parametrize("seed", SEEDS)
