@@ -644,7 +644,10 @@ class DataFrame:
         from spark_rapids_tpu.utils import tracing
         # the query's fault domain (lifecycle.py): deadline + cancel
         # token + resource registry; teardown runs on scope exit
-        # whether the drain below succeeds, times out, or fails
+        # whether the drain below succeeds, times out, or fails.  Every
+        # step of the action is a section (docs/observability.md,
+        # "Sections"): the scope's entry and exit, plan, the drain, the
+        # bookkeeping between them, and the final table build
         with lifecycle.query_scope(self.session.conf) as qc:
             # query_trace OUTSIDE the ExecContext construction: both set
             # the process-global span switch from the conf, but only
@@ -654,12 +657,16 @@ class DataFrame:
             # planner's spans (plan.fusion, plan.aqe) are a traced
             # query's too
             with tracing.query_trace(self.session.conf):
-                with tracing.phase(tracing.SPAN_QUERY_PLAN, "plan_us"):
+                with tracing.section(tracing.SPAN_QUERY_PLAN,
+                                     total="plan_us"):
                     result = plan_query(self.plan, self.session.conf)
-                ctx = ExecContext(self.session.conf)
+                with tracing.section(tracing.SPAN_QUERY_OPEN,
+                                     own="scope_us"):
+                    ctx = ExecContext(self.session.conf)
                 batches = []
-                with tracing.phase(tracing.SPAN_QUERY_EXECUTE,
-                                   "execute_us"):
+                with tracing.section(tracing.SPAN_QUERY_EXECUTE,
+                                     total="execute_us",
+                                     own="execute_self_us"):
                     for rb in result.physical.execute_host(ctx):
                         # root-drain checkpoint: covers plans (or
                         # subtrees) on the CPU fallback engine, whose
@@ -667,14 +674,23 @@ class DataFrame:
                         # their own
                         lifecycle.check_cancel()
                         batches.append(rb)
+        with tracing.section(tracing.SPAN_QUERY_CLOSE, own="scope_us"):
+            self._stamp(result, qc)
+        with tracing.section(tracing.SPAN_EGRESS_TABLE, own="egress_us"):
+            arrow_schema = result.physical.output_schema.to_arrow()
+            if not batches:
+                return pa.Table.from_batches([], schema=arrow_schema)
+            return pa.Table.from_batches(batches).cast(arrow_schema)
+
+    def _stamp(self, result, qc) -> None:
+        """Pair the retained plan with ITS query's identity once the
+        drain succeeded — the profile header (docs/observability.md)
+        reads these, never a process-global "last finished" note a later
+        write or a concurrent session could overwrite."""
         if qc.sem_wait_ms:
             # per-query admission-wait telemetry, visible through
             # session.last_query_metrics() beside the operator metrics
             result.physical.metrics["semWaitMs"].add(qc.sem_wait_ms)
-        # pair the retained plan with ITS query's identity — the
-        # profile header (docs/observability.md) reads these, never a
-        # process-global "last finished" note a later write or a
-        # concurrent session could overwrite
         result.query_id = qc.query_id
         result.wall_ms = qc.wall_ms
         result.programs = qc.programs
@@ -691,10 +707,6 @@ class DataFrame:
             _cost.observe_plan(result.physical)
             _placement.note_query(result.placement, qc.wall_ms,
                                   query_id=qc.query_id)
-        arrow_schema = result.physical.output_schema.to_arrow()
-        if not batches:
-            return pa.Table.from_batches([], schema=arrow_schema)
-        return pa.Table.from_batches(batches).cast(arrow_schema)
 
     # -- ML handoff (reference InternalColumnarRddConverter.scala:470-579:
     # export the internal columnar stream without a row conversion) --------
@@ -706,7 +718,7 @@ class DataFrame:
         from spark_rapids_tpu.exec.basic import DeviceToHostExec
         from spark_rapids_tpu.exec.base import TpuExec
         from spark_rapids_tpu.utils import tracing
-        with tracing.phase(tracing.SPAN_QUERY_PLAN, "plan_us"):
+        with tracing.section(tracing.SPAN_QUERY_PLAN, total="plan_us"):
             result = plan_query(self.plan, self.session.conf)
         root = result.physical
         if isinstance(root, DeviceToHostExec):
@@ -720,17 +732,21 @@ class DataFrame:
             # query_trace scopes the span switch here exactly as in
             # _execute: the handoff path must not leak it either
             with tracing.query_trace(self.session.conf):
-                ctx = ExecContext(self.session.conf)
-                with tracing.phase(tracing.SPAN_QUERY_EXECUTE,
-                                   "execute_us"):
+                with tracing.section(tracing.SPAN_QUERY_OPEN,
+                                     own="scope_us"):
+                    ctx = ExecContext(self.session.conf)
+                with tracing.section(tracing.SPAN_QUERY_EXECUTE,
+                                     total="execute_us",
+                                     own="execute_self_us"):
                     batches = list(root.execute_columnar(ctx))
         # retain + stamp only after the drain succeeded (the _execute
         # invariant): a failed handoff must not replace a prior query's
         # valid profile with an unexecuted, unstamped tree
-        result.query_id = qc.query_id
-        result.wall_ms = qc.wall_ms
-        result.programs = qc.programs
-        self.session._last_plan_result = result
+        with tracing.section(tracing.SPAN_QUERY_CLOSE, own="scope_us"):
+            result.query_id = qc.query_id
+            result.wall_ms = qc.wall_ms
+            result.programs = qc.programs
+            self.session._last_plan_result = result
         return batches
 
     def to_jax(self):

@@ -344,7 +344,15 @@ def device_batch_to_host(batch: ColumnarBatch,
     All planes of all columns come back in ONE pull through
     ``columnar/transfer.py:device_pull`` (counted, fault-injectable) —
     the fixed per-pull latency would otherwise multiply by 2-3 pulls
-    per column."""
+    per column.  An ``egress.finish`` section (``egress_us``), the pull
+    inside it its own."""
+    from spark_rapids_tpu.utils import tracing
+    with tracing.section(tracing.SPAN_EGRESS_FINISH, own="egress_us"):
+        return _batch_to_host(batch, schema, metrics)
+
+
+def _batch_to_host(batch: ColumnarBatch, schema: Optional[Schema],
+                   metrics) -> pa.RecordBatch:
     from spark_rapids_tpu.columnar.transfer import device_pull
     schema = schema or batch.schema
     pulls = []

@@ -29,7 +29,6 @@ maxlen) stats to shrink the big pull.
 
 from __future__ import annotations
 
-import contextlib
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -99,7 +98,6 @@ def device_pull(tree, metrics=None):
     is any pytree of device arrays; returns the matching host tree.
     One call = one link round trip — the unit the single-pull egress
     paths minimize."""
-    import time
     from spark_rapids_tpu import lifecycle
     from spark_rapids_tpu.obs import registry as obs
     faults.maybe_fail(FAULT_SITE_D2H,
@@ -107,12 +105,11 @@ def device_pull(tree, metrics=None):
     # the blocking link wait is the one spot in the egress path
     # cooperative cancellation cannot reach: a wedged pull is bounded
     # by the watchdog and surfaces as a typed QueryHangError
-    t0 = time.perf_counter_ns()
-    with tracing.trace_range(tracing.SPAN_D2H_PULL):
+    with tracing.section(tracing.SPAN_D2H_PULL,
+                         total="pull_wait_us") as pulled:
         host = lifecycle.supervise(lambda: jax.device_get(tree),
                                    lifecycle.FAULT_SITE_PIPELINE_HANG)
-    pull_us = (time.perf_counter_ns() - t0) // 1000
-    tracing.phase_add("pull_wait_us", pull_us)
+    pull_us = pulled.ns // 1000
     nbytes = sum(getattr(x, "nbytes", 8)
                  for x in jax.tree_util.tree_leaves(host))
     _bump_d2h("pulls", 1)
@@ -128,22 +125,15 @@ def device_pull(tree, metrics=None):
     return host
 
 
-@contextlib.contextmanager
-def _blocked(what: str):
-    """A synchronous wait on the device that is not an egress pull: it
-    carries a span (``d2h.sync:<what>``) and lands in the ``phases``
-    counters (``blocking_reads``, ``pull_wait_us``), so an idle gap under
-    it is named for what it is.  Not a counted link pull: ``d2hPulls`` /
+def _blocked(what: str) -> "tracing.section":
+    """A synchronous wait on the device that is not an egress pull: a
+    section (``d2h.sync:<what>``) whose time lands in ``pull_wait_us``,
+    so an idle gap under it is named for what it is, and one more of
+    the ``blocking_reads``.  Not a counted link pull: ``d2hPulls`` /
     ``d2hBytes`` keep meaning what egress moved."""
-    import time
-    t0 = time.perf_counter_ns()
-    try:
-        with tracing.trace_range(f"{tracing.SPAN_D2H_SYNC}:{what}"):
-            yield
-    finally:
-        tracing.phase_add("pull_wait_us",
-                          (time.perf_counter_ns() - t0) // 1000)
-        tracing.phase_add("blocking_reads", 1)
+    tracing.phase_add("blocking_reads", 1)
+    return tracing.section(f"{tracing.SPAN_D2H_SYNC}:{what}",
+                           total="pull_wait_us")
 
 
 def blocking_read(tree, what: str):
@@ -167,7 +157,8 @@ def place_on_device(host_array, device):
     here so the ICI exchange code carries no raw ``jax.device_put``
     (tests/lint_robustness.py confines host-staged uploads to this
     module)."""
-    return jax.device_put(host_array, device)
+    with tracing.section(tracing.SPAN_HOST_DISPATCH, own="dispatch_us"):
+        return jax.device_put(host_array, device)
 
 
 def parallel_device_pull(trees, metrics=None):
@@ -282,7 +273,8 @@ def pipelined_h2d(items, upload, runtime, metrics=None, enabled=True):
 
     ``h2dOverlapMs`` accumulates the consumer time spent inside the
     yield while an upload was dispatched but not yet synchronized — the
-    wall-clock the pipeline reclaimed from the old serial loop.
+    wall-clock the pipeline reclaimed from the old serial loop.  No span
+    covers that yield: the consumer's work is its own.
     """
     import time
     from spark_rapids_tpu.obs import registry as obs
@@ -291,10 +283,10 @@ def pipelined_h2d(items, upload, runtime, metrics=None, enabled=True):
         # upload dispatch latency + size distribution: jax.device_put
         # returns at dispatch, so this is the host-side cost of getting
         # an upload IN FLIGHT (the link itself overlaps downstream)
-        t0 = time.perf_counter_ns()
-        b = upload(item)
-        obs.record(obs.HIST_H2D_UPLOAD_US,
-                   (time.perf_counter_ns() - t0) // 1000)
+        with tracing.section(tracing.SPAN_HOST_DISPATCH,
+                             own="dispatch_us") as sent:
+            b = upload(item)
+        obs.record(obs.HIST_H2D_UPLOAD_US, sent.ns // 1000)
         size = getattr(b, "size_bytes", None)
         if callable(size):
             obs.record(obs.HIST_H2D_UPLOAD_BYTES, size())
@@ -313,8 +305,7 @@ def pipelined_h2d(items, upload, runtime, metrics=None, enabled=True):
                 b = _timed_upload(item)
             if pending is not None:
                 t0 = time.perf_counter_ns()
-                with tracing.trace_range(tracing.SPAN_H2D_OVERLAP):
-                    yield pending
+                yield pending
                 overlap_ns += time.perf_counter_ns() - t0
             pending = b
         if pending is not None:
@@ -380,13 +371,18 @@ def pipelined_d2h(items, dispatch, finish, ctx=None, metrics=None,
     dispatch, finish, yield, repeat — no lookahead, no admission,
     byte-for-byte the old path.  ``d2hOverlapMs`` accumulates consumer
     time spent inside the yield while a dispatched item's copy was in
-    flight — the wall-clock the pipeline reclaimed."""
+    flight — the wall-clock the pipeline reclaimed; no span covers that
+    yield.  Each ``finish`` is an ``egress.finish`` section."""
     if enabled is None:
         enabled = ctx is not None and ctx.conf.io_egress_enabled
     if not enabled:
         try:
             for item in items:
-                yield finish(dispatch(item))
+                staged = dispatch(item)
+                with tracing.section(tracing.SPAN_EGRESS_FINISH,
+                                     own="egress_us"):
+                    out = finish(staged)
+                yield out
         finally:
             # same guaranteed upstream close as the pipelined path: a
             # consumer failure must unwind the device pipeline on BOTH
@@ -400,7 +396,10 @@ def pipelined_d2h(items, dispatch, finish, ctx=None, metrics=None,
         limiter = ctx.runtime.catalog.egress_staging
 
     def _finish(staged):
-        with tracing.trace_range(tracing.SPAN_D2H_WAIT):
+        # the limiter's wait and the pull are inside: the pull is its
+        # own section (``pull_wait_us``), the wait and the host
+        # assembly this one's (``egress_us``)
+        with tracing.section(tracing.SPAN_EGRESS_FINISH, own="egress_us"):
             if limiter is not None and nbytes is not None:
                 with limiter.limit(nbytes(staged)):
                     return finish(staged)
@@ -415,8 +414,7 @@ def pipelined_d2h(items, dispatch, finish, ctx=None, metrics=None,
                 out = _finish(pending)
                 pending = staged
                 t0 = time.perf_counter_ns()
-                with tracing.trace_range(tracing.SPAN_D2H_OVERLAP):
-                    yield out
+                yield out
                 overlap_ns += time.perf_counter_ns() - t0
             else:
                 pending = staged
@@ -819,9 +817,14 @@ class _PackPending:
 def pack_finish(pending: "_PackPending", metrics=None) -> pa.RecordBatch:
     """Blocking half of the pack: pull the staged planes (one link
     round trip — cheap when ``start_host_copies`` raced ahead) and
-    unpack to a host RecordBatch."""
+    unpack to a host RecordBatch: an ``egress.finish`` section."""
     if pending.ready is not None:
         return pending.ready
+    with tracing.section(tracing.SPAN_EGRESS_FINISH, own="egress_us"):
+        return _pack_finish(pending, metrics)
+
+
+def _pack_finish(pending: "_PackPending", metrics) -> pa.RecordBatch:
     if pending.total_dev is None:
         pulled_planes = device_pull(pending.planes, metrics=metrics)
         n = pending.n
@@ -1059,7 +1062,13 @@ def pack_partitions_finish(pending: "_PartsPending", metrics=None
     per-partition counts in ONE ``device_get``, then slice
     per-partition record batches (zero-copy arrow slices) from the
     counts — None for empty partitions, matching ``partition_batch``'s
-    contract."""
+    contract.  An ``egress.finish`` section."""
+    with tracing.section(tracing.SPAN_EGRESS_FINISH, own="egress_us"):
+        return _pack_partitions_finish(pending, metrics)
+
+
+def _pack_partitions_finish(pending: "_PartsPending", metrics
+                            ) -> List[Optional[pa.RecordBatch]]:
     pk = pending.pack
     pulled_planes, n, counts_h = device_pull(
         (pk.planes, pk.total_dev, pending.counts), metrics=metrics)

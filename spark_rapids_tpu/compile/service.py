@@ -250,9 +250,12 @@ def _first_waitable(out):
 
 def _traced_launch(row: _Row, target, args, kwargs):
     """A launch under the trace switch: count it, charge it to the plan
-    node whose ``next()`` is running, and hand its first output to the
-    watcher.  Never blocks on the device."""
-    out = target(*args, **kwargs)
+    node whose ``next()`` is running and to the span the launching code
+    has open (read before the launch's own ``host.dispatch``), and hand
+    its first output to the watcher.  Never blocks on the device."""
+    span = tracing.current_span() or NO_SPAN
+    with tracing.section(tracing.SPAN_HOST_DISPATCH, own="dispatch_us"):
+        out = target(*args, **kwargs)
     t_dispatch = time.perf_counter_ns()
     row.bump()
     node = tracing.current_node()
@@ -265,8 +268,7 @@ def _traced_launch(row: _Row, target, args, kwargs):
         with _LEDGER_LOCK:
             row.untimed += 1
     else:
-        _submit((row, node_time, tracing.current_span() or NO_SPAN,
-                 t_dispatch, leaf))
+        _submit((row, node_time, span, t_dispatch, leaf))
     return out
 
 
@@ -287,7 +289,8 @@ class Program:
     def _launch(self, target, args, kwargs):
         if tracing.is_enabled():
             return _traced_launch(self._row, target, args, kwargs)
-        out = target(*args, **kwargs)
+        with tracing.section(tracing.SPAN_HOST_DISPATCH, own="dispatch_us"):
+            out = target(*args, **kwargs)
         self._row.bump()
         return out
 

@@ -62,7 +62,7 @@ class TpuProjectExec(TpuExec):
                     cols = run_project(self.exprs, batch,
                                        partition_id=pid,
                                        metrics=self.metrics)
-                    yield ColumnarBatch(cols, batch.rows_raw, self._schema)
+                yield ColumnarBatch(cols, batch.rows_raw, self._schema)
         return self._count_output(gen())
 
 

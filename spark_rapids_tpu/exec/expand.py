@@ -53,12 +53,11 @@ class TpuExpandExec(TpuExec):
         def gen():
             for pid, batch in enumerate(
                     self.children[0].execute_columnar(ctx)):
-                with self.metrics.timed(METRIC_TOTAL_TIME):
-                    for proj in self.projections:
+                for proj in self.projections:
+                    with self.metrics.timed(METRIC_TOTAL_TIME):
                         cols = evaluate_projection(proj, batch,
                                                    partition_id=pid)
-                        yield ColumnarBatch(cols, batch.rows_raw,
-                                            self._schema)
+                    yield ColumnarBatch(cols, batch.rows_raw, self._schema)
         return self._count_output(gen())
 
 

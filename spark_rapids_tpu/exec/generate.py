@@ -102,7 +102,7 @@ class TpuGenerateExec(TpuExec):
                             INT32, eidx.astype(jnp.int32),
                             live & (eidx >= 0), n_out))
                     cols.append(elem_col.gather(eidx, n_out))
-                    yield ColumnarBatch(cols, n_out, self._schema)
+                yield ColumnarBatch(cols, n_out, self._schema)
         return self._count_output(gen())
 
 

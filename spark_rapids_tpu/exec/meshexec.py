@@ -464,8 +464,8 @@ def _single_child_collective(node: TpuExec, ctx: ExecContext):
         batch = _drain_single_batch(node.children[0], ctx)
         if batch is None:
             return
-        with node.metrics.timed(METRIC_TOTAL_TIME):
-            yield from _host_fallback(node, ctx, [batch])
+        yield from node.metrics.timed_iter(
+            METRIC_TOTAL_TIME, _host_fallback(node, ctx, [batch]))
         return
     if inp is shardscan.EMPTY:
         return
@@ -480,17 +480,16 @@ def _single_child_collective(node: TpuExec, ctx: ExecContext):
             # out-of-core path instead of consulting the over-budget
             # gate — the operator stays on device, partition by
             # partition, under the same stage budget
-            with node.metrics.timed(METRIC_TOTAL_TIME):
-                yield from ooc.run_single(node, ctx, handles)
+            yield from node.metrics.timed_iter(
+                METRIC_TOTAL_TIME, ooc.run_single(node, ctx, handles))
             return
         inp = _concat_from_handles(handles, ctx)
         if inp is None:
             return
-    with node.metrics.timed(METRIC_TOTAL_TIME):
-        yield from _guarded_collective(
-            node, ctx, [inp],
-            lambda: node._run_mesh(ctx, inp),
-            lambda: _host_fallback(node, ctx, [_drained_input(inp)]))
+    yield from node.metrics.timed_iter(METRIC_TOTAL_TIME, _guarded_collective(
+        node, ctx, [inp],
+        lambda: node._run_mesh(ctx, inp),
+        lambda: _host_fallback(node, ctx, [_drained_input(inp)])))
 
 
 def _attempt_sharded(node: TpuExec, ctx: ExecContext, idx: int):
@@ -809,8 +808,8 @@ class TpuMeshHashJoinExec(TpuExec):
                     else:
                         inputs.append(
                             _drain_single_batch(self.children[i], ctx))
-                with self.metrics.timed(METRIC_TOTAL_TIME):
-                    yield from _host_fallback(self, ctx, inputs)
+                yield from self.metrics.timed_iter(
+                    METRIC_TOTAL_TIME, _host_fallback(self, ctx, inputs))
                 return
             if sharded[0] is not None or sharded[1] is not None:
                 # at least one sharded side: the other side (if any)
@@ -843,8 +842,8 @@ class TpuMeshHashJoinExec(TpuExec):
                     # over-budget collected inputs take the grace-
                     # partitioned join (docs/out_of_core.md) instead of
                     # the giant concat + over-budget gate
-                    with self.metrics.timed(METRIC_TOTAL_TIME):
-                        yield from ooc.run_join(self, ctx, lh, rh)
+                    yield from self.metrics.timed_iter(
+                        METRIC_TOTAL_TIME, ooc.run_join(self, ctx, lh, rh))
                     return
                 try:
                     # materialize_all closes lh itself (even on error);
@@ -855,13 +854,13 @@ class TpuMeshHashJoinExec(TpuExec):
                     close_all(rh)
                     raise
                 rb = _concat_from_handles(rh, ctx)
-            with self.metrics.timed(METRIC_TOTAL_TIME):
-                yield from _guarded_collective(
+            yield from self.metrics.timed_iter(
+                METRIC_TOTAL_TIME, _guarded_collective(
                     self, ctx, [lb, rb],
                     lambda: self._run_mesh(ctx, lb, rb),
                     lambda: _host_fallback(
                         self, ctx, [_drained_input(lb),
-                                    _drained_input(rb)]))
+                                    _drained_input(rb)])))
         return self._count_output(gen())
 
 

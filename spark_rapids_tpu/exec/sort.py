@@ -115,16 +115,17 @@ class TpuSortExec(TpuExec):
                     return
                 with self.metrics.timed(METRIC_TOTAL_TIME):
                     batch = concat_batches(materialize_all(handles, ctx))
-                    # spill-retry only (withRetryNoSplit): a global sort
-                    # needs its whole input in one kernel
-                    yield from with_retry(
-                        lambda b: sort_batch(self.orders, b), batch, ctx)
+                # spill-retry only (withRetryNoSplit): a global sort
+                # needs its whole input in one kernel
+                yield from self.metrics.timed_iter(
+                    METRIC_TOTAL_TIME, with_retry(
+                        lambda b: sort_batch(self.orders, b), batch, ctx))
             else:
                 for b in self.children[0].execute_columnar(ctx):
-                    with self.metrics.timed(METRIC_TOTAL_TIME):
-                        yield from with_retry(
+                    yield from self.metrics.timed_iter(
+                        METRIC_TOTAL_TIME, with_retry(
                             lambda bb: sort_batch(self.orders, bb), b,
-                            ctx)
+                            ctx))
         return self._count_output(gen())
 
 

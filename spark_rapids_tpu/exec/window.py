@@ -573,23 +573,24 @@ class TpuWindowExec(TpuExec):
                 self.children[0].execute_columnar(ctx), ctx)
             if not handles:
                 return
+            from spark_rapids_tpu.utils.retry import with_retry
             with self.metrics.timed(METRIC_TOTAL_TIME):
-                from spark_rapids_tpu.utils.retry import with_retry
                 batch = concat_batches(materialize_all(handles, ctx))
 
-                def run_window(b):
-                    # spill-retry only (withRetryNoSplit): partitions
-                    # must stay whole, and they cross any row split
-                    fn = _compile_window(self.window_cols,
-                                         _batch_signature(b),
-                                         b.capacity)
-                    outs = fn(_flatten_batch(b), b.rows_traced)
-                    cols = list(b.columns)
-                    for (data, valid), (name, w) in zip(
-                            outs, self.window_cols):
-                        cols.append(DeviceColumn(w.dtype, data, valid,
-                                                 b.rows_raw))
-                    return ColumnarBatch(cols, b.rows_raw, self._schema)
+            def run_window(b):
+                # spill-retry only (withRetryNoSplit): partitions
+                # must stay whole, and they cross any row split
+                fn = _compile_window(self.window_cols,
+                                     _batch_signature(b),
+                                     b.capacity)
+                outs = fn(_flatten_batch(b), b.rows_traced)
+                cols = list(b.columns)
+                for (data, valid), (name, w) in zip(
+                        outs, self.window_cols):
+                    cols.append(DeviceColumn(w.dtype, data, valid,
+                                             b.rows_raw))
+                return ColumnarBatch(cols, b.rows_raw, self._schema)
 
-                yield from with_retry(run_window, batch, ctx)
+            yield from self.metrics.timed_iter(
+                METRIC_TOTAL_TIME, with_retry(run_window, batch, ctx))
         return self._count_output(gen())

@@ -249,7 +249,7 @@ class PrefetchIterator:
         # these bytes to make the next item this get() is waiting for
         self._release_prev()
         t0 = time.perf_counter_ns()
-        with tracing.trace_range(self._span):
+        with tracing.section(self._span, own="io_wait_us"):
             # bounded get polling the query's cancel token: a cancelled
             # or past-deadline query raises typed out of the wait
             # instead of parking on a queue a torn-down producer will

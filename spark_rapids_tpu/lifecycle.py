@@ -58,6 +58,7 @@ import time
 from typing import Callable, Dict, Optional
 
 from spark_rapids_tpu import faults
+from spark_rapids_tpu.utils import tracing
 from spark_rapids_tpu.errors import (
     EngineError, QueryCancelledError, QueryHangError, QueryTimeoutError,
 )
@@ -497,6 +498,36 @@ def query_scope(conf=None, timeout_ms: Optional[int] = None):
     if existing is not None:
         yield existing
         return
+    # the scope's own work, entry and exit, is the action's bookkeeping
+    # (``scope_us``), the trace's part of it ``trace_us``; neither
+    # section is held across the yield
+    with tracing.section(tracing.SPAN_QUERY_OPEN, own="scope_us"):
+        qc = _open_scope(conf, timeout_ms)
+        prev = _set_current(qc)
+    try:
+        yield qc
+    except BaseException as e:
+        qc.error = e
+        raise
+    finally:
+        with tracing.section(tracing.SPAN_QUERY_CLOSE, own="scope_us"):
+            _set_current(prev)
+            if qc.programs_mark is not None:
+                # a traced query: wait (off the device's path, on this
+                # thread only) until the watcher has credited every
+                # program it launched, so its profile and the `programs`
+                # counters are complete when the scope closes
+                from spark_rapids_tpu.compile import service as _service
+                with tracing.section(tracing.SPAN_TRACE_DRAIN,
+                                     own="trace_us"):
+                    _service.drain_watcher()
+                    qc.programs = _service.ledger_since(qc.programs_mark)
+            qc.finish()
+
+
+def _open_scope(conf, timeout_ms: Optional[int]) -> "QueryContext":
+    """The outermost scope's entry: a QueryContext from ``conf`` and the
+    process-global switches its keys configure."""
     if conf is not None:
         qc = QueryContext.from_conf(conf)
         # conf-driven fault injection reaches EVERY site from here, not
@@ -550,7 +581,8 @@ def query_scope(conf=None, timeout_ms: Optional[int] = None):
         from spark_rapids_tpu import compile as _compile
         _compile.configure_from_conf(conf)
         if conf.trace_enabled:
-            qc.programs_mark = _compile.service.ledger_mark()
+            with tracing.section(tracing.SPAN_TRACE_DRAIN, own="trace_us"):
+                qc.programs_mark = _compile.service.ledger_mark()
     else:
         qc = QueryContext(timeout_ms=timeout_ms or 0)
     from spark_rapids_tpu.obs import journal as _journal
@@ -558,23 +590,7 @@ def query_scope(conf=None, timeout_ms: Optional[int] = None):
         _journal.emit(_journal.EVENT_QUERY_START, query=qc.query_id,
                       timeout_ms=int(qc.token.timeout_s * 1000),
                       hang_timeout_ms=int(qc.hang_timeout_s * 1000))
-    prev = _set_current(qc)
-    try:
-        yield qc
-    except BaseException as e:
-        qc.error = e
-        raise
-    finally:
-        _set_current(prev)
-        if qc.programs_mark is not None:
-            # a traced query: wait (off the device's path, on this
-            # thread only) until the watcher has credited every program
-            # it launched, so its profile and the `programs` counters
-            # are complete when the scope closes
-            from spark_rapids_tpu.compile import service as _service
-            _service.drain_watcher()
-            qc.programs = _service.ledger_since(qc.programs_mark)
-        qc.finish()
+    return qc
 
 
 def register_resource(close: Callable[[], None], kind: str = "resource",

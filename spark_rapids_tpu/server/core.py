@@ -352,14 +352,13 @@ class SessionServer:
         try:
             # the request is a traced scope of its own: its bookkeeping
             # before and after the query (tenant conf, bind, result-cache
-            # key) is under the switch too, not only DataFrame._execute
+            # key) is under the switch too, not only DataFrame._execute,
+            # and is the worker's section (``scope_us``), the query's own
+            # sections nested in it
             with tracing.switch_scope(self.session.conf.trace_enabled), \
-                    tracing.trace_range(
-                        f"{tracing.SPAN_SERVER_EXECUTE}:{ticket.tenant}"):
-                # the wait is over by the time a worker can say so: a
-                # marker at the pick-up; its length is in admit_wait_us
-                with tracing.trace_range(tracing.SPAN_SERVER_ADMIT_WAIT):
-                    pass
+                    tracing.section(
+                        f"{tracing.SPAN_SERVER_EXECUTE}:{ticket.tenant}",
+                        own="scope_us"):
                 while True:
                     attempts += 1
                     view = _TenantSession(

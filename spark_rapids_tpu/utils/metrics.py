@@ -259,6 +259,25 @@ class MetricSet:
     def timed(self, name: str):
         return _Timer(self[name], self)
 
+    def timed_iter(self, name: str, it):
+        """``it`` with each ``next()`` under ``timed(name)``: the work
+        that makes an item is timed and its consumer's is not, and no
+        span is held across the ``yield`` (tests/lint_robustness.py).
+        Closing this closes ``it``."""
+        it = iter(it)
+        done = object()
+        try:
+            while True:
+                with self.timed(name):
+                    item = next(it, done)
+                if item is done:
+                    return
+                yield item
+        finally:
+            close = getattr(it, "close", None)
+            if close is not None:
+                close()
+
     def span_name(self, metric: str) -> str:
         """``<owner>.<metric>``, built once per metric (and only when a
         traced timer first asks)."""

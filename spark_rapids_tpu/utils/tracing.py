@@ -18,6 +18,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+from typing import Optional
 
 try:
     import jax
@@ -28,40 +29,50 @@ except Exception:  # pragma: no cover
 _enabled = False
 
 # Overlap-pipeline span names (docs/io_overlap.md): the prefetch wait is
-# the consumer blocked on the background decode queue; the H2D overlap
-# span covers consumer compute running while the next upload is in
-# flight.  Shared constants so Xprof captures from different operators
-# aggregate under the same labels.
+# the consumer blocked on the background decode queue, the coalesce pull
+# the consumer blocked on the device lookahead.  Both are sections whose
+# self time is the ``io_wait_us`` counter.  Shared constants so Xprof
+# captures from different operators aggregate under the same labels.
 SPAN_PREFETCH_WAIT = "io.prefetch.wait"
-SPAN_H2D_OVERLAP = "io.h2d.overlap"
 SPAN_COALESCE_PULL = "io.coalesce.pull"
-# the egress (device->host) mirror: the D2H wait is the consumer blocked
-# on the background download queue; the overlap span covers host
-# serialize/send/write running while the next pull is in flight
-# (docs/d2h_egress.md)
-SPAN_D2H_WAIT = "io.d2h.wait"
-SPAN_D2H_OVERLAP = "io.d2h.overlap"
+# egress (docs/d2h_egress.md): a pulled result made host Arrow (the pull
+# inside it is ``d2h.pull``), and an action's final table build.  Both
+# are sections whose self time is the ``egress_us`` counter
+SPAN_EGRESS_FINISH = "egress.finish"
+SPAN_EGRESS_TABLE = "egress.table"
+# a call that hands the device work: a program launch
+# (compile/service.py) or a host->device copy dispatch
+# (columnar/transfer.py); a section whose self time is ``dispatch_us``
+SPAN_HOST_DISPATCH = "host.dispatch"
 # the planner's whole-stage fusion rewrite (plan/fusion.py)
 SPAN_PLAN_FUSION = "plan.fusion"
 # adaptive replanning passes (docs/adaptive.md): one span per
 # stats-driven replan of the not-yet-executed plan remainder
 SPAN_PLAN_AQE = "plan.aqe"
-# the two phases of a query (api.py): planning, then the root drain with
-# the dispatch watcher's drain at its end; and the two blocking device
+# the two phases of a query (api.py): planning, then the root drain
+# (sections whose totals are ``plan_us`` and ``execute_us``, and the
+# drain's self time ``execute_self_us``); and the two blocking device
 # reads (columnar/transfer.py): an egress pull, and a small synchronous
 # read outside egress (``d2h.sync:<what>``: a row count, a debug column)
 SPAN_QUERY_PLAN = "query.plan"
 SPAN_QUERY_EXECUTE = "query.execute"
 SPAN_D2H_PULL = "d2h.pull"
 SPAN_D2H_SYNC = "d2h.sync"
+# an action's own bookkeeping around plan and drain (lifecycle.py,
+# api.py): the query scope's entry and its exit, and the trace's own
+# work inside them (the dispatch ledger's mark, the watcher's drain and
+# the ledger's read-back): sections whose self times are ``scope_us``
+# and ``trace_us``
+SPAN_QUERY_OPEN = "query.open"
+SPAN_QUERY_CLOSE = "query.close"
+SPAN_TRACE_DRAIN = "trace.drain"
 # a served request (server/core.py), on the worker that picked its
-# ticket up: ``server.execute:<tenant>`` from pick-up to typed outcome,
-# a marker ``server.admit_wait`` at the pick-up (the wait itself ended
-# there; its microseconds are the ``server.admit_wait_us`` counter), and
-# the server's own work around the query: resolving the ticket to a
+# ticket up: ``server.execute:<tenant>`` from pick-up to typed outcome
+# (a section whose self time is ``scope_us``; the admission wait ended
+# at the pick-up and is the ``server.admit_wait_us`` counter), and the
+# server's own work around the query: resolving the ticket to a
 # DataFrame (parse or bind) and forming the result cache's key (plan and
 # input-snapshot fingerprints, which stat and read every scanned file)
-SPAN_SERVER_ADMIT_WAIT = "server.admit_wait"
 SPAN_SERVER_EXECUTE = "server.execute"
 SPAN_SERVER_RESOLVE = "server.resolve"
 SPAN_SERVER_CACHE_KEY = "server.cache_key"
@@ -71,7 +82,11 @@ SPAN_SERVER_CACHE_KEY = "server.cache_key"
 # made one batch and split over the mesh, or scanned shard by shard
 # onto it; the ``shard_map`` programs from launch to their sync; the
 # result pulled or stacked back into one batch.  Their microseconds are
-# the ``ici.ingest_us`` / ``collective_us`` / ``gather_us`` counters
+# the ``ici.ingest_us`` / ``collective_us`` / ``gather_us`` counters.
+# These, and the join and scan spans below, are totals measured beside
+# the sections, not sections: the launches, pulls and waits inside them
+# are the sections' own, and their Python is the operators'
+# (``execute_self_us``)
 SPAN_ICI_INGEST = "ici.ingest"
 SPAN_ICI_COLLECTIVE = "ici.collective"
 SPAN_ICI_GATHER = "ici.gather"
@@ -90,39 +105,47 @@ SPAN_SCAN_DECODE = "scan.decode"
 SPAN_SCAN_UPLOAD = "scan.upload"
 
 # Always-on phase counters (the ``phases`` group of ``engine_stats()``,
-# docs/observability.md): microseconds a query spent planning and
-# executing, microseconds a thread sat in a blocking device read, and
-# the number of synchronous reads outside ``device_pull``.  Bumped once
-# per query or per pull, never per batch.
+# docs/observability.md), kept in nanoseconds and read in microseconds.
+# Each is bumped by one section (``section``) as it closes, once per
+# launch, wait or query, never per row.  ``plan_us``, ``execute_us`` and
+# ``pull_wait_us`` are a section's whole time; the others its self time:
+# the time on its thread inside it and inside no section nested in it,
+# so the sections tile a query's host time and nothing counts twice.
+# ``blocking_reads`` counts the synchronous reads outside ``device_pull``.
 _PHASES_LOCK = threading.Lock()
-_PHASES = {"plan_us": 0, "execute_us": 0, "pull_wait_us": 0,
-           "blocking_reads": 0}
-
-# Per-thread stacks kept under the switch only: the names of the open
-# spans (``trace_range`` / ``MetricSet.timed``) and the plan nodes whose
-# ``next()`` is running (exec/base.py ``_count_output``).  The dispatch
-# ledger (compile/service.py) reads the top of each when a program is
-# launched.
-_TLS = threading.local()
+_PHASES = dict.fromkeys((
+    "plan_us", "execute_us", "pull_wait_us", "blocking_reads",
+    "dispatch_us", "io_wait_us", "egress_us", "execute_self_us",
+    "scope_us", "trace_us"), 0)
 
 
-def _stack(which: str) -> list:
-    st = getattr(_TLS, which, None)
-    if st is None:
-        st = []
-        setattr(_TLS, which, st)
-    return st
+class _Thread(threading.local):
+    """One thread's open sections (always on: ``[start_ns, nested_ns]``
+    each, innermost last) and, under the switch only, the open spans
+    (``trace_range`` / ``MetricSet.timed``) and the plan nodes whose
+    ``next()`` is running (exec/base.py ``_count_output``).  The
+    dispatch ledger (compile/service.py) reads the top of the last two
+    when a program is launched.  ``tally`` is ``tally()``'s."""
+
+    def __init__(self):
+        self.sections: list = []
+        self.spans: list = []
+        self.nodes: list = []
+        self.tally: Optional[dict] = None
+
+
+_TLS = _Thread()
 
 
 def current_span() -> str:
     """Innermost open span of this thread ('' when none)."""
-    st = getattr(_TLS, "spans", None)
-    return st[-1] if st else ""
+    st = _TLS.spans
+    return st[-1].name if st else ""
 
 
 def current_node():
     """The plan node whose ``next()`` runs on this thread, or None."""
-    st = getattr(_TLS, "nodes", None)
+    st = _TLS.nodes
     return st[-1] if st else None
 
 
@@ -132,7 +155,7 @@ def running(node, it):
     ``node``.  Callers wrap only when the switch is on."""
     it = iter(it)
     while True:
-        nodes = _stack("nodes")  # of the thread that runs this next()
+        nodes = _TLS.nodes  # of the thread that runs this next()
         nodes.append(node)
         try:
             item = next(it)
@@ -144,13 +167,15 @@ def running(node, it):
 
 
 def phase_add(counter: str, amount: int) -> None:
+    """Add ``amount`` to a phase counter that counts (not a clock)."""
     with _PHASES_LOCK:
         _PHASES[counter] += amount
 
 
 def phase_stats() -> dict:
     with _PHASES_LOCK:
-        return dict(_PHASES)
+        return {k: v // 1000 if k.endswith("_us") else v
+                for k, v in _PHASES.items()}
 
 
 def reset_phase_stats() -> None:
@@ -170,6 +195,18 @@ def is_enabled() -> bool:
     return _enabled
 
 
+def _leave(stack: list, entry) -> None:
+    """Take ``entry`` off ``stack``: the top, unless a region was left
+    out of order, and then its own entry and not another's."""
+    if stack and stack[-1] is entry:
+        stack.pop()
+    else:
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i] is entry:
+                del stack[i]
+                break
+
+
 class _Span:
     """One open span: the profiler annotation plus an entry on the
     thread's span stack."""
@@ -181,13 +218,13 @@ class _Span:
         self._ann = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
-        _stack("spans").append(self.name)
+        _TLS.spans.append(self)
         self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._ann.__exit__(*exc)
-        _stack("spans").pop()
+        _leave(_TLS.spans, self)
         return False
 
 
@@ -216,16 +253,68 @@ def trace_range(name: str, metric=None):
             metric.add(time.perf_counter_ns() - start)
 
 
+class section:
+    """A timed region of a thread's host time, always on: its span under
+    the switch (``annotation``, as ``trace_range`` opens it), and on
+    close its whole time into the phase counter ``total`` and its self
+    time (the time in no section nested in it) into ``own``, either or
+    both.  ``ns`` is the whole time once closed.  A section opens and
+    closes on one thread and is never held across a ``yield``
+    (tests/lint_robustness.py): a generator resumed later, or on another
+    thread, would charge its consumer's time to it."""
+
+    __slots__ = ("span", "total", "own", "ns", "_frame", "_ann")
+
+    def __init__(self, span: str, total: Optional[str] = None,
+                 own: Optional[str] = None):
+        self.span = span
+        self.total = total
+        self.own = own
+        self.ns = 0
+
+    def __enter__(self):
+        self._ann = ann = annotation(self.span)
+        if ann is not None:
+            ann.__enter__()
+        self._frame = frame = [time.perf_counter_ns(), 0]
+        _TLS.sections.append(frame)
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        frame = self._frame
+        th = _TLS
+        st = th.sections
+        _leave(st, frame)
+        self.ns = ns = end - frame[0]
+        own = ns - frame[1]
+        if st:
+            st[-1][1] += ns
+        if self.total is not None or self.own is not None:
+            with _PHASES_LOCK:
+                if self.total is not None:
+                    _PHASES[self.total] += ns
+                if self.own is not None:
+                    _PHASES[self.own] += own
+        if th.tally is not None:
+            th.tally[self.span] = th.tally.get(self.span, 0) + own
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
 @contextlib.contextmanager
-def phase(span: str, counter: str):
-    """``trace_range(span)`` whose elapsed microseconds also land in the
-    always-on phase counter ``counter``."""
-    start = time.perf_counter_ns()
+def tally():
+    """Collect this thread's sections for the enclosed work: yields a
+    dict that fills with ``{span: self nanoseconds}`` as each section
+    closes, so the values add up to the time the sections covered (the
+    per-query table of docs/observability.md, "Sections")."""
+    was = _TLS.tally
+    _TLS.tally = out = {}
     try:
-        with trace_range(span):
-            yield
+        yield out
     finally:
-        phase_add(counter, (time.perf_counter_ns() - start) // 1000)
+        _TLS.tally = was
 
 
 # Traced scopes that are open now, and the switch as it stood before

@@ -46,6 +46,17 @@ test suite itself:
    hot Q3 on four chips; docs/ici_shuffle.md, "Where a mesh program
    lives").  Programs go through ``parallel/mesh.py: mesh_program``.
 
+6. **A timed region held across a ``yield``** (repo-wide): a
+   ``with`` over a section, a span or a metric timer
+   (``tracing.section`` / ``trace_range`` / ``annotation``,
+   ``MetricSet.timed``, ``mesh.phase``) whose body yields.  The
+   generator's consumer then runs inside the region, on whatever
+   thread resumes it, and the thread's section and span stacks charge
+   that work to the wrong name (docs/observability.md, "Sections").
+   Time each ``next()`` instead (``MetricSet.timed_iter``).  A
+   ``@contextmanager`` function is exempt: its ``yield`` is the
+   caller's ``with`` body.
+
 Run as part of the normal suite (pytest.ini collects ``lint_*.py``).
 """
 
@@ -1158,3 +1169,101 @@ def test_every_ooc_conf_key_is_documented():
         "spark.rapids.sql.ooc.* conf keys missing from "
         "docs/out_of_core.md — an undocumented out-of-core knob is "
         f"one nobody can safely turn: {missing}")
+
+
+_TIMED_REGIONS = ("section", "trace_range", "annotation", "timed", "phase")
+
+
+def _callee_name(node: ast.expr):
+    if isinstance(node, ast.Call):
+        f = node.func
+        return f.attr if isinstance(f, ast.Attribute) \
+            else getattr(f, "id", None)
+    return None
+
+
+def _yields_in(body) -> List[int]:
+    """Line numbers of the yields in ``body``, nested functions aside."""
+    out, todo = [], list(body)
+    while todo:
+        n = todo.pop()
+        if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
+                          ast.Lambda, ast.ClassDef)):
+            continue
+        if isinstance(n, (ast.Yield, ast.YieldFrom)):
+            out.append(n.lineno)
+        todo.extend(ast.iter_child_nodes(n))
+    return out
+
+
+def _is_contextmanager(fn: ast.AST) -> bool:
+    return any((getattr(d, "attr", None) or getattr(d, "id", None))
+               == "contextmanager" for d in fn.decorator_list)
+
+
+def _regions_held_across_yield(tree: ast.AST, rel: str) -> List[str]:
+    exempt = set()
+    for fn in ast.walk(tree):
+        if isinstance(fn, ast.FunctionDef) and _is_contextmanager(fn):
+            exempt.update(id(n) for n in fn.body)
+    offenders = []
+
+    def visit(node, in_cm):
+        for child in ast.iter_child_nodes(node):
+            cm = in_cm or id(child) in exempt
+            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                cm = False
+            if isinstance(child, ast.With) and not cm and any(
+                    _callee_name(i.context_expr) in _TIMED_REGIONS
+                    for i in child.items):
+                offenders.extend(f"{rel}:{ln}"
+                                 for ln in _yields_in(child.body))
+            visit(child, cm)
+    visit(tree, False)
+    return offenders
+
+
+def test_no_timed_region_is_held_across_a_yield():
+    offenders = []
+    for path in _package_sources():
+        offenders += _regions_held_across_yield(
+            _parsed(path), os.path.relpath(path, _REPO))
+    assert not offenders, (
+        "a section, span or metric timer held open across a yield: the "
+        "consumer's work lands in it, on whichever thread resumes the "
+        "generator — time each next() instead "
+        f"(MetricSet.timed_iter): {offenders}")
+
+
+def test_the_yield_lint_catches_what_it_is_for():
+    src = """
+import contextlib
+
+def held(it, ms):
+    for b in it:
+        with ms.timed("totalTime"):
+            yield b
+
+def spanned(it):
+    with tracing.section("x", own="egress_us"):
+        yield from it
+
+def fine(it, ms):
+    for b in it:
+        with ms.timed("totalTime"):
+            out = b
+        yield out
+
+def nested_def_is_its_own(ms):
+    with ms.timed("totalTime"):
+        def gen():
+            yield 1
+    return gen
+
+@contextlib.contextmanager
+def phase(name):
+    with tracing.trace_range(name):
+        yield
+"""
+    found = _regions_held_across_yield(ast.parse(src), "t.py")
+    assert found == ["t.py:7", "t.py:11"], found

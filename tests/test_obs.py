@@ -119,7 +119,10 @@ def test_snapshot_has_the_programs_and_phases_groups_flat_and_numeric():
     from spark_rapids_tpu.compile import service
     snap = registry.snapshot()
     assert set(snap["phases"]) == {"plan_us", "execute_us",
-                                   "pull_wait_us", "blocking_reads"}
+                                   "pull_wait_us", "blocking_reads",
+                                   "dispatch_us", "io_wait_us", "egress_us",
+                                   "execute_self_us", "scope_us",
+                                   "trace_us"}
     assert {"dispatches", "device_us", "starved_us", "untimed"} \
         <= set(snap["programs"])
     for fam in service.FAMILIES:

@@ -179,7 +179,7 @@ def test_a_traced_request_is_spanned_by_the_server(tpch, monkeypatch):
     workers = {t for t, _ in seen if t.startswith("srt-server-worker-")}
     assert len(workers) == 1
     names = [n for t, n in seen if t in workers]
-    assert names[:2] == ["server.execute:dashboards", "server.admit_wait"]
+    assert names[:2] == ["server.execute:dashboards", "server.resolve"]
     for inner in ("server.resolve", "server.cache_key", "query.plan",
                   "query.execute"):
         assert inner in names, inner
