@@ -132,6 +132,9 @@ class TpuBroadcastHashJoinExec(TpuHashJoinExec):
     batch comes from the exchange's cache, so re-executions (or a future
     multi-consumer plan) build the hash table input only once."""
 
+    # the plan chose the build side: the broadcast one
+    _sides_by_size = False
+
     def __init__(self, left, broadcast: TpuBroadcastExchangeExec,
                  left_keys: List[Expression],
                  right_keys: List[Expression], join_type: str = "inner",

@@ -7,6 +7,17 @@ kept for the task lifetime), GpuBroadcastHashJoinExec.scala:83.
 
 TPU design (SURVEY §7 "hard parts": two-pass count-then-gather under
 static shapes):
+  0. SIDES: the build side is the right child, except that an inner
+     equi-join with no condition (``TpuHashJoinExec`` itself, not the
+     broadcast join) builds on its left child when the left side ends
+     holding fewer rows than the right by the host-known row bounds
+     (``_peek_smaller_left``) and the uniqueness probe (step 1) finds
+     its keys unique; the right batches then stream past it on an fk
+     route, each output is cut to its pulled count (``_trim_to_count``),
+     and the output keeps its [left..., right...] column order.  A
+     smaller left side with repeated keys is streamed past the right
+     build as before: the generic probe's search over every row of the
+     larger side costs more than the larger build.
   1. BUILD (once): hash the build-side keys (splitmix64 over column
      values; packed-chunk folds for strings), sort build rows by hash.
   2. PROBE-COUNT (per stream batch, jitted): hash stream keys, binary
@@ -55,9 +66,11 @@ from spark_rapids_tpu.utils.metrics import METRIC_TOTAL_TIME
 # ``band``: the same over a band-narrowed probe; ``fk``: the one
 # sync-free program over unique build keys; ``fk_dense``: its
 # direct-address form), ``broadcast`` where the build side came from a
-# ``TpuBroadcastExchangeExec`` whatever the route, and the rows a join
-# saw as the host knows them without a device read: an exact count
-# where one was pulled already, else a ``LazyRows`` bound.
+# ``TpuBroadcastExchangeExec`` whatever the route, ``build_left`` where
+# the join built on its smaller left side (unique keys, an fk route),
+# and the rows a join saw as the host knows them without a device read:
+# an exact count where one was pulled already, else a ``LazyRows``
+# bound.
 # ``out_slots`` is the capacity of the batches handed on: work
 # downstream is done at capacity, not at rows (ROADMAP D14).
 # ``build_us`` / ``probe_us`` sum the node metrics ``buildTime`` /
@@ -65,8 +78,8 @@ from spark_rapids_tpu.utils.metrics import METRIC_TOTAL_TIME
 JOIN_ROUTES = ("generic", "band", "fk", "fk_dense")
 _JOIN_LOCK = threading.Lock()
 _JOIN = dict.fromkeys(
-    ("joins", *JOIN_ROUTES, "broadcast", "build_rows", "stream_rows",
-     "out_rows", "out_slots", "build_us", "probe_us"), 0)
+    ("joins", *JOIN_ROUTES, "broadcast", "build_left", "build_rows",
+     "stream_rows", "out_rows", "out_slots", "build_us", "probe_us"), 0)
 
 
 def join_stats() -> Dict[str, int]:
@@ -816,24 +829,97 @@ def _compile_gather_pairs(s_sig, b_sig, in_cap: int, out_cap: int):
     return fn
 
 
-def _gather_pairs(s_batch: ColumnarBatch, b_batch: ColumnarBatch,
-                  keep, i, brow, kept, out_cap: int,
-                  schema: Schema, wrap=None) -> ColumnarBatch:
-    """Compact verified candidates and gather both sides.  ``kept`` may be
-    a device scalar (LazyRows) — the output capacity is sized by the
-    host-known candidate total instead, avoiding a second link sync.
-    Encoded columns gather their codes planes and re-wrap (``wrap``
-    overrides the dictionary per combined-position — the join code
-    view's re-keyed stream key decodes through the build dictionary)."""
+def _wrap_joined(sv, outs, rows, schema: Schema,
+                 build_left: bool) -> ColumnarBatch:
+    """The joined batch from a pair gather's planes, which come stream
+    side first.  Encoded columns re-wrap: the code view's wrap maps
+    override the dictionary per position (its re-keyed stream key
+    decodes through the build dictionary).  A join built on its left
+    side turns the columns round here, on the host, so that its output
+    is [left..., right...] like any other join's."""
+    from spark_rapids_tpu.columnar import encoding
+    cols = list(sv.s_batch.columns) + list(sv.b_batch.columns)
+    ns = len(sv.s_batch.columns)
+    wrap = dict(sv.s_wrap)
+    wrap.update({ns + i: d for i, d in sv.b_wrap.items()})
+    if build_left:
+        order = list(range(ns, len(cols))) + list(range(ns))
+        cols = [cols[i] for i in order]
+        outs = [outs[i] for i in order]
+        at = {src: dst for dst, src in enumerate(order)}
+        wrap = {at[i]: d for i, d in wrap.items()}
+    return encoding.wrap_gathered(cols, outs, rows, schema,
+                                  extra_wrap=wrap)
+
+
+def _gather_pairs(sv, keep, i, brow, kept, out_cap: int,
+                  schema: Schema) -> ColumnarBatch:
+    """Compact verified candidates and gather both sides of the stream
+    view ``sv``.  ``kept`` may be a device scalar (LazyRows) — the
+    output capacity is sized by the host-known candidate total instead,
+    avoiding a second link sync.  Encoded columns gather their codes
+    planes (``_wrap_joined``)."""
     from spark_rapids_tpu.columnar import encoding
     from spark_rapids_tpu.columnar.column import rows_traced
-    s_flat, s_sig = encoding.flat_and_sig(s_batch)
-    b_flat, b_sig = encoding.flat_and_sig(b_batch)
+    s_flat, s_sig = encoding.flat_and_sig(sv.s_batch)
+    b_flat, b_sig = encoding.flat_and_sig(sv.b_batch)
     fn = _compile_gather_pairs(s_sig, b_sig, keep.shape[0], out_cap)
     outs = fn(s_flat, b_flat, keep, i, brow, rows_traced(kept))
-    return encoding.wrap_gathered(
-        list(s_batch.columns) + list(b_batch.columns), outs, kept,
-        schema, extra_wrap=wrap)
+    return _wrap_joined(sv, outs, kept, schema, False)
+
+
+_TRIM_CACHE = KernelCache("join.trim", 256)
+
+
+def _trim_to_count(outs, kept, in_cap: int, metrics):
+    """-> (planes, rows): an fk program's output, whose matches lie
+    packed at its front, cut to the bucket of their pulled count.  A
+    join built on its left side streams the larger side past the
+    smaller, so most of a stream batch's slots find no partner, and the
+    operators downstream work at the capacity they are handed (D14):
+    one count a batch, as the generic route pulls, buys them the rows'
+    own capacity."""
+    from spark_rapids_tpu.columnar.transfer import device_pull
+    n = int(device_pull(kept, metrics=metrics))
+    cap = bucket_capacity(max(1, n))
+    if cap >= in_cap:
+        return outs, n
+    fn = _TRIM_CACHE.get(cap)
+    if fn is None:
+        def run(planes):
+            return jax.tree_util.tree_map(lambda p: p[:cap], planes)
+        fn = engine_jit(run, family="join", name="trim")
+        _TRIM_CACHE[cap] = fn
+    return fn(outs), n
+
+
+def _peek_smaller_left(right: List[ColumnarBatch],
+                       left: Iterator[ColumnarBatch]):
+    """-> (left batches pulled, the left side, whether it is smaller).
+
+    ``right`` is the drained right side; ``left`` is pulled only until
+    its batches' host-known row bounds reach the right's sum.  The left
+    side is the smaller where it ends first holding fewer rows; a tie,
+    or a left side with no batch, is not.  No device value is read."""
+    r = sum(b.rows_bound for b in right)
+    pulled: List[ColumnarBatch] = []
+    rows = 0
+    for b in left:
+        pulled.append(b)
+        rows += b.rows_bound
+        if rows >= r:
+            return pulled, left, False
+    return pulled, left, bool(pulled)
+
+
+def _handing_on(batches: List[ColumnarBatch],
+                rest=()) -> Iterator[ColumnarBatch]:
+    """``batches`` in their order, each let go of as it is handed on,
+    then ``rest``: a side held whole is freed batch by batch as it
+    streams."""
+    while batches:
+        yield batches.pop(0)
+    yield from rest
 
 
 _UNMATCHED_CACHE = KernelCache("join.unmatched", 256)
@@ -915,8 +1001,17 @@ def _gather_side_with_nulls(batch: ColumnarBatch, mask, count,
 
 
 class TpuHashJoinExec(TpuExec):
-    """Shared hash-join core; build side = right child (reference
-    GpuHashJoin.scala:40, build-right like GpuShuffledHashJoinExec)."""
+    """Shared hash-join core (reference GpuHashJoin.scala:40).  The build
+    side is the right child, as GpuShuffledHashJoinExec's, except that an
+    inner equi-join with no condition builds on its left child where the
+    left side holds fewer rows by the host-known bounds
+    (``_peek_smaller_left``) and its keys are unique: the side a plan
+    cannot size statically, such as a join's output, is sized here, and
+    the larger right side streams past it on a sync-free fk route.  A
+    class whose plan fixes the build side (the broadcast join) sets
+    ``_sides_by_size`` False and always builds right."""
+
+    _sides_by_size = True
 
     def __init__(self, left, right, left_keys: List[Expression],
                  right_keys: List[Expression], join_type: str = "inner",
@@ -964,8 +1059,9 @@ class TpuHashJoinExec(TpuExec):
         """The join (``_join``), counted once into the ``join`` group
         when it ends, however it ends."""
         from spark_rapids_tpu.exec.broadcast import TpuBroadcastExchangeExec
-        seen = {"joins": 1, "route": "generic", "build_rows": 0,
-                "stream_rows": 0, "out_rows": 0, "out_slots": 0,
+        seen = {"joins": 1, "route": "generic", "build_left": 0,
+                "build_rows": 0, "stream_rows": 0, "out_rows": 0,
+                "out_slots": 0,
                 "broadcast": int(isinstance(self.children[1],
                                             TpuBroadcastExchangeExec))}
         clocks = {"build_us": self.metrics["buildTime"],
@@ -984,24 +1080,21 @@ class TpuHashJoinExec(TpuExec):
                 for k, v in seen.items():
                     _JOIN[k] += v
 
-    def _join(self, ctx: ExecContext, seen: dict
-              ) -> Iterator[ColumnarBatch]:
-        """``seen`` is ``_run``'s tally: the route taken and the rows of
-        each side, written here as they become known on the host."""
+    def _build(self, batches: List[ColumnarBatch], build_left: bool):
+        """-> (join code view, keys key): ``batches`` made the one build
+        batch, on the side ``build_left`` names, and its code view.  The
+        join's own work on its build side (the child's is the child's)."""
         from spark_rapids_tpu.columnar import encoding as _enc
-        schema = self.output_schema
-        is_cross = self.join_type == "cross"
-        # BUILD: coalesce right side to one batch
-        # (RequireSingleBatch goal, GpuShuffledHashJoinExec.scala:83)
-        b_batches = list(self.children[1].execute_columnar(ctx))
-        # the join's own work on its build side (the child's is the
-        # child's): the concat here, the uniqueness probe below
+        s_child, b_child = self.children[::-1] if build_left \
+            else self.children
+        s_keys, b_keys = (self.right_keys, self.left_keys) if build_left \
+            else (self.left_keys, self.right_keys)
         with tracing.trace_range(tracing.SPAN_JOIN_BUILD,
                                  self.metrics["buildTime"]):
-            if b_batches:
-                b_batch = concat_batches(b_batches)
+            if batches:
+                b_batch = concat_batches(batches)
             else:
-                b_batch = _empty_batch(self.children[1].output_schema)
+                b_batch = _empty_batch(b_child.output_schema)
             # equi-join keys compare as CODES where both sides reference
             # encoded columns (docs/compressed.md): the view keeps the
             # build side's codes, re-keys each stream batch into the
@@ -1009,15 +1102,20 @@ class TpuHashJoinExec(TpuExec):
             # INT32 refs — a stream batch arriving dense drops to the
             # dense-keys variant
             jv = _enc.JoinCodeView(
-                b_batch, self.left_keys, self.right_keys,
-                len(self.children[0].output_schema.fields),
+                b_batch, s_keys, b_keys,
+                len(s_child.output_schema.fields),
                 condition=self.condition)
-        seen["build_rows"] = b_batch.rows_bound
-        b_batch = jv.build_batch
-        b_flat, b_sig = _enc.flat_and_sig(b_batch)
-        keys_key = (tuple(e.key() for e in self.left_keys),
+        keys_key = (tuple(e.key() for e in s_keys),
                     tuple(e.key() for e in jv.rkeys_code),
                     self.join_type)
+        return jv, keys_key
+
+    def _probe(self, jv, keys_key) -> tuple:
+        """-> (max_run, key_lo, key_hi) of the build batch (``_build_probe``,
+        memoized)."""
+        from spark_rapids_tpu.columnar import encoding as _enc
+        b_batch = jv.build_batch
+        b_flat, b_sig = _enc.flat_and_sig(b_batch)
 
         def build_probe_thunk():
             # the separate build executable exists ONLY for this probe;
@@ -1037,15 +1135,57 @@ class TpuHashJoinExec(TpuExec):
                          device_pull((max_run, klo, khi),
                                      metrics=self.metrics))
 
+        return _build_probe(keys_key, b_flat, b_batch.rows_raw,
+                            build_probe_thunk, b_batch.capacity)
+
+    def _join(self, ctx: ExecContext, seen: dict
+              ) -> Iterator[ColumnarBatch]:
+        """``seen`` is ``_run``'s tally: the route taken, the side built
+        on and the rows of each side, written here as they become known
+        on the host."""
+        from spark_rapids_tpu.columnar import encoding as _enc
+        schema = self.output_schema
+        is_cross = self.join_type == "cross"
+        equi = self.join_type == "inner" and self.condition is None
+        # BUILD: coalesce the build side to one batch
+        # (RequireSingleBatch goal, GpuShuffledHashJoinExec.scala:83);
+        # the right side is drained first, and an inner equi-join then
+        # builds on the left instead where the left side is the smaller
+        # and its keys are unique
+        right = list(self.children[1].execute_columnar(ctx))
+        stream = self.children[0].execute_columnar(ctx)
+        built = probe = None
+        if equi and self._sides_by_size:
+            pulled, rest, smaller = _peek_smaller_left(right, stream)
+            if smaller:
+                built = self._build(pulled, True)
+                probe = self._probe(*built)
+                if probe[0] > 1:
+                    # repeated keys would take the generic route, whose
+                    # search runs over every row of the larger side
+                    built = probe = None
+            if built is None:
+                stream = _handing_on(pulled, rest)
+            else:
+                pulled.clear()
+                stream = _handing_on(right)
+        build_left = built is not None
+        if built is None:
+            built = self._build(right, False)
+            right.clear()
+        jv, keys_key = built
+        b_batch = jv.build_batch
+        b_flat, b_sig = _enc.flat_and_sig(b_batch)
+        seen["build_left"] = int(build_left)
+        seen["build_rows"] = b_batch.rows_bound
+
         from spark_rapids_tpu.columnar.column import LazyRows
         # FK fast path: inner equi-join against UNIQUE build keys (the
         # dimension-table shape) fuses probe+verify+compact+gather into
         # one kernel with a static output capacity — no host sync per
         # batch (the general path needs one to size its expansion)
-        if self.join_type == "inner" and self.condition is None:
-            max_run, klo, khi = _build_probe(
-                keys_key, b_flat, b_batch.rows_raw, build_probe_thunk,
-                b_batch.capacity)
+        if equi:
+            max_run, klo, khi = probe or self._probe(jv, keys_key)
             fk = max_run <= 1
         else:
             fk, klo, khi = False, 0, -1
@@ -1097,17 +1237,15 @@ class TpuHashJoinExec(TpuExec):
                             s_flat, sb.rows_traced,
                             vb_flat, b_batch.rows_traced)
                     self.metrics["fkFastPathBatches"].add(1)
-                    n_out = LazyRows(kept, sb.rows_bound)
-                    nsc = len(sv.s_batch.columns)
-                    wrap = dict(sv.s_wrap)
-                    wrap.update({nsc + i: d
-                                 for i, d in sv.b_wrap.items()})
-                    return _enc.wrap_gathered(
-                        list(sv.s_batch.columns)
-                        + list(sv.b_batch.columns), outs, n_out,
-                        schema, extra_wrap=wrap)
+                    rows = LazyRows(kept, sb.rows_bound)
+                    if build_left:
+                        outs, rows = _trim_to_count(outs, kept,
+                                                    sb.capacity,
+                                                    self.metrics)
+                    return _wrap_joined(sv, outs, rows, schema,
+                                        build_left)
 
-            for s_batch in self.children[0].execute_columnar(ctx):
+            for s_batch in stream:
                 seen["stream_rows"] += s_batch.rows_bound
                 yield from with_retry(process_fk, s_batch, ctx,
                                       split=split_batch_half)
@@ -1180,14 +1318,10 @@ class TpuHashJoinExec(TpuExec):
                     mb = m_build
                 if jt in ("inner", "cross", "left", "right", "full"):
                     if n_candidates:
-                        nsc = len(sv.s_batch.columns)
-                        wrap = dict(sv.s_wrap)
-                        wrap.update({nsc + i2: d2
-                                     for i2, d2 in sv.b_wrap.items()})
                         out = _gather_pairs(
-                            sv.s_batch, sv.b_batch, keep, i, brow,
+                            sv, keep, i, brow,
                             LazyRows(kept, n_candidates), out_cap,
-                            schema, wrap=wrap)
+                            schema)
                         if self.condition is not None:
                             out = filter_batch(self.condition, out)
                             out.schema = schema
@@ -1209,7 +1343,7 @@ class TpuHashJoinExec(TpuExec):
                         LazyRows(n_unmatched, sb.rows_bound), schema))
             return outs, mb
 
-        for s_batch in self.children[0].execute_columnar(ctx):
+        for s_batch in stream:
             seen["stream_rows"] += s_batch.rows_bound
             for outs, mb in with_retry(process_stream, s_batch, ctx,
                                        split=split_batch_half):

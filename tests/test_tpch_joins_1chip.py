@@ -19,7 +19,10 @@ asks its readers for 26 of its tables' 80 columns (``scan.columns_read`` /
 that produced its rows; ``scan.decode_us``, ``upload_us`` and
 ``upload_bytes`` move on a miss and stand still on a hit; the four spans
 (``join.build``, ``join.probe``, ``scan.decode``, ``scan.upload``) are
-emitted under ``spark.rapids.sql.trace.enabled``.
+emitted under ``spark.rapids.sql.trace.enabled``.  With the broadcast
+threshold scaled with the data, as at SF1 on the chip, Q3's and Q5's
+lineitem joins build on the filtered orders, their smaller side with
+unique keys, and still answer what the reference does.
 """
 
 import contextlib
@@ -54,6 +57,7 @@ COLUMNS = {"q3": (2 + 4 + 4, 8 + 9 + 16),
 FALLBACKS = ("ici.fallbacks", "ooc.fallbacks", "compile.aotFailures",
              "fusion.warm_errors")
 MISS_COSTS = ("decode_us", "upload_us", "upload_bytes")
+SCALED_BROADCAST = 10 * 1024 * 1024 * ROWS // 6_000_000  # SF1's 10 MB
 SPANS = (tracing.SPAN_JOIN_BUILD, tracing.SPAN_JOIN_PROBE,
          tracing.SPAN_SCAN_DECODE, tracing.SPAN_SCAN_UPLOAD)
 
@@ -162,6 +166,7 @@ def runs(tmp_path_factory, config):
                                           "scan")
             finally:
                 sess.stop()
+            out[seed, "paths"] = paths
     finally:
         tracing._Span.__enter__ = enter
     return out
@@ -283,6 +288,54 @@ def test_the_join_group_adds_up(runs, seed, query, when):
     fast = sum(n.metrics.get("fkFastPathBatches", 0) > 0
                for n in run["nodes"])
     assert join["fk"] + join["fk_dense"] == fast
+
+
+@pytest.fixture(scope="module")
+def scaled(runs, config):
+    """Q3 and Q5 on every seed with the broadcast threshold scaled with
+    the data (SF1's 10 MB at 60 k rows), so that, as at SF1 on the chip,
+    lineitem is too large to broadcast and each query's lineitem join
+    is a ``TpuHashJoinExec`` over two sides the planner cannot size:
+    each query's answer and what its ``join`` group grew by."""
+    builders = _load("queries", "tpch_power.py")
+    out = {}
+    for seed in SEEDS:
+        conf = dict(config["conf"])
+        conf["spark.sql.autoBroadcastJoinThreshold"] = str(SCALED_BROADCAST)
+        sess = TpuSession(conf)
+        try:
+            tables = {n: sess.read.parquet(p)
+                      for n, p in runs[seed, "paths"].items()}
+            for q in CELL_QUERIES:
+                before = sess.engine_stats()
+                got = builders.build(q, tables).to_arrow()
+                out[seed, q] = {
+                    "got": got,
+                    "nodes": [n.name for n in
+                              _nodes(sess.last_query_profile().root)],
+                    "join": _grown(sess.engine_stats(), before, "join")}
+        finally:
+            sess.stop()
+    return out
+
+
+@pytest.mark.parametrize("query", CELL_QUERIES)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_a_lineitem_join_builds_on_its_smaller_side(runs, scaled, config,
+                                                    seed, query):
+    """Each query's lineitem join builds on the filtered orders, whose
+    keys are unique, and streams lineitem past it on a sync-free route
+    (``exec/joins.py: _peek_smaller_left``): one ``build_left`` a query,
+    every join on ``fk`` or ``fk_dense``, and the reference's answer."""
+    run = scaled[seed, query]
+    join = run["join"]
+    assert run["nodes"].count("TpuHashJoinExec") == 1
+    assert join["joins"] == JOINS[query]
+    assert join["build_left"] == 1
+    assert join["fk"] + join["fk_dense"] == join["joins"]
+    assert join["out_slots"] >= join["out_rows"] > 0
+    want = runs[seed, "hot", query]["want"]
+    assert _compared(config, run["got"], want, query) == (0, {})
 
 
 @pytest.mark.parametrize("counter", MISS_COSTS)
