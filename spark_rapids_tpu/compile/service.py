@@ -50,6 +50,11 @@ def _bump(key: str, v) -> None:
 # One family per layer of PERF.md section 3 that launches programs.
 FAMILIES = ("scan", "stage", "aggregate", "sort", "join", "window",
             "concat", "exchange", "egress")
+# The one role a metric reads apart from the families and from whatever
+# body does the work: every aggregate update program (the dense and the
+# sorted body alike), never a merge.  Its rows count under their family too,
+# and its sums sit in ``engine_stats()["roles"]``, outside ``programs``.
+AGG_UPDATE = "agg_update"
 _NAME_RE = re.compile(r"[a-z0-9_]+")
 NO_SPAN = "(no span)"
 
@@ -62,12 +67,13 @@ class _Row:
     reads made so far.  Everything else is written by the watcher under
     ``_LEDGER_LOCK``."""
 
-    __slots__ = ("family", "name", "_ticks", "bump", "_reads",
+    __slots__ = ("family", "name", "role", "_ticks", "bump", "_reads",
                  "device_ns", "no_node_ns", "untimed", "starved_ns")
 
-    def __init__(self, family: str, name: str):
+    def __init__(self, family: str, name: str, role: Optional[str]):
         self.family = family
         self.name = name
+        self.role = role
         self._ticks = itertools.count()
         self.bump = self._ticks.__next__
         self._reads = 0
@@ -87,17 +93,19 @@ _LEDGER_LOCK = threading.Lock()
 _ROWS: Dict[Tuple[str, str], _Row] = {}
 
 
-def _row(family: str, name: str) -> _Row:
+def _row(family: str, name: str, role: Optional[str] = None) -> _Row:
     if family not in FAMILIES:
         raise ValueError(f"engine_jit: family {family!r} is not one of "
                          f"{FAMILIES}")
     if not _NAME_RE.fullmatch(name):
         raise ValueError(f"engine_jit: name {name!r} must match "
                          "[a-z0-9_]+ (it is an engine_stats() key)")
+    if role not in (None, AGG_UPDATE):
+        raise ValueError(f"engine_jit: role {role!r} is not {AGG_UPDATE!r}")
     with _LEDGER_LOCK:
         row = _ROWS.get((family, name))
         if row is None:
-            row = _ROWS[(family, name)] = _Row(family, name)
+            row = _ROWS[(family, name)] = _Row(family, name, role)
         return row
 
 
@@ -311,17 +319,19 @@ class Program:
         return f"Program({self.family}_{self.name})"
 
 
-def engine_jit(fn, *, family: str, name: str, **kwargs) -> Program:
+def engine_jit(fn, *, family: str, name: str, role: Optional[str] = None,
+               **kwargs) -> Program:
     """The one sanctioned ``jax.jit`` wrapper.  ``family`` is one of
     ``FAMILIES`` and ``name`` says which program of the family; the XLA
     module is ``jit_<family>_<name>`` and the body runs under
     ``jax.named_scope("<family>.<name>")``, so a profile names the
-    engine's programs, not ``jit_run``.  A jitted fn compiles lazily on
-    first call per signature (the JAX persistent cache, when the store
-    enabled it, covers those compiles at the XLA layer); call sites that
-    want measured compile time and store counters AOT-compile through
-    ``aot_compile`` instead."""
-    row = _row(family, name)
+    engine's programs, not ``jit_run``.  ``role=AGG_UPDATE`` adds the
+    program's launches and device time to ``roles_snapshot()`` as well.
+    A jitted fn compiles lazily on first call per signature (the JAX
+    persistent cache, when the store enabled it, covers those compiles at
+    the XLA layer); call sites that want measured compile time and store
+    counters AOT-compile through ``aot_compile`` instead."""
+    row = _row(family, name, role)
     scope = f"{family}.{name}"
 
     @functools.wraps(fn)
@@ -337,7 +347,8 @@ def ledger_rows() -> Dict[str, dict]:
     """``{<family>_<name>: counters}``, every program ever named."""
     with _LEDGER_LOCK:
         return {f"{r.family}_{r.name}": {
-            "family": r.family, "dispatches": r.dispatches(),
+            "family": r.family, "role": r.role,
+            "dispatches": r.dispatches(),
             "device_ns": r.device_ns, "no_node_ns": r.no_node_ns,
             "untimed": r.untimed, "starved_ns": dict(r.starved_ns)}
             for r in _ROWS.values()}
@@ -397,6 +408,19 @@ def programs_snapshot() -> dict:
         out[f"{fam}_dispatches"] = fam_n[fam]
         out[f"{fam}_device_us"] = fam_ns[fam] // 1000
     return out
+
+
+def roles_snapshot() -> dict:
+    """The ``roles`` group of ``engine_stats()``: the launches (always) and
+    device microseconds (under the trace switch) of every program built
+    with ``role=AGG_UPDATE``, whatever its family or name."""
+    n = ns = 0
+    for r in ledger_rows().values():
+        if r["role"] == AGG_UPDATE:
+            n += r["dispatches"]
+            ns += r["device_ns"]
+    return {f"{AGG_UPDATE}_dispatches": n,
+            f"{AGG_UPDATE}_device_us": ns // 1000}
 
 
 def pallas_interpret() -> bool:

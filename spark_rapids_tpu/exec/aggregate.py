@@ -50,7 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_tpu.compile.service import engine_jit
+from spark_rapids_tpu.compile.service import AGG_UPDATE, engine_jit
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn, bucket_capacity
 from spark_rapids_tpu.columnar.dtypes import (
@@ -454,7 +454,7 @@ def _compile_folded_update(h_steps, input_sig, aux_sig, capacity: int,
 
     fn = engine_jit(run, family="aggregate",
                     name="masked_pallas_update" if dense
-                    else "masked_update")
+                    else "masked_update", role=AGG_UPDATE)
     _AGG_CACHE[cache_key] = fn
     return fn
 

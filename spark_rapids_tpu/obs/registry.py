@@ -210,6 +210,9 @@ def snapshot() -> dict:
         # launches per program family, always counted; device and
         # host-starved microseconds under spark.rapids.sql.trace.enabled
         "programs": compile_service.programs_snapshot(),
+        # the same ledger's rows by role, apart from the families: every
+        # aggregate update program, whatever its body, and no merge
+        "roles": compile_service.roles_snapshot(),
         # where a query's wall time went on the host: planning,
         # executing, and blocked in a device read
         "phases": tracing.phase_stats(),

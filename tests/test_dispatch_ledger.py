@@ -89,6 +89,17 @@ def test_engine_jit_refuses_a_name_that_is_no_stats_key(family, name):
         service.engine_jit(lambda x: x, family=family, name=name)
 
 
+def test_engine_jit_refuses_a_role_it_does_not_know():
+    with pytest.raises(ValueError):
+        service.engine_jit(lambda x: x, family="aggregate",
+                           name="merge", role="merge")
+    prog = service.engine_jit(lambda x: x, family="aggregate",
+                              name="masked_update", role=service.AGG_UPDATE)
+    assert service.ledger_rows()["aggregate_masked_update"]["role"] == \
+        "agg_update"
+    assert (prog.family, prog.name) == ("aggregate", "masked_update")
+
+
 def test_aot_compile_lowers_through_a_program():
     prog = service.engine_jit(lambda x: x * 2, family="stage",
                               name="project")
@@ -152,6 +163,9 @@ def test_snapshot_has_every_key_at_zero_or_more_from_the_start():
         want |= {f"{fam}_dispatches", f"{fam}_device_us"}
     assert set(snap) == want
     assert all(isinstance(v, int) and v >= 0 for v in snap.values())
+    roles = service.roles_snapshot()
+    assert set(roles) == {"agg_update_dispatches", "agg_update_device_us"}
+    assert all(isinstance(v, int) and v >= 0 for v in roles.values())
 
 
 def test_dispatch_counts_are_equal_with_the_switch_on_and_off():
