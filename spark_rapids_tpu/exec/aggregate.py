@@ -29,7 +29,10 @@ elementwise predicate and no compaction gather.
 ``_compile_folded_update`` is the one builder.  Input batches whose
 dense updates share a program are updated as a GROUP, up to
 ``GROUP_MEMBERS`` in one launch that returns one partial a batch: what a
-launch costs the host is paid once for all of them.
+launch costs the host is paid once for all of them.  An update launch is
+waited on only once the next has gone out (``_launch_update`` returns
+its confirm), so the host stages group k + 1 while the device runs
+group k; the partials still join the merge in launch order.
 
 The body under that mask is chosen from what the host can see of the
 batch (``_dense_domain``): a key domain the host already knows (every
@@ -66,7 +69,8 @@ from spark_rapids_tpu.exprs.base import (
 )
 from spark_rapids_tpu.utils.metrics import (
     METRIC_GROUPED_UPDATE_BATCHES, METRIC_MASKED_FILTER_BATCHES,
-    METRIC_PALLAS_AGG_BATCHES, METRIC_TOTAL_TIME,
+    METRIC_OVERLAPPED_UPDATE_LAUNCHES, METRIC_PALLAS_AGG_BATCHES,
+    METRIC_TOTAL_TIME,
 )
 
 
@@ -739,32 +743,42 @@ class TpuHashAggregateExec(TpuExec):
         """One update over one input batch: a group of one."""
         return self._update_group([self._stage(batch, conf)], [batch])[0]
 
-    def _update_with_retry(self, members, batches, ctx):
-        """OOM -> spill-retry, then split rows and retry (reference
-        RmmRapidsRetryIterator withRetry + SplitAndRetryOOM,
-        aggregate.scala update path).  A group retries whole after the
-        spill; one that still does not fit is given up, and its members
-        go one a launch, where the rows split to the full depth."""
+    def _launch_update(self, members, batches, ctx):
+        """Launch one update inside a retry scope and return its confirm:
+        called, it waits for the launch and returns its partials, running
+        the OOM ladder there (reference RmmRapidsRetryIterator withRetry +
+        SplitAndRetryOOM, aggregate.scala update path).  A group retries
+        whole after the spill; one that still does not fit is given up,
+        and its members go one a launch, where the rows split to the
+        full depth."""
         from spark_rapids_tpu.utils.retry import (
-            is_device_oom, split_batch_half, with_retry,
+            is_device_oom, launch, split_batch_half, with_retry,
         )
-        if len(members) > 1:
+
+        def alone(m, b):
+            # a half is another batch: it is staged anew
+            return lambda x: self._update_group(
+                [m if x is b else self._stage(x, ctx.conf)], [x])[0]
+
+        if len(members) == 1:
+            (m,), (b,) = members, batches
+            return launch(alone(m, b), b, ctx, split=split_batch_half).confirm
+        group = launch(lambda bs: self._update_group(members, bs), batches,
+                       ctx)
+
+        def confirm():
             try:
-                (parts,) = with_retry(
-                    lambda bs: self._update_group(members, bs), batches,
-                    ctx)
+                (parts,) = group.confirm()
                 return parts
             except Exception as e:
                 if not is_device_oom(e):
                     raise
-        parts = []
-        for m, b in zip(members, batches):
-            # a half is another batch: it is staged anew
-            parts.extend(with_retry(
-                lambda x: self._update_group(
-                    [m if x is b else self._stage(x, ctx.conf)], [x])[0],
-                b, ctx, split=split_batch_half))
-        return parts
+            parts = []
+            for m, b in zip(members, batches):
+                parts.extend(with_retry(alone(m, b), b, ctx,
+                                        split=split_batch_half))
+            return parts
+        return confirm
 
     def _run_merge(self, batch: ColumnarBatch):
         """Merge concatenated partials: the sorted body with the merge
@@ -862,10 +876,23 @@ class TpuHashAggregateExec(TpuExec):
             # coalesce's pending batches are (exec/coalesce.py), their
             # staging beside them
             members, waiting = [], []
+            # the confirm of the update launched last: launch k + 1 goes
+            # out before launch k is waited on, so the host stages one
+            # group while the device runs the one before; its inputs stay
+            # reachable until the confirm, which may re-run them
+            pending = []
+
+            def confirm():
+                if pending:
+                    for part in pending.pop()():
+                        partials.append(SpillableBatch(part, cat))
 
             def update(ms, batches):
-                for part in self._update_with_retry(ms, batches, ctx):
-                    partials.append(SpillableBatch(part, cat))
+                launched = self._launch_update(ms, batches, ctx)
+                if pending:
+                    self.metrics[METRIC_OVERLAPPED_UPDATE_LAUNCHES].add(1)
+                confirm()
+                pending.append(launched)
 
             def close_group():
                 if members:
@@ -888,6 +915,7 @@ class TpuHashAggregateExec(TpuExec):
                     if len(members) >= GROUP_MEMBERS:
                         close_group()
                 close_group()
+                confirm()
                 if not partials:
                     if self.groupings:
                         return  # grouped agg of empty input -> no rows
@@ -898,6 +926,7 @@ class TpuHashAggregateExec(TpuExec):
                     partials.append(SpillableBatch(
                         self._run_update(empty), cat))  # sorted body
             except BaseException:
+                del pending[:]
                 close_all(waiting)
                 close_all(partials)
                 raise

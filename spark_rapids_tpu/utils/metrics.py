@@ -95,6 +95,7 @@ METRIC_DATA_SIZE = "dataSize"
 METRIC_PALLAS_AGG_BATCHES = "pallasAggBatches"
 METRIC_MASKED_FILTER_BATCHES = "maskedFilterBatches"
 METRIC_GROUPED_UPDATE_BATCHES = "groupedUpdateBatches"
+METRIC_OVERLAPPED_UPDATE_LAUNCHES = "overlappedUpdateLaunches"
 METRIC_FK_FAST_PATH_BATCHES = "fkFastPathBatches"
 METRIC_BAND_JOIN_PROBES = "bandJoinProbes"
 METRIC_SCAN_CACHE_HITS = "scanCacheHits"

@@ -10,9 +10,12 @@ TPU shape: XLA raises RESOURCE_EXHAUSTED from a kernel launch; we ask the
 spill catalog to demote everything it can, retry once, then split the
 input batch rows in half and recurse (bounded depth).  Under JAX async
 dispatch the error can surface at a later consumption point, so the
-retry scope synchronizes on ``fn``'s result before returning — a
-deferred launch failure is raised HERE, inside the scope that can
-recover, not downstream where nothing can."""
+retry scope synchronizes on ``fn``'s result before the result is used —
+a deferred launch failure is raised HERE, inside the scope that can
+recover, not downstream where nothing can.  The scope comes in two
+phases (``launch``, then ``Launched.confirm``) so a caller can issue its
+next launch before it waits on this one; ``with_retry`` runs both at
+once."""
 
 from __future__ import annotations
 
@@ -107,6 +110,75 @@ def _sync_result(obj) -> None:
         blocking_wait(arrays, "retry")
 
 
+class Launched:
+    """One launch of ``fn(batch)`` inside a retry scope, not yet waited on
+    (``launch``): it holds ``fn``, the input and the retry arguments, so
+    ``confirm`` can run the whole ladder over them."""
+
+    def __init__(self, fn, batch, ctx, split, max_depth, fire_launch_site,
+                 result, error):
+        self.fn, self.batch, self.ctx = fn, batch, ctx
+        self.split, self.max_depth = split, max_depth
+        self.fire_launch_site = fire_launch_site
+        self._result, self._error = result, error
+
+    def confirm(self) -> List:
+        """Wait for the launch and return ``[result]``; on a device OOM,
+        whether the launch raised it or the wait, spill everything
+        spillable and re-run, then split and recurse, all inside this
+        scope."""
+        fn, batch, ctx = self.fn, self.batch, self.ctx
+        try:
+            if self._error is not None:
+                raise self._error
+            _sync_result(self._result)
+            return [self._result]
+        except Exception as e:
+            if not is_device_oom(e):
+                raise
+            self._result = self._error = None
+            if ctx is not None:
+                # pressure-relief retry: demote every unpinned handle (a
+                # catalog-locked sweep; the budget itself is never
+                # mutated, so concurrent retries cannot corrupt it)
+                ctx.runtime.catalog.spill_all()
+                try:
+                    res = fn(batch)
+                    _sync_result(res)
+                    return [res]
+                except Exception as e2:
+                    if not is_device_oom(e2):
+                        raise
+            if self.split is None or self.max_depth <= 0 \
+                    or batch.num_rows <= 1:
+                raise
+        out: List = []
+        for part in _split_with_relief(self.split, batch, ctx):
+            out.extend(with_retry(fn, part, ctx, self.split,
+                                  self.max_depth - 1,
+                                  fire_launch_site=self.fire_launch_site))
+        return out
+
+
+def launch(fn: Callable, batch, ctx=None,
+           split: Optional[Callable] = None, max_depth: int = 3,
+           fire_launch_site: bool = True) -> Launched:
+    """The first phase of ``with_retry``: fire ``kernel.launch`` and call
+    ``fn(batch)``, waiting for nothing.  A device OOM the call raises is
+    held for ``confirm``, which recovers from it as from one the wait
+    raises; any other error raises here."""
+    try:
+        if fire_launch_site:
+            faults.maybe_fail_oom("kernel.launch")
+        res, err = fn(batch), None
+    except Exception as e:
+        if not is_device_oom(e):
+            raise
+        res, err = None, e
+    return Launched(fn, batch, ctx, split, max_depth, fire_launch_site,
+                    res, err)
+
+
 def with_retry(fn: Callable, batch, ctx=None,
                split: Optional[Callable] = None,
                max_depth: int = 3,
@@ -123,49 +195,31 @@ def with_retry(fn: Callable, batch, ctx=None,
     ``fire_launch_site=False`` so one attempt never consumes two
     injection triggers.
 
-    Synchronization policy: EVERY attempt synchronizes on ``fn``'s
-    result (one batched ``jax.block_until_ready``) before the scope
+    Synchronization policy: the scope has two phases, ``launch`` (fire
+    the site, call ``fn``) and ``Launched.confirm`` (one batched
+    ``jax.block_until_ready`` over ``fn``'s result, and the OOM ladder
+    on failure); ``with_retry`` is the one followed at once by the
+    other, and EVERY attempt of the ladder synchronizes before it
     returns.  Under JAX async dispatch a launch failure can otherwise
     surface at a later consumption point where nothing can recover —
     the sort/window/FK-join fns return un-synced device arrays, so
     without the sync their retries would never fire for real device
-    OOMs.  The lost overlap is recovered structurally by the scan
-    prefetch/double-buffer pipeline (docs/io_overlap.md), which overlaps
-    host work with device compute across batches rather than relying on
-    un-synced results escaping the retry scope.
+    OOMs.  A caller may hold a ``Launched`` across its next launch (the
+    aggregate confirms update k after it has launched k + 1, so the host
+    stages one group while the device runs the one before), but it
+    confirms each before it uses the result: the wait still precedes
+    consumption, and a deferred failure still raises inside the scope.
+    Elsewhere the overlap comes from the scan prefetch/double-buffer
+    pipeline (docs/io_overlap.md), which overlaps host work with device
+    compute across batches rather than relying on un-synced results
+    escaping the retry scope.
 
     The split call itself runs under the same spill-retry: materializing
     both halves while the original batch is live can OOM under exactly
     the pressure that triggered the split, so a split-time OOM gets one
     pressure-relief attempt instead of propagating uncaught."""
-    try:
-        if fire_launch_site:
-            faults.maybe_fail_oom("kernel.launch")
-        res = fn(batch)
-        _sync_result(res)
-        return [res]
-    except Exception as e:
-        if not is_device_oom(e):
-            raise
-        if ctx is not None:
-            # pressure-relief retry: demote every unpinned handle (a
-            # catalog-locked sweep; the budget itself is never mutated, so
-            # concurrent retries cannot corrupt it)
-            ctx.runtime.catalog.spill_all()
-            try:
-                res = fn(batch)
-                _sync_result(res)
-                return [res]
-            except Exception as e2:
-                if not is_device_oom(e2):
-                    raise
-        if split is None or max_depth <= 0 or batch.num_rows <= 1:
-            raise
-    out: List = []
-    for part in _split_with_relief(split, batch, ctx):
-        out.extend(with_retry(fn, part, ctx, split, max_depth - 1,
-                              fire_launch_site=fire_launch_site))
-    return out
+    return launch(fn, batch, ctx, split, max_depth,
+                  fire_launch_site).confirm()
 
 
 def _split_with_relief(split: Callable, batch, ctx) -> List:

@@ -145,6 +145,9 @@ def test_grouped_partials_equal_one_a_launch(tmp_path, monkeypatch, query,
     assert _launches(before, DENSE) == len(want_sizes)
     in_groups = sum(n for n in want_sizes if n > 1)
     assert sum_plan_metric(s, "groupedUpdateBatches") == in_groups
+    # launches less one: 0 for a single group
+    assert sum_plan_metric(s, "overlappedUpdateLaunches") == \
+        len(want_sizes) - 1
     assert sum_plan_metric(s, "pallasAggBatches") == batches
     assert sum_plan_metric(s, "maskedFilterBatches") == batches
     grouped_partials, spy.sizes, spy.partials = spy.partials, [], []
@@ -153,6 +156,7 @@ def test_grouped_partials_equal_one_a_launch(tmp_path, monkeypatch, query,
     alone, s1 = _run(build)
     assert spy.sizes == [1] * batches
     assert sum_plan_metric(s1, "groupedUpdateBatches") == 0
+    assert sum_plan_metric(s1, "overlappedUpdateLaunches") == batches - 1
     assert sum_plan_metric(s1, "pallasAggBatches") == batches
     assert len(grouped_partials) == batches
     assert grouped_partials == spy.partials

@@ -9,8 +9,10 @@ The answers equal the plain reference (``benchmark/reference/tpch.py``)
 under the configuration's limits on three seeds; every update batch took
 the dense route; ``roles.agg_update_dispatches`` grows by one launch a
 group, ceil(58 / 8) = 8 an aggregate, while the merge, the evaluation and
-the other aggregate programs move only the ``aggregate`` family; and under
-the trace switch ``roles.agg_update_device_us`` grows, off it not.
+the other aggregate programs move only the ``aggregate`` family; every
+launch but the first goes out before the one before it is confirmed
+(``overlappedUpdateLaunches`` 7); and under the trace switch
+``roles.agg_update_device_us`` grows, off it not.
 """
 
 import importlib.util
@@ -101,6 +103,9 @@ def runs(tmp_path_factory, config):
                     "got": got, "want": reference.QUERIES[q](paths),
                     "batches": sum(n.metrics.get("pallasAggBatches", 0)
                                    for n in nodes),
+                    "overlapped": sum(
+                        n.metrics.get("overlappedUpdateLaunches", 0)
+                        for n in nodes),
                     "grown": {k: after[k] - before[k] for k in after},
                     "ledger": {
                         p: r["dispatches"]
@@ -152,6 +157,16 @@ def test_the_update_role_counts_one_launch_a_group_and_no_merge(runs, seed,
     assert ledger["aggregate_evaluate"] >= 1
     assert run["grown"]["aggregate_dispatches"] == sum(ledger.values()) \
         > groups
+
+
+@pytest.mark.parametrize("query", QUERIES)
+@pytest.mark.parametrize("seed", SEEDS)
+def test_every_update_launch_but_the_first_overlaps_the_one_before(
+        runs, seed, query):
+    """The aggregate waits on a group only once the next has gone out:
+    seven of its eight launches go out with the one before unconfirmed."""
+    groups = math.ceil(BATCHES / GROUP_MEMBERS)
+    assert runs[seed, query]["overlapped"] == groups - 1 == 7
 
 
 @pytest.mark.parametrize("query", QUERIES)
